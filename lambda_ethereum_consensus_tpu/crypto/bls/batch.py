@@ -134,7 +134,7 @@ def _contained_chain_verify(checks) -> list[bool] | None:
     returns ``None`` — the caller re-runs the SAME check on the
     bit-exact host path — instead of escaping and dropping the whole
     gossip batch.  The fault is counted per plane and latches the
-    ``/debug/slo`` health flag, so a permanently dead tunnel degrading
+    ``/debug/slo`` health flag, so a permanently dead device degrading
     every drain to host speed cannot hide."""
     try:
         return _device_chain_verify(checks)

@@ -158,14 +158,52 @@ def mul_mod_planes(a, b, interpret: bool = False):
 # reshape (no transpose).
 
 
+_TILE_CALLS: dict = {}
+
+
+def _tile_call(kernel, interpret: bool):
+    """The kernel over whole tiles: ``(32, rows, 128)`` x2 -> same shape.
+
+    Compiled mode puts exactly this behind ONE ``jax.jit`` per kernel: a
+    staged program (ladder, Miller loop) reaches these kernels at hundreds
+    of call sites, and without it every site re-traces the unrolled kernel
+    and lowers its own Mosaic module — minutes of host-side lowering per
+    program.  Behind the jit the trace and the lowering are cached per tile
+    count (operands of every shape flatten and pad to it first), and each
+    site becomes a call the compiler inlines: the same compiled program,
+    lowered in seconds."""
+    key = (kernel, interpret)
+    fn = _TILE_CALLS.get(key)
+    if fn is None:
+        import jax
+        import jax.numpy as jnp
+        from jax.experimental import pallas as pl
+        from jax.experimental.pallas import tpu as pltpu
+
+        spec = pl.BlockSpec(
+            (_N, SUBLANES, LANES), lambda i: (0, i, 0), memory_space=pltpu.VMEM
+        )
+
+        def run(a3, b3):
+            rows = a3.shape[1]
+            return pl.pallas_call(
+                kernel,
+                out_shape=jax.ShapeDtypeStruct((_N, rows, LANES), jnp.int32),
+                grid=(rows // SUBLANES,),
+                in_specs=[spec, spec],
+                out_specs=spec,
+                interpret=interpret,
+            )(a3, b3)
+
+        fn = _TILE_CALLS[key] = run if interpret else jax.jit(run)
+    return fn
+
+
 def _plane_call(kernel, a, b, interpret: bool):
     """Broadcast two plane operands, flatten component axes into the
     batch, pad to the tile quantum, run the kernel tile-wise, restore the
     shape."""
-    import jax
     import jax.numpy as jnp
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
 
     shape = jnp.broadcast_shapes(a.shape, b.shape)
     a = jnp.broadcast_to(a, shape)
@@ -179,17 +217,9 @@ def _plane_call(kernel, a, b, interpret: bool):
         a = jnp.pad(a, ((0, 0), (0, mp - m)))
         b = jnp.pad(b, ((0, 0), (0, mp - m)))
     rows = mp // LANES
-    spec = pl.BlockSpec(
-        (_N, SUBLANES, LANES), lambda i: (0, i, 0), memory_space=pltpu.VMEM
+    out = _tile_call(kernel, interpret)(
+        a.reshape(_N, rows, LANES), b.reshape(_N, rows, LANES)
     )
-    out = pl.pallas_call(
-        kernel,
-        out_shape=jax.ShapeDtypeStruct((_N, rows, LANES), jnp.int32),
-        grid=(rows // SUBLANES,),
-        in_specs=[spec, spec],
-        out_specs=spec,
-        interpret=interpret,
-    )(a.reshape(_N, rows, LANES), b.reshape(_N, rows, LANES))
     return out.reshape(_N, mp)[:, :m].reshape(shape)
 
 

@@ -14,10 +14,11 @@ with three planks:
   and ``memory_analysis()`` footprint, keyed ``(entry, shape signature)``
   like the attribution table.  Joined with the per-entry call counts and
   the entry's span-histogram family, each entry gets achieved-GFLOP/s and
-  achieved-GB/s plus a roofline ratio against a per-backend peak table
-  (:data:`PEAKS` — the TPU row is the v5e datasheet; CPU/GPU rows are
-  honest order-of-magnitude placeholders, overridable via
-  ``PROFILE_PEAK_GFLOPS``/``PROFILE_PEAK_GBS``).  ``/debug/profile``
+  achieved-GB/s plus a roofline ratio against the published peaks of the
+  device (:data:`PEAKS`, keyed by jax's ``device_kind``, each row citing
+  its source; ``PROFILE_PEAK_GFLOPS``/``PROFILE_PEAK_GBS`` calibrate a
+  device the table does not hold — one that is neither in the table nor
+  calibrated gets NO roofline ratio).  ``/debug/profile``
   serves the ranked headroom view; ``ops_entry_flops_total`` /
   ``ops_entry_bytes_total`` / ``ops_entry_roofline_ratio`` expose the
   same numbers to Prometheus.
@@ -68,7 +69,7 @@ from ..tracing import get_recorder
 __all__ = [
     "PEAKS",
     "PlaneRegistry",
-    "backend_peaks",
+    "device_peaks",
     "capture_budget",
     "capture_state",
     "capture_trace",
@@ -155,23 +156,25 @@ def cost_for(entry: str, sig: str) -> dict | None:
         return dict(row) if row is not None else None
 
 
-# Per-backend peak table: (peak GFLOP/s, peak GB/s).  The TPU row is the
-# v5e datasheet (197 TFLOP/s bf16 MXU, 819 GB/s HBM); the CPU and GPU
-# rows are HONEST PLACEHOLDERS — order-of-magnitude single-socket /
-# single-card figures so a CPU dev run still ranks entries sensibly.
-# Override per deployment with PROFILE_PEAK_GFLOPS / PROFILE_PEAK_GBS.
-PEAKS: dict[str, tuple[float, float]] = {
-    "tpu": (197000.0, 819.0),
-    "gpu": (10000.0, 900.0),
-    "cpu": (50.0, 20.0),
+# Published peaks by jax ``device_kind``: (peak GFLOP/s, peak GB/s,
+# source).  A device that is not here has no roofline — neither another
+# chip's peaks nor a host placeholder stands in for it.  Calibrate such a
+# deployment with PROFILE_PEAK_GFLOPS / PROFILE_PEAK_GBS.
+PEAKS: dict[str, tuple[float, float, str]] = {
+    "TPU v5 lite": (
+        197000.0,
+        819.0,
+        'Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, '
+        "819 GB/s HBM per chip",
+    ),
 }
 
 
-def backend_peaks(backend: str | None) -> dict:
-    """``{"gflops", "gbs", "backend", "source"}`` for one backend name,
-    with the env overrides applied."""
-    gflops, gbs = PEAKS.get(backend or "cpu", PEAKS["cpu"])
-    source = "table"
+def device_peaks(device_kind: str | None) -> dict | None:
+    """``{"device_kind", "gflops", "gbs", "source"}`` for one device
+    kind with the env overrides applied, or ``None`` when the kind is not
+    in the table and not fully calibrated from the environment."""
+    gflops, gbs, source = PEAKS.get(device_kind or "", (None, None, None))
     # each override parses independently: a typo in one must not
     # silently discard the other valid calibration
     try:
@@ -186,7 +189,12 @@ def backend_peaks(backend: str | None) -> dict:
             gbs, source = float(env_gb), "env"
     except ValueError:
         pass
-    return {"backend": backend, "gflops": gflops, "gbs": gbs, "source": source}
+    if gflops is None or gbs is None:
+        return None
+    return {
+        "device_kind": device_kind, "gflops": gflops, "gbs": gbs,
+        "source": source,
+    }
 
 
 # Entry-prefix -> span-histogram family: the dispatch latency evidence
@@ -222,30 +230,31 @@ def _family_totals(metrics, family: str) -> tuple[float, int]:
     return total_s, total_n
 
 
-def _default_backend() -> str | None:
+def _default_device_kind() -> str | None:
     if "jax" not in sys.modules:
         return None
     try:
         import jax
 
-        return jax.default_backend()
+        return jax.devices()[0].device_kind
     except Exception:
         return None
 
 
-def entry_report(metrics=None, backend: str | None = None) -> list[dict]:
+def entry_report(metrics=None, device_kind: str | None = None) -> list[dict]:
     """The ranked headroom view: one row per entry point with FLOP/byte
     attribution, achieved rates against its span family, and the
-    roofline ratio vs the backend peaks.  Rows with achieved data rank
-    first, most headroom first — the entries leaving the most throughput
-    on the table lead the list."""
+    roofline ratio vs the device's published peaks (none for a device
+    kind :func:`device_peaks` does not know).  Rows with roofline data
+    rank first, most headroom first — the entries leaving the most
+    throughput on the table lead the list."""
     from ..slo import slos_for_family
     from .aot import compile_profile
 
     m = metrics if metrics is not None else get_metrics()
-    if backend is None:
-        backend = _default_backend()
-    peaks = backend_peaks(backend)
+    if device_kind is None:
+        device_kind = _default_device_kind()
+    peaks = device_peaks(device_kind)
 
     calls: dict[tuple[str, str], int] = {}
     for row in compile_profile():
@@ -301,10 +310,12 @@ def entry_report(metrics=None, backend: str | None = None) -> list[dict]:
             continue
         e["achieved_gflops"] = e["flops_total"] / span_s / 1e9
         e["achieved_gbs"] = e["bytes_total"] / span_s / 1e9
+        if peaks is None:
+            continue
         e["compute_ratio"] = e["achieved_gflops"] / peaks["gflops"]
         e["memory_ratio"] = e["achieved_gbs"] / peaks["gbs"]
         # the binding resource's achieved fraction; headroom is what a
-        # hand-written kernel could still claim on this backend
+        # hand-written kernel could still claim on this device
         e["roofline_ratio"] = min(
             1.0, max(e["compute_ratio"], e["memory_ratio"])
         )
@@ -690,13 +701,13 @@ def capture_trace(seconds: float, out_dir: str | None = None, tracer=None) -> di
 def profile_report(metrics=None, total_bytes: float | None = None) -> dict:
     """The ``/debug/profile`` payload: ranked entries, plane accounting,
     peaks and capture state in one snapshot."""
-    backend = _default_backend()
+    kind = _default_device_kind()
     if total_bytes is None:
         total_bytes = live_device_bytes()
     return {
-        "backend": backend,
-        "peaks": backend_peaks(backend),
-        "entries": entry_report(metrics=metrics, backend=backend),
+        "device_kind": kind,
+        "peaks": device_peaks(kind),
+        "entries": entry_report(metrics=metrics, device_kind=kind),
         "planes": plane_bytes(total_bytes),
         "live_device_bytes": total_bytes,
         "plane_watermark_bytes": plane_watermark(),

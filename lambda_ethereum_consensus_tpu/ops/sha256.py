@@ -306,8 +306,6 @@ _SHARDED_TREES: dict = {}
 
 def _sharded_tree_fn(mesh, depth_local: int, depth_global: int):
     """One compiled sharded-tree program per (mesh, shape) key."""
-    from .mesh import shard_map_compat
-
     key = (tuple(d.id for d in mesh.devices.flat), depth_local, depth_global)
     fn = _SHARDED_TREES.get(key)
     if fn is not None:
@@ -328,7 +326,10 @@ def _sharded_tree_fn(mesh, depth_local: int, depth_global: int):
         return hash_blocks_jnp(level)  # (1, 8) replicated
 
     fn = jax.jit(
-        shard_map_compat(shard_fn, mesh, P("dp", None), P())
+        jax.shard_map(
+            shard_fn, mesh=mesh, in_specs=P("dp", None), out_specs=P(),
+            check_vma=False,
+        )
     )
     _SHARDED_TREES[key] = fn
     return fn
@@ -374,10 +375,10 @@ def _shard_tree_enabled(n_blocks: int) -> bool:
 
     if env_flag("SSZ_NO_SHARD"):
         return False
-    from .mesh import _multi_device_tpu, initialized_device_count
+    from .mesh import _multi_device_tpu, device_count
 
-    n = initialized_device_count()
-    if n is None or n <= 1:
+    n = device_count()
+    if n <= 1:
         return False
     if env_flag("SSZ_SHARD"):
         return True

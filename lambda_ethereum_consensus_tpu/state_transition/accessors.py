@@ -45,8 +45,8 @@ def get_block_root(state, epoch: int, spec: ChainSpec | None = None) -> bytes:
 # ------------------------------------------------------------- registry
 
 def get_active_validator_indices(state, epoch: int) -> list[int]:
-    if hasattr(state, "active_indices"):  # BeaconStateMut vectorized path
-        return [int(i) for i in state.active_indices(epoch)]
+    if hasattr(state, "active_index_tuple"):  # BeaconStateMut: memoized
+        return state.active_index_tuple(epoch)
     return [
         i for i, v in enumerate(state.validators) if is_active_validator(v, epoch)
     ]

@@ -216,6 +216,7 @@ class BeaconStateMut:
                 value = TrackedList.adopt(value)
             object.__setattr__(self, name, value)
         object.__setattr__(self, "_registry_cache", None)
+        object.__setattr__(self, "_active_cache", {})
         object.__setattr__(self, "_pubkey_index", None)
         # incremental-root engine rides the state lineage (ssz/incremental):
         # process_slot reuses it across slots AND across freeze/thaw cycles
@@ -273,6 +274,7 @@ class BeaconStateMut:
     def touch_registry(self) -> None:
         """Invalidate registry columns after mutating ``validators``."""
         self._registry_cache = None
+        self._active_cache = {}
 
     def update_validator(self, index: int, **changes) -> None:
         self.validators[index] = self.validators[index].copy(**changes)
@@ -308,7 +310,24 @@ class BeaconStateMut:
         return np.asarray(getattr(self, f"{which}_epoch_participation"), np.uint8)
 
     def active_indices(self, epoch: int) -> np.ndarray:
-        """Indices active at ``epoch`` (vectorized is_active_validator)."""
-        reg = self.registry()
-        mask = (reg["activation_epoch"] <= epoch) & (epoch < reg["exit_epoch"])
-        return np.nonzero(mask)[0]
+        """Indices active at ``epoch`` (vectorized is_active_validator).
+        Memoized per epoch until the registry is touched (read-only): a
+        block's attestations ask once each, and a 2^20-validator scan per
+        question is what made a mainnet-width block take minutes."""
+        hit = self._active_cache.get(epoch)
+        if hit is None:
+            reg = self.registry()
+            mask = (reg["activation_epoch"] <= epoch) & (epoch < reg["exit_epoch"])
+            arr = np.nonzero(mask)[0]
+            arr.flags.writeable = False
+            hit = self._active_cache[epoch] = [arr, None]
+        return hit[0]
+
+    def active_index_tuple(self, epoch: int) -> tuple:
+        """:meth:`active_indices` as Python ints — the form the spec's
+        samplers index (same memo, same invalidation)."""
+        arr = self.active_indices(epoch)
+        hit = self._active_cache[epoch]
+        if hit[1] is None:
+            hit[1] = tuple(arr.tolist())
+        return hit[1]

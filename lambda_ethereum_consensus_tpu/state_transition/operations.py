@@ -242,8 +242,12 @@ def process_attestation(
     participation = getattr(state, f"{which}_epoch_participation")
 
     proposer_reward_numerator = 0
+    # get_base_reward per attester, with its per-increment factor (one
+    # O(registry) total-active-balance reduction) taken once, not per index
+    per_increment = accessors.get_base_reward_per_increment(state, spec)
     base_rewards = {
-        i: accessors.get_base_reward(state, i, spec)
+        i: state.validators[i].effective_balance
+        // spec.EFFECTIVE_BALANCE_INCREMENT * per_increment
         for i in indexed.attesting_indices
     }
     for index in indexed.attesting_indices:

@@ -312,8 +312,6 @@ def _build_sharded_kernels(mesh) -> dict:
     from jax import lax
     from jax.sharding import PartitionSpec as P
 
-    from ..ops.mesh import shard_map_compat
-
     key = tuple(d.id for d in mesh.devices.flat)
     with _KERNEL_LOCK:
         hit = _SHARD_KERNELS.get(key)
@@ -328,7 +326,11 @@ def _build_sharded_kernels(mesh) -> dict:
     def _smap(fn, in_specs, out_specs, name, donate=()):
         kwargs = {"donate_argnums": donate} if donate else {}
         jitted = jax.jit(
-            shard_map_compat(fn, mesh, in_specs, out_specs), **kwargs
+            jax.shard_map(
+                fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+                check_vma=False,
+            ),
+            **kwargs,
         )
         return aot_jit(jitted, name, disk=False)
 

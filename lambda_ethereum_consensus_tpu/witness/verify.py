@@ -93,9 +93,9 @@ def _shard_enabled() -> bool:
         return False
     if env_flag("WITNESS_SHARD"):
         return True
-    from ..ops.mesh import _multi_device_tpu, initialized_device_count
+    from ..ops.mesh import _multi_device_tpu
 
-    return _multi_device_tpu(initialized_device_count())
+    return _multi_device_tpu()
 
 
 def _verify_rounds_body(nodes, lidx, ridx, oidx, root_idx, expected):
@@ -149,12 +149,11 @@ def _get_sharded_kernel(mesh):
     from jax.sharding import PartitionSpec as P
 
     from ..ops.aot import aot_jit
-    from ..ops.mesh import shard_map_compat
 
-    sharded = shard_map_compat(
+    sharded = jax.shard_map(
         _verify_rounds_body,
-        mesh,
-        (
+        mesh=mesh,
+        in_specs=(
             P("dp", None, None),  # nodes (B, S, 8)
             P(None, "dp", None),  # lidx (D, B, W)
             P(None, "dp", None),  # ridx
@@ -162,7 +161,8 @@ def _get_sharded_kernel(mesh):
             P("dp"),              # root_idx (B,)
             P("dp", None),        # expected (B, 8)
         ),
-        P("dp"),
+        out_specs=P("dp"),
+        check_vma=False,
     )
     fn = aot_jit(jax.jit(sharded), "witness_verify_sharded")
     _SHARDED_KERNELS[key] = fn

@@ -32,14 +32,11 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 import jax
 
-jax.config.update(
-    "jax_compilation_cache_dir",
-    os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), ".jax_cache"),
-)
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 10.0)
-
 from lambda_ethereum_consensus_tpu.crypto.bls import curve as C, native
 from lambda_ethereum_consensus_tpu.ops import bls_pairing as DP
+from lambda_ethereum_consensus_tpu.utils.env import enable_compile_cache
+
+enable_compile_cache()
 
 
 def make_check(n: int):

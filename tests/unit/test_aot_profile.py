@@ -133,16 +133,19 @@ def test_compile_context_attributes_warmup(no_disk):
     assert aot._ctx_label() == "live"  # context restored
 
 
-def test_uncached_fallback_is_profiled(no_disk):
+def test_unlowerable_function_raises(no_disk):
+    """A program that does not lower is an error, not a quiet uncached
+    run: nothing is cached for it and every call raises."""
     def plain(x):
         return x + 1
 
     call = aot.aot_jit(plain, "prof_plain")
-    assert call(1) == 2
-    assert call(2) == 3  # second call comes from the sig cache
+    assert call.jitted is plain  # the wrapped function stays reachable
+    for _ in range(2):
+        with pytest.raises(AttributeError):
+            call(1)
     row = [e for e in aot.compile_profile() if e["entry"] == "prof_plain"][0]
-    assert row["source"] == "uncached"
-    assert row["misses"] == 1 and row["hits"] == 1
+    assert row["source"] is None and row["hits"] == 0
 
 
 def test_load_failure_counts_error_and_falls_back_to_compile(
@@ -151,7 +154,10 @@ def test_load_failure_counts_error_and_falls_back_to_compile(
     """A corrupt cache file must surface as aot_errors_total{stage=load}
     and a fresh compile, never a wrong result."""
     monkeypatch.delenv("BLS_NO_AOT", raising=False)
-    monkeypatch.setenv("BLS_AOT_DIR", str(tmp_path))
+    # the one cache root placed from outside: the AOT tier is its aot/
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert aot.aot_dir() == str(tmp_path / "aot")
+    (tmp_path / "aot").mkdir()
     fake = _FakeJitted()
     call = aot.aot_jit(fake, "prof_corrupt")
 
@@ -163,7 +169,7 @@ def test_load_failure_counts_error_and_falls_back_to_compile(
     key = hashlib.sha256(
         f"prof_corrupt||{aot._env_tag()}||{sig}||{aot._src_version()}".encode()
     ).hexdigest()[:32]
-    path = os.path.join(str(tmp_path), f"prof_corrupt-{key}.aot")
+    path = os.path.join(aot.aot_dir(), f"prof_corrupt-{key}.aot")
     with open(path, "wb") as fh:
         fh.write(b"not a pickle")
 
@@ -172,6 +178,47 @@ def test_load_failure_counts_error_and_falls_back_to_compile(
     assert _counter("aot_errors_total", stage="load") == before + 1
     row = [e for e in aot.compile_profile() if e["entry"] == "prof_corrupt"][0]
     assert row["errors"] >= 1 and row["source"] == "compile"
+
+
+def test_disk_load_keeps_single_device_program_single_device(
+    monkeypatch, tmp_path
+):
+    """Save an executable, load it back in this 8-virtual-device process
+    and call it with single-device arguments.  ``deserialize_and_load``
+    defaults to every device of the backend, which made the loaded
+    program expect 8 shards ("Expected args to
+    execute_sharded_on_local_devices to have 8 shards") — every second
+    process on a multi-device host."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    assert len(jax.devices()) > 1, "precondition: a multi-device process"
+    monkeypatch.delenv("BLS_NO_AOT", raising=False)
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    x = jnp.arange(12, dtype=jnp.int32).reshape(3, 4)
+    want = np.asarray(x) * 3 + 1
+
+    first = aot.aot_jit(jax.jit(lambda v: v * 3 + 1), "prof_disk_roundtrip")
+    np.testing.assert_array_equal(np.asarray(first(x)), want)
+    saved = list((tmp_path / "aot").glob("prof_disk_roundtrip-*.aot"))
+    assert len(saved) == 1
+
+    # a fresh wrapper has an empty in-memory tier, as a new process would
+    loads_before = _counter("aot_loads_total")
+    errors_before = _counter("aot_errors_total", stage="load")
+    second = aot.aot_jit(jax.jit(lambda v: v * 3 + 1), "prof_disk_roundtrip")
+    got = second(x)
+    np.testing.assert_array_equal(np.asarray(got), want)
+    assert len(got.sharding.device_set) == 1
+    np.testing.assert_array_equal(np.asarray(second(x)), want)  # memory hit
+    assert _counter("aot_loads_total") == loads_before + 1
+    assert _counter("aot_errors_total", stage="load") == errors_before
+    row = [
+        e for e in aot.compile_profile()
+        if e["entry"] == "prof_disk_roundtrip"
+    ][0]
+    assert row["source"] == "disk" and row["loads"] == 1
 
 
 # ------------------------------------------------------------- API routes

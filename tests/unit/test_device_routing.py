@@ -17,9 +17,9 @@ from lambda_ethereum_consensus_tpu.utils import env as env_mod
 
 @pytest.fixture(autouse=True)
 def _reset_device_default_memo():
-    env_mod._DEVICE_DEFAULT = None
+    env_mod._TPU_BACKEND = None
     yield
-    env_mod._DEVICE_DEFAULT = None
+    env_mod._TPU_BACKEND = None
 
 
 def _node():

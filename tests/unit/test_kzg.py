@@ -305,7 +305,7 @@ def test_device_fault_falls_back_to_host(monkeypatch, setup, sample):
     from lambda_ethereum_consensus_tpu.telemetry import get_metrics
 
     def boom(nbits, interpret):
-        raise RuntimeError("dead device tunnel")
+        raise RuntimeError("dead device")
 
     monkeypatch.setattr(K, "_get_msm_kernel", boom)
     blobs, commitments, proofs = sample

@@ -1,7 +1,7 @@
 """Regression tests for the round-25 thread-shared-state /
 lifecycle-teardown sweep (graftlint v2's first interprocedural catch).
 
-Four process-wide memos (``utils/env._DEVICE_DEFAULT``,
+Four process-wide memos (``utils/env._TPU_BACKEND``,
 ``ops/bigint._OPS``, ``ops/bls_fq12._FQ12_OPS``,
 ``ops/mesh._DEFAULT_MESH``) were rebuilt with no lock while being
 reachable from three thread classes at once — the asyncio event loop,
@@ -49,10 +49,10 @@ def test_device_default_memo_single_probe(monkeypatch):
     """Concurrent first calls compute the platform probe once and agree."""
     monkeypatch.setenv("JAX_PLATFORMS", "cpu")
     monkeypatch.delenv("BLS_NO_DEVICE", raising=False)
-    monkeypatch.setattr(env_mod, "_DEVICE_DEFAULT", None)
+    monkeypatch.setattr(env_mod, "_TPU_BACKEND", None)
     results = _hammer(env_mod.device_default)
     assert results == [False] * len(results)
-    assert env_mod._DEVICE_DEFAULT is False
+    assert env_mod._TPU_BACKEND is False
 
 
 def test_bigint_ops_memo_builds_once(monkeypatch):
