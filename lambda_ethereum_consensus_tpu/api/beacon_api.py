@@ -44,11 +44,12 @@ debug contract from the causal-tracing round):
   budgets, multi-window burn rates) as JSON; ``scripts/slo_check.py``
   turns the same report into a CI exit code;
 - ``GET /debug/profile`` — the round-18 cost & memory observatory:
-  entry points ranked by roofline headroom (HLO FLOP/byte attribution
-  vs the per-backend peak table) plus per-plane device-memory
+  the per-entry cost table (HLO FLOP/byte attribution times call
+  counts), the device's published peaks and per-plane device-memory
   accounting; ``POST /debug/profile/capture`` opens a budgeted
   on-demand ``jax.profiler`` window whose start/stop instants land in
-  the flight recorder.
+  the flight recorder and whose trace carries the program's spans as
+  ``span:<name>`` annotations.
 
 Every matched route records its handler latency into the
 ``api_request_seconds{route=...}`` histogram (the family the
@@ -753,22 +754,17 @@ class BeaconApiServer:
         executable with its shape signature, compile/load seconds, cache
         hit/miss counts, causing call site and last use — plus the
         process-wide stat counters.  Round 18 joins the cost-analysis
-        columns (FLOPs, bytes accessed, roofline ratio) onto the same
-        per-(entry, shape) rows — ONE attribution surface, not two.
+        columns (FLOPs, bytes accessed) onto the same per-(entry, shape)
+        rows — ONE attribution surface, not two.
         Offloaded route: the table snapshot copies under ops/aot._LOCK."""
         from ..ops import profile as ops_profile
         from ..ops.aot import all_shape_buckets, aot_stats, compile_profile
 
         rows = compile_profile()
-        roofline = {
-            e["entry"]: e["roofline_ratio"]
-            for e in ops_profile.entry_report()
-        }
         for row in rows:
             cost = ops_profile.cost_for(row["entry"], row["signature"])
             row["flops"] = cost["flops"] if cost else None
             row["bytes_accessed"] = cost["bytes_accessed"] if cost else None
-            row["roofline_ratio"] = roofline.get(row["entry"])
         return self._json({
             "data": {
                 "stats": aot_stats(),
@@ -785,11 +781,12 @@ class BeaconApiServer:
         })
 
     def _debug_profile(self) -> tuple[str, str, bytes]:
-        """The round-18 device cost & memory observatory: entry points
-        ranked by roofline headroom (FLOP/byte attribution joined with
-        their span histograms against the per-backend peak table),
-        per-plane device-memory accounting with the unattributed
-        remainder and high watermark, and the capture budget/state.
+        """The round-18 device cost & memory observatory: the per-entry
+        cost table (FLOP/byte attribution times call counts, ranked by
+        cumulative FLOPs, with the governing span family and SLO), the
+        device's published peaks, per-plane device-memory accounting
+        with the unattributed remainder and high watermark, and the
+        capture budget/state.
         Offloaded route: reads histogram snapshots and (when jax is
         live) walks ``jax.live_arrays()``."""
         from ..ops import profile as ops_profile

@@ -378,8 +378,10 @@ def batch_verify_each_cached(
         live = [(k, r) for k, r in enumerate(pending) if k not in dead_ranges]
         oks = {k: False for k in dead_ranges}
         if live:
+            # a generator: hash-to-G2 and pack() run inside
+            # chain_verify_cached's bls_host_pack span
             for (k, _), ok in zip(
-                live, chain_verify_cached(cache, [pack(r) for _, r in live])
+                live, chain_verify_cached(cache, (pack(r) for _, r in live))
             ):
                 oks[k] = ok
         nxt: list[list[int]] = []

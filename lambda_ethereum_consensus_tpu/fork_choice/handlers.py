@@ -99,7 +99,11 @@ def on_block(
     spec: ChainSpec | None = None,
 ) -> bytes:
     """Validate + apply a block; returns its root (ref: handlers.ex:51-90)."""
-    spec = spec or get_chain_spec()
+    with span("fork_choice_on_block"):
+        return _on_block(store, signed_block, execution_engine, spec or get_chain_spec())
+
+
+def _on_block(store: Store, signed_block, execution_engine, spec: ChainSpec) -> bytes:
     block = signed_block.message
     parent_root = bytes(block.parent_root)
     expect(parent_root in store.block_states, "unknown parent block")
@@ -527,42 +531,44 @@ def _attestation_batch_cached(
 
     pending = []  # (i, att, ctx, cid, attesting, missing, sroot, target_state)
     contain = _DrainContainment("cached attestation drain")
-    for i, attestation in enumerate(attestations):
-        try:
-            validate_on_attestation(store, attestation, is_from_block, spec)
-            store_target_checkpoint_state(store, attestation.data.target, spec)
-            target_state = store.checkpoint_states[
-                checkpoint_key(attestation.data.target)
-            ]
-            ctx = get_attestation_context(
-                store, attestation.data.target, target_state, spec
-            )
-            cid, attesting, missing = ctx.participation(attestation)
-            if len(attesting) == 0:
-                raise ForkChoiceError("attestation has no participants", reject=True)
-            signing_root = ctx.signing_root(attestation.data)
-            pending.append(
-                (i, attestation, ctx, cid, attesting, missing, signing_root,
-                 target_state)
-            )
-        except ForkChoiceError as e:
-            results[i] = e
-        except (BlsError, DeserializationError) as e:
-            results[i] = ForkChoiceError(str(e), reject=True)
-        except (SpecError, ValueError) as e:
-            # context build / numpy participation split can surface plain
-            # ValueError (bad bitfield buffer, cache shape checks) — same
-            # blast-radius rule as the device-cache loop below
-            results[i] = ForkChoiceError(str(e))
-        except Exception as e:
-            # remaining ADVICE r5 gap: the PREP loop lacked the generic
-            # per-item containment the verify loop below already has
-            results[i] = contain.verdict(e)
+    with span("attestation_prepare"):
+        for i, attestation in enumerate(attestations):
+            try:
+                validate_on_attestation(store, attestation, is_from_block, spec)
+                store_target_checkpoint_state(store, attestation.data.target, spec)
+                target_state = store.checkpoint_states[
+                    checkpoint_key(attestation.data.target)
+                ]
+                ctx = get_attestation_context(
+                    store, attestation.data.target, target_state, spec
+                )
+                cid, attesting, missing = ctx.participation(attestation)
+                if len(attesting) == 0:
+                    raise ForkChoiceError("attestation has no participants", reject=True)
+                signing_root = ctx.signing_root(attestation.data)
+                pending.append(
+                    (i, attestation, ctx, cid, attesting, missing, signing_root,
+                     target_state)
+                )
+            except ForkChoiceError as e:
+                results[i] = e
+            except (BlsError, DeserializationError) as e:
+                results[i] = ForkChoiceError(str(e), reject=True)
+            except (SpecError, ValueError) as e:
+                # context build / numpy participation split can surface plain
+                # ValueError (bad bitfield buffer, cache shape checks) — same
+                # blast-radius rule as the device-cache loop below
+                results[i] = ForkChoiceError(str(e))
+            except Exception as e:
+                # remaining ADVICE r5 gap: the PREP loop lacked the generic
+                # per-item containment the verify loop below already has
+                results[i] = contain.verdict(e)
 
     # one thread-pooled decompression pass (C++ when available) — AFTER
     # validation, so junk that fork choice rejects anyway never costs the
     # ~10 ms/sig Python fallback (an event-loop DoS at gossip batch sizes)
-    sig_points = g2_from_bytes_batch([bytes(p[1].signature) for p in pending])
+    with span("signature_decompress"):
+        sig_points = g2_from_bytes_batch([bytes(p[1].signature) for p in pending])
 
     by_ctx: dict[int, list] = {}  # id(ctx) -> [(i, att, attesting, entry)]
     ctxs: dict[int, object] = {}
@@ -655,7 +661,8 @@ def _attestation_batch_cached(
                     "invalid attestation signature", reject=True
                 )
 
-    update_latest_messages_batch(store, accepted)
+    with span("votes_apply"):
+        update_latest_messages_batch(store, accepted)
 
 
 def update_latest_messages_batch(store, accepted) -> None:

@@ -309,26 +309,36 @@ async def _drain_decode_verify(
     ``gossip_batch_error_count`` — ADVICE r5: silent drops look like a
     hung pipeline — with one traceback per outage via ``owner``'s
     latch, not one per drain), short-verdict padding, and per-message
-    verdict dispatch.
+    verdict dispatch.  Two stage spans split the drain around the
+    handler: ``gossip_decode`` (the decode loop) and ``gossip_verdicts``
+    (every awaited ``validate_message``).
 
     ``items`` are ``(subscription, msg_id, payload, peer_id, trace)``;
     ``handler`` receives ``[(subscription, GossipMessage)]`` pairs.
     """
     pairs: list[tuple] = []
-    for sub, msg_id, payload, peer_id, trace in items:
-        try:
-            data = snappy_decompress(payload)
-            value = (
-                sub.ssz_type.decode(data, sub.spec)
-                if sub.ssz_type is not None
-                else None
-            )
-        except Exception:
-            if trace is not None:
-                trace.end("decode_error", _DECODE_END_ARGS)
-            await sub.port.validate_message(msg_id, VERDICT_REJECT)
-            continue
-        pairs.append((sub, GossipMessage(msg_id, data, peer_id, value, trace)))
+    rejected: list[tuple] = []
+    # one span per drain, left before the first await: annotations nest
+    # by thread, not by task
+    with span("gossip_decode", topic=metric_topic):
+        for sub, msg_id, payload, peer_id, trace in items:
+            try:
+                data = snappy_decompress(payload)
+                value = (
+                    sub.ssz_type.decode(data, sub.spec)
+                    if sub.ssz_type is not None
+                    else None
+                )
+            except Exception:
+                if trace is not None:
+                    trace.end("decode_error", _DECODE_END_ARGS)
+                rejected.append((sub, msg_id))
+                continue
+            pairs.append((sub, GossipMessage(msg_id, data, peer_id, value, trace)))
+    if rejected:  # before the handler: the peer's penalty does not wait for the verify
+        with span("gossip_verdicts", topic=metric_topic):
+            for sub, msg_id in rejected:
+                await sub.port.validate_message(msg_id, VERDICT_REJECT)
     if not pairs:
         return
     handler_failed = False
@@ -351,14 +361,17 @@ async def _drain_decode_verify(
         verdicts += [VERDICT_IGNORE] * (len(pairs) - len(verdicts))
     end_ts = time.monotonic()  # one clock read for the whole batch
     end_stage = "error" if handler_failed else "done"
-    for (sub, msg), verdict in zip(pairs, verdicts):
-        if msg.trace is not None:
-            msg.trace.end(
-                end_stage,
-                _VERDICT_END_ARGS.get(verdict) or {"verdict": str(verdict)},
-                end_ts,
-            )
-        await sub.port.validate_message(msg.msg_id, verdict)
+    # the verdict loop and nothing else: per message one trace end and
+    # one awaited sidecar round trip
+    with span("gossip_verdicts", topic=metric_topic):
+        for (sub, msg), verdict in zip(pairs, verdicts):
+            if msg.trace is not None:
+                msg.trace.end(
+                    end_stage,
+                    _VERDICT_END_ARGS.get(verdict) or {"verdict": str(verdict)},
+                    end_ts,
+                )
+            await sub.port.validate_message(msg.msg_id, verdict)
 
 
 class SharedLaneSink:

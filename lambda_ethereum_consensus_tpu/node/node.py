@@ -54,7 +54,7 @@ from ..types.validator import SignedAggregateAndProof
 from .chain import LiveChainView
 from .pending_blocks import PendingBlocks
 from .sync import SyncBlocks
-from .telemetry import Metrics, telemetry_enabled
+from .telemetry import Metrics, span, telemetry_enabled
 
 log = logging.getLogger("node")
 
@@ -1133,51 +1133,58 @@ class BeaconNode:
             now = time.time()
             await asyncio.sleep(1.0 - (now % 1.0))
             try:
-                on_tick(self.store, int(time.time()), self.spec)
-                # durability barrier: one batched fsync when the
-                # finalized checkpoint advanced this tick (never per-put)
-                self._persist_finality()
-                self._sample_device_telemetry()
-                self._maybe_poll_gossip_stats()
-                # finality-lag decomposition: observes on the FIRST tick
-                # and then once per epoch change (internal dedup) — the
-                # first-tick sample guarantees every soak scenario emits
-                # at least one finality_lag_epochs observation
-                self.forensics.observe_epoch(self.store, self.spec)
-                # one SLO evaluation per tick: publishes the slo_* gauges
-                # and appends the burn-rate snapshot the multi-window
-                # evaluation (and /debug/slo) reads — at 1 Hz the engine's
-                # bounded history covers well past the slow window
-                get_engine().evaluate()
-                if self.store.head_cache is not None:
-                    # O(1) cached head for the per-tick gauge — the full
-                    # LMD-GHOST get_head stays on the consensus-critical
-                    # paths (chain view, API, production)
-                    head = self.store.head_cache.head()
-                    head_block = self.store.blocks.get(head)
-                    if head_block is not None:
-                        # own gauge: sync_store_slot belongs to _on_applied
-                        # (per-applied-block); mixing writers would make
-                        # the sync panel flap between fork heads
-                        self.metrics.set_gauge(
-                            "fork_choice_head_slot", int(head_block.slot)
-                        )
-                    # proposer-boost expiry / checkpoint moves on the
-                    # tick can also flip the head with no apply or
-                    # attestation batch in sight
-                    self._observe_head_transition()
-                # duty phases fire off the tick but run on an executor
-                # thread (batched signing is CPU-heavy by design); one
-                # in-flight firing at a time — a slow phase must not
-                # pile a new firing onto every tick behind it
-                if self.duties is not None and (
-                    self._duty_task is None or self._duty_task.done()
-                ):
-                    self._duty_task = asyncio.ensure_future(
-                        self._fire_duties()
-                    )
+                # synchronous, so its annotation nests on the loop
+                # thread whatever other tasks hold open
+                with span("node_tick"):
+                    self._tick()
             except Exception:
                 log.exception("tick failed")
+
+    def _tick(self) -> None:
+        """One tick's body: never awaits."""
+        on_tick(self.store, int(time.time()), self.spec)
+        # durability barrier: one batched fsync when the
+        # finalized checkpoint advanced this tick (never per-put)
+        self._persist_finality()
+        self._sample_device_telemetry()
+        self._maybe_poll_gossip_stats()
+        # finality-lag decomposition: observes on the FIRST tick
+        # and then once per epoch change (internal dedup) — the
+        # first-tick sample guarantees every soak scenario emits
+        # at least one finality_lag_epochs observation
+        self.forensics.observe_epoch(self.store, self.spec)
+        # one SLO evaluation per tick: publishes the slo_* gauges
+        # and appends the burn-rate snapshot the multi-window
+        # evaluation (and /debug/slo) reads — at 1 Hz the engine's
+        # bounded history covers well past the slow window
+        get_engine().evaluate()
+        if self.store.head_cache is not None:
+            # O(1) cached head for the per-tick gauge — the full
+            # LMD-GHOST get_head stays on the consensus-critical
+            # paths (chain view, API, production)
+            head = self.store.head_cache.head()
+            head_block = self.store.blocks.get(head)
+            if head_block is not None:
+                # own gauge: sync_store_slot belongs to _on_applied
+                # (per-applied-block); mixing writers would make
+                # the sync panel flap between fork heads
+                self.metrics.set_gauge(
+                    "fork_choice_head_slot", int(head_block.slot)
+                )
+            # proposer-boost expiry / checkpoint moves on the
+            # tick can also flip the head with no apply or
+            # attestation batch in sight
+            self._observe_head_transition()
+        # duty phases fire off the tick but run on an executor
+        # thread (batched signing is CPU-heavy by design); one
+        # in-flight firing at a time — a slow phase must not
+        # pile a new firing onto every tick behind it
+        if self.duties is not None and (
+            self._duty_task is None or self._duty_task.done()
+        ):
+            self._duty_task = asyncio.ensure_future(
+                self._fire_duties()
+            )
 
     async def _fire_duties(self) -> None:
         """One duty-scheduler pass: phase production on an executor
@@ -1374,7 +1381,7 @@ class BeaconNode:
             except Exception:  # a device fault must not kill ticks
                 pass
         if "lambda_ethereum_consensus_tpu.ops.profile" in sys.modules:
-            # per-entry cost counters/roofline gauges (round 18): gated
+            # per-entry cost counters (round 18): gated
             # on the observatory already being imported — it is pulled
             # in by the first AOT compile, so a node that never compiled
             # a device program pays nothing here

@@ -12,16 +12,18 @@ with three planks:
   every executable the AOT cache resolves — compiled or deserialized —
   contributes its compile-time ``cost_analysis()`` FLOPs/bytes-accessed
   and ``memory_analysis()`` footprint, keyed ``(entry, shape signature)``
-  like the attribution table.  Joined with the per-entry call counts and
-  the entry's span-histogram family, each entry gets achieved-GFLOP/s and
-  achieved-GB/s plus a roofline ratio against the published peaks of the
-  device (:data:`PEAKS`, keyed by jax's ``device_kind``, each row citing
-  its source; ``PROFILE_PEAK_GFLOPS``/``PROFILE_PEAK_GBS`` calibrate a
-  device the table does not hold — one that is neither in the table nor
-  calibrated gets NO roofline ratio).  ``/debug/profile``
-  serves the ranked headroom view; ``ops_entry_flops_total`` /
-  ``ops_entry_bytes_total`` / ``ops_entry_roofline_ratio`` expose the
-  same numbers to Prometheus.
+  like the attribution table, and is joined with the per-entry call
+  counts and the span-histogram family and SLO that govern the entry.
+  ``/debug/profile`` serves that table, ranked by cumulative FLOPs,
+  beside the published peaks of the device (:data:`PEAKS`, keyed by
+  jax's ``device_kind``, each row citing its source;
+  ``PROFILE_PEAK_GFLOPS``/``PROFILE_PEAK_GBS`` calibrate a device the
+  table does not hold); ``ops_entry_flops_total`` /
+  ``ops_entry_bytes_total`` expose the same counts to Prometheus.  No
+  share of a peak is computed here: compile-time FLOPs over host span
+  seconds are not a device rate, and the BLS kernels are u32 limb
+  arithmetic for which the published bf16 peak is no ceiling — a device
+  rate comes from the device's own timeline (a capture window).
 - **Per-plane HBM accounting** (:class:`PlaneRegistry`): the subsystems
   that pin device memory (registry planes, the resident epoch plane,
   witness buffers, AOT executables, duty-sign ladders) register byte
@@ -40,15 +42,9 @@ with three planks:
   ``PROFILE_CAPTURE_MAX_S``, deleted (and errored) when the written
   trace exceeds ``PROFILE_CAPTURE_MAX_MB``.  Start/stop instants land in
   the PR-4 flight recorder so Perfetto exports line up with the node's
-  own timeline.
-
-Achieved rates are deliberately conservative: an entry's cumulative
-FLOPs divide by its mapped span family's cumulative seconds, and a span
-can cover host prep plus several entries (the BLS chain stages all ride
-``attestation_batch_verify_seconds``) — so per-entry achieved is a
-*contribution* rate, a lower bound, and the headroom ranking errs toward
-naming more candidates, which is the useful direction for a "where is
-throughput left on the table" view.
+  own timeline, and for the length of the capture every ``telemetry``
+  span writes a ``span:<name>`` annotation into the trace — the host's
+  stages on the device's clock.
 
 No jax import at module scope: a pure-host node can import (and
 register planes with) this module for free; everything device-touching
@@ -63,7 +59,7 @@ import sys
 import threading
 import time
 
-from ..telemetry import get_metrics
+from ..telemetry import annotate_spans, get_metrics
 from ..tracing import get_recorder
 
 __all__ = [
@@ -157,8 +153,8 @@ def cost_for(entry: str, sig: str) -> dict | None:
 
 
 # Published peaks by jax ``device_kind``: (peak GFLOP/s, peak GB/s,
-# source).  A device that is not here has no roofline — neither another
-# chip's peaks nor a host placeholder stands in for it.  Calibrate such a
+# source).  A device that is not here has none — neither another chip's
+# peaks nor a host placeholder stands in for it.  Calibrate such a
 # deployment with PROFILE_PEAK_GFLOPS / PROFILE_PEAK_GBS.
 PEAKS: dict[str, tuple[float, float, str]] = {
     "TPU v5 lite": (
@@ -197,9 +193,9 @@ def device_peaks(device_kind: str | None) -> dict | None:
     }
 
 
-# Entry-prefix -> span-histogram family: the dispatch latency evidence
-# each entry's FLOP counts divide by.  Several chain stages share one
-# drain span — see the module doc for why that stays honest.
+# Entry-prefix -> span-histogram family: the span (and through it the
+# SLO) whose wall time covers the entry's dispatches.  Several chain
+# stages share one drain span.
 _ENTRY_SPANS: tuple[tuple[str, str], ...] = (
     ("duty_sign", "duty_sign_seconds"),
     ("witness_verify", "witness_verify_seconds"),
@@ -241,20 +237,17 @@ def _default_device_kind() -> str | None:
         return None
 
 
-def entry_report(metrics=None, device_kind: str | None = None) -> list[dict]:
-    """The ranked headroom view: one row per entry point with FLOP/byte
-    attribution, achieved rates against its span family, and the
-    roofline ratio vs the device's published peaks (none for a device
-    kind :func:`device_peaks` does not know).  Rows with roofline data
-    rank first, most headroom first — the entries leaving the most
-    throughput on the table lead the list."""
+def entry_report(metrics=None) -> list[dict]:
+    """One row per entry point: FLOP/byte attribution from the cost table
+    times the call counts, code/temp bytes, and the span family and SLO
+    that govern its dispatches.  Ranked by cumulative FLOPs, most first.
+    No achieved rate and no ratio to a peak: compile-time FLOPs over host
+    span seconds say nothing about the device — its timeline does (a
+    ``capture_trace`` window, reduced as ``benchmark/tracered.py`` does)."""
     from ..slo import slos_for_family
     from .aot import compile_profile
 
     m = metrics if metrics is not None else get_metrics()
-    if device_kind is None:
-        device_kind = _default_device_kind()
-    peaks = device_peaks(device_kind)
 
     calls: dict[tuple[str, str], int] = {}
     for row in compile_profile():
@@ -292,9 +285,6 @@ def entry_report(metrics=None, device_kind: str | None = None) -> list[dict]:
         family = _span_family(e["entry"])
         e["span_family"] = family
         e["span_seconds"] = e["span_count"] = None
-        e["achieved_gflops"] = e["achieved_gbs"] = None
-        e["compute_ratio"] = e["memory_ratio"] = None
-        e["roofline_ratio"] = e["headroom"] = None
         e["slo"] = None
         if family is None:
             continue
@@ -306,28 +296,8 @@ def entry_report(metrics=None, device_kind: str | None = None) -> list[dict]:
         span_s, span_n = span_cache[family]
         e["span_seconds"] = round(span_s, 6)
         e["span_count"] = span_n
-        if span_s <= 0.0:
-            continue
-        e["achieved_gflops"] = e["flops_total"] / span_s / 1e9
-        e["achieved_gbs"] = e["bytes_total"] / span_s / 1e9
-        if peaks is None:
-            continue
-        e["compute_ratio"] = e["achieved_gflops"] / peaks["gflops"]
-        e["memory_ratio"] = e["achieved_gbs"] / peaks["gbs"]
-        # the binding resource's achieved fraction; headroom is what a
-        # hand-written kernel could still claim on this device
-        e["roofline_ratio"] = min(
-            1.0, max(e["compute_ratio"], e["memory_ratio"])
-        )
-        e["headroom"] = 1.0 - e["roofline_ratio"]
 
-    ranked = sorted(
-        (e for e in entries.values() if e["roofline_ratio"] is not None),
-        key=lambda e: (-(e["headroom"] or 0.0), -e["flops_total"]),
-    ) + sorted(
-        (e for e in entries.values() if e["roofline_ratio"] is None),
-        key=lambda e: -e["flops_total"],
-    )
+    ranked = sorted(entries.values(), key=lambda e: -e["flops_total"])
     for i, e in enumerate(ranked, 1):
         e["rank"] = i
     return ranked
@@ -340,11 +310,10 @@ _EMITTED_TOTALS: dict[str, tuple[float, float]] = {}
 
 
 def emit_entry_metrics(metrics=None) -> None:
-    """Publish the per-entry families: ``ops_entry_flops_total`` /
-    ``ops_entry_bytes_total`` counter deltas and the
-    ``ops_entry_roofline_ratio`` gauge.  Called from the node tick
-    (gated on this module already being imported) — idempotent across
-    co-resident nodes because the cursors are process-wide."""
+    """Publish the per-entry counter deltas ``ops_entry_flops_total`` /
+    ``ops_entry_bytes_total``.  Called from the node tick (gated on this
+    module already being imported) — idempotent across co-resident nodes
+    because the cursors are process-wide."""
     m = metrics if metrics is not None else get_metrics()
     if not m.enabled:
         return
@@ -370,10 +339,6 @@ def emit_entry_metrics(metrics=None) -> None:
             m.inc("ops_entry_flops_total", d_flops, entry=name)
         if d_bytes > 0:
             m.inc("ops_entry_bytes_total", d_bytes, entry=name)
-        if e["roofline_ratio"] is not None:
-            m.set_gauge(
-                "ops_entry_roofline_ratio", e["roofline_ratio"], entry=name
-            )
 
 
 # --------------------------------------------------- per-plane accounting
@@ -615,7 +580,10 @@ def capture_trace(seconds: float, out_dir: str | None = None, tracer=None) -> di
     oversized window must not start eating the device), deletes the
     capture and raises when the written trace exceeds the byte budget.
     Runs synchronously — callers own the threading (the API route runs
-    it on a worker thread per the round-10 executor discipline).
+    it on a worker thread per the round-10 executor discipline).  For the
+    length of the capture every ``telemetry`` span also writes a
+    ``span:<name>`` annotation into the trace (``telemetry.annotate_spans``),
+    so the device's idle gaps can be laid to host work.
     ``tracer`` is a test seam defaulting to ``jax.profiler``."""
     max_s, max_mb = capture_budget()
     m = get_metrics()
@@ -649,11 +617,17 @@ def capture_trace(seconds: float, out_dir: str | None = None, tracer=None) -> di
         )
         t0 = time.perf_counter()
         try:
-            tracer.start_trace(path)
+            # the program's spans ride the capture as ``span:<name>``
+            # annotations: on before the trace starts, off after it stops
+            annotate_spans(tracer.TraceAnnotation)
             try:
-                time.sleep(seconds)
+                tracer.start_trace(path)
+                try:
+                    time.sleep(seconds)
+                finally:
+                    tracer.stop_trace()
             finally:
-                tracer.stop_trace()
+                annotate_spans(None)
         except Exception:
             m.inc("profile_captures_total", result="error")
             # close the window on the /debug/trace timeline even on a
@@ -699,15 +673,16 @@ def capture_trace(seconds: float, out_dir: str | None = None, tracer=None) -> di
 
 
 def profile_report(metrics=None, total_bytes: float | None = None) -> dict:
-    """The ``/debug/profile`` payload: ranked entries, plane accounting,
-    peaks and capture state in one snapshot."""
+    """The ``/debug/profile`` payload: the per-entry cost table, plane
+    accounting, the device's published peaks and capture state in one
+    snapshot."""
     kind = _default_device_kind()
     if total_bytes is None:
         total_bytes = live_device_bytes()
     return {
         "device_kind": kind,
         "peaks": device_peaks(kind),
-        "entries": entry_report(metrics=metrics, device_kind=kind),
+        "entries": entry_report(metrics=metrics),
         "planes": plane_bytes(total_bytes),
         "live_device_bytes": total_bytes,
         "plane_watermark_bytes": plane_watermark(),

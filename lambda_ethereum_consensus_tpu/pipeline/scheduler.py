@@ -268,10 +268,14 @@ class IngestScheduler:
             if not ready:
                 timeout = self._sleep_budget(now)
                 m.observe("ingest_sched_seconds", time.perf_counter() - t0)
-                try:
-                    await asyncio.wait_for(self._wake.wait(), timeout)
-                except asyncio.TimeoutError:
-                    pass
+                # asleep for want of a ready lane: a submit or the next
+                # lane deadline ends it (never a slow op: an idle node
+                # sleeps here for as long as nothing arrives)
+                with m.span("ingest_wait", slow=float("inf")):
+                    try:
+                        await asyncio.wait_for(self._wake.wait(), timeout)
+                    except asyncio.TimeoutError:
+                        pass
                 continue
             # one DRR round: deficit grows by weight, service is bounded
             # by min(deficit, depth, max_batch) and snapped to a warmed

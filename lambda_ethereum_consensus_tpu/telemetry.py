@@ -17,6 +17,11 @@ perf PRs report against:
   *distributions*, not averages, are what committee-based-consensus
   signature cost is dominated by (arxiv 2302.00418) — p99 per span is the
   dashboard contract.
+- **Device-clock bridge** (:func:`annotate_spans`): while
+  ``ops/profile.capture_trace`` has a profiler capture open, every span
+  also writes a ``jax.profiler.TraceAnnotation`` named ``span:<name>``,
+  so an idle gap on the device's timeline can be laid to what the host
+  was doing.  Outside a capture it costs one global read per span entry.
 - **No-op mode** (``TELEMETRY_OFF=1``, or ``Metrics(enabled=False)``):
   every recording call returns after one attribute check, ``span()``
   returns a shared inert context manager, and no metric keys are ever
@@ -45,6 +50,7 @@ __all__ = [
     "DEFAULT_BUCKETS",
     "BoundSpan",
     "Metrics",
+    "annotate_spans",
     "device_fault",
     "device_fault_state",
     "get_metrics",
@@ -76,6 +82,8 @@ _HELP = {
     "gossip_batch_error_count": "gossip items dropped by internal errors",
     "gossip_queue_depth": "queued gossip messages at drain start",
     "gossip_drain_seconds": "one gossip batch: decode + verify + verdicts",
+    "gossip_decode_seconds": "one gossip batch's snappy + SSZ decode loop",
+    "gossip_verdicts_seconds": "one gossip batch's verdict loop: trace ends + every awaited validate_message",
     "gossip_shed_count": "gossip messages dropped at admission, by topic/reason",
     "ingest_lane_depth": "queued items per ingest scheduler lane",
     "ingest_lane_occupancy": "lane depth over lane capacity (0..1)",
@@ -86,8 +94,17 @@ _HELP = {
     "ingest_batch_size": "items per handler call out of the scheduler",
     "ingest_flush_wait_seconds": "oldest-item queue wait at lane flush",
     "ingest_sched_seconds": "one scheduling round's bookkeeping (no handler time)",
+    "ingest_wait_seconds": "ingest drain loop asleep: no lane ready, waiting for a submit or the next lane deadline",
+    "node_tick_seconds": "one 1 Hz node tick body (fork-choice tick, finality persist, device sampling, SLO evaluation)",
     "ingest_degraded": "1 while the load-shedding latch is active",
     "attestation_batch_verify_seconds": "one batched attestation signature check",
+    "attestation_prepare_seconds": "cached drain: per-item validation, checkpoint state, context and participation split",
+    "signature_decompress_seconds": "one batched G2 signature decompression + subgroup check (host)",
+    "bls_host_pack_seconds": "host side of one chained verify up to its first device dispatch: hash-to-G2, entry packing, limb planes, uploads",
+    "bls_dispatch_seconds": "one chained verify's program calls and the layout packing between them (the device works meanwhile)",
+    "bls_device_wait_seconds": "host blocked fetching one chained verify's verdict flags from the device",
+    "votes_apply_seconds": "vectorized latest-message + head-cache update for one drain's accepted votes",
+    "fork_choice_on_block_seconds": "one fork-choice on_block: checks, state transition, store update",
     "block_transition_seconds": "full state transition of one block (slots + block + state-root check)",
     "epoch_transition_seconds": "one epoch-boundary processing pass (resident or host path)",
     "resident_plane_validators": "validators held as resident device columns by the transition plane",
@@ -100,7 +117,6 @@ _HELP = {
     "device_plane_bytes_watermark": "high watermark of total live device bytes",
     "ops_entry_flops_total": "HLO-estimated FLOPs dispatched per AOT entry point",
     "ops_entry_bytes_total": "HLO-estimated bytes accessed per AOT entry point",
-    "ops_entry_roofline_ratio": "achieved/peak roofline ratio per entry (max of compute and memory fractions)",
     "profile_captures_total": "on-demand jax.profiler capture attempts, by result",
     "profile_capture_seconds": "wall time of one on-demand profiler capture window",
     "registry_plane_resident_bytes": "device bytes of shared registry planes",
@@ -261,8 +277,33 @@ def _emit_slow(name: str, dt: float, slow: float, labels, exc_type) -> None:
     )
 
 
+# The annotation class (``jax.profiler.TraceAnnotation``) while a profiler
+# capture is open, else None: the one global read a span entry pays.
+_ANNOTATION = None
+
+
+def annotate_spans(annotation) -> None:
+    """Device-clock bridge: given ``jax.profiler.TraceAnnotation``, every
+    span entered from now on (``_Span`` and ``_BoundTimer``) also writes an
+    annotation named ``span:<name>`` into the profiler's trace; given None,
+    spans stop doing so.  ``ops/profile.capture_trace`` brackets its
+    capture with the two calls; a span that straddles either edge closes
+    exactly what it opened.  Annotations nest by thread, not by asyncio
+    task: spans held across an ``await`` interleave on the loop thread's
+    line."""
+    global _ANNOTATION
+    _ANNOTATION = annotation
+
+
+def _annotate(cls, name: str):
+    """One span's annotation, entered."""
+    ann = cls("span:" + name)
+    ann.__enter__()
+    return ann
+
+
 class _Span:
-    __slots__ = ("_metrics", "_name", "_labels", "_key", "_slow", "_t0")
+    __slots__ = ("_metrics", "_name", "_labels", "_key", "_slow", "_t0", "_ann")
 
     def __init__(self, metrics: "Metrics", name: str, slow: float, labels: dict):
         self._metrics = metrics
@@ -274,11 +315,15 @@ class _Span:
         self._key = (name + "_seconds", self._labels)
 
     def __enter__(self):
+        cls = _ANNOTATION
+        self._ann = None if cls is None else _annotate(cls, self._name)
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, exc_type, exc, tb):
         dt = time.perf_counter() - self._t0
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
         self._metrics._observe_key(self._key, dt)
         if dt >= self._slow:
             _emit_slow(self._name, dt, self._slow, self._labels, exc_type)
@@ -289,17 +334,21 @@ class _BoundTimer:
     """One timing of a :class:`BoundSpan` — the only per-call allocation
     on a bound call site."""
 
-    __slots__ = ("_bound", "_t0")
+    __slots__ = ("_bound", "_t0", "_ann")
 
     def __init__(self, bound: "BoundSpan"):
         self._bound = bound
 
     def __enter__(self):
+        cls = _ANNOTATION
+        self._ann = None if cls is None else _annotate(cls, self._bound._name)
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, exc_type, exc, tb):
         dt = time.perf_counter() - self._t0
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
         b = self._bound
         hist = b._hist
         if hist is None:

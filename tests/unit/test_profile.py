@@ -91,9 +91,10 @@ class _FakeJitted:
         return _FakeLowered(self._executable)
 
 
-def test_entry_report_ranks_by_roofline_headroom(no_disk):
-    """Entries joined with their span families rank most-headroom-first
-    and carry achieved rates against the device's published peaks."""
+def test_entry_report_joins_cost_calls_span_family_and_slo(no_disk):
+    """One row per entry: cost-table FLOPs times call counts, the span
+    family and SLO that govern it, ranked by cumulative FLOPs — and no
+    achieved rate or share of a peak (those come from a device trace)."""
     m = Metrics(enabled=True)
     # a duty_sign-prefixed entry maps onto duty_sign_seconds
     call = aot.aot_jit(_FakeJitted(_FakeExecutable()), "duty_sign_t18")
@@ -102,46 +103,36 @@ def test_entry_report_ranks_by_roofline_headroom(no_disk):
     call(1.0)  # 3 calls x 2 GFLOP
     m.observe("duty_sign_seconds", 1.0)
     m.observe("duty_sign_seconds", 1.0)  # 2 s total span time
-    report = profile.entry_report(metrics=m, device_kind="TPU v5 lite")
+    report = profile.entry_report(metrics=m)
     row = next(e for e in report if e["entry"] == "duty_sign_t18")
     assert row["calls"] == 3
     assert row["flops_total"] == pytest.approx(6.0e9)
+    assert row["bytes_total"] == pytest.approx(1.2e9)
+    assert row["code_bytes"] == 4096 and row["temp_bytes"] == 512
     assert row["span_family"] == "duty_sign_seconds"
-    assert row["achieved_gflops"] == pytest.approx(3.0)
-    peaks = profile.device_peaks("TPU v5 lite")
-    assert peaks["source"].startswith("Google Cloud documentation")
-    assert row["compute_ratio"] == pytest.approx(3.0 / peaks["gflops"])
-    assert 0.0 <= row["roofline_ratio"] <= 1.0
-    assert row["headroom"] == pytest.approx(1.0 - row["roofline_ratio"])
+    assert row["span_seconds"] == pytest.approx(2.0) and row["span_count"] == 2
     # the governing SLO rides along (duty_sign_p95 budgets this family)
     assert row["slo"]["name"] == "duty_sign_p95"
-    # ranking: rows with roofline data lead, ranks are 1..n
-    ranks = [e["rank"] for e in report]
-    assert ranks == list(range(1, len(report) + 1))
-    with_data = [e for e in report if e["headroom"] is not None]
-    assert sorted(
-        (e["headroom"] for e in with_data), reverse=True
-    ) == [e["headroom"] for e in with_data]
+    for gone in ("achieved_gflops", "compute_ratio", "memory_ratio",
+                 "roofline_ratio", "headroom"):
+        assert gone not in row
+    # ranking: most cumulative FLOPs first, ranks are 1..n
+    assert [e["rank"] for e in report] == list(range(1, len(report) + 1))
+    totals = [e["flops_total"] for e in report]
+    assert totals == sorted(totals, reverse=True)
 
 
-def test_unknown_device_kind_gets_no_roofline(no_disk, monkeypatch):
-    """A device the peak table does not hold is ranked by FLOPs only:
-    achieved rates, but no ratio against another chip's (or a host
-    placeholder's) peaks — unless the deployment calibrates it."""
+def test_device_peaks_table_and_calibration(monkeypatch):
+    """The published-peaks table holds the v5e with its source; a device
+    it does not hold has none — neither another chip's nor a host
+    placeholder's — unless the deployment calibrates both numbers."""
     monkeypatch.delenv("PROFILE_PEAK_GFLOPS", raising=False)
     monkeypatch.delenv("PROFILE_PEAK_GBS", raising=False)
-    m = Metrics(enabled=True)
-    call = aot.aot_jit(_FakeJitted(_FakeExecutable()), "duty_sign_t18u")
-    call(1.0)
-    m.observe("duty_sign_seconds", 1.0)
+    peaks = profile.device_peaks("TPU v5 lite")
+    assert peaks["source"].startswith("Google Cloud documentation")
+    assert (peaks["gflops"], peaks["gbs"]) == (197000.0, 819.0)
     for kind in ("cpu", "TPU v9 imaginary", None):
         assert profile.device_peaks(kind) is None
-    row = next(
-        e for e in profile.entry_report(metrics=m, device_kind="cpu")
-        if e["entry"] == "duty_sign_t18u"
-    )
-    assert row["achieved_gflops"] == pytest.approx(2.0)
-    assert row["roofline_ratio"] is None and row["headroom"] is None
     # one override alone is not a calibration; both are
     monkeypatch.setenv("PROFILE_PEAK_GFLOPS", "100")
     assert profile.device_peaks("cpu") is None
@@ -149,10 +140,7 @@ def test_unknown_device_kind_gets_no_roofline(no_disk, monkeypatch):
     assert profile.device_peaks("cpu")["source"] == "env"
 
 
-def test_emit_entry_metrics_publishes_counter_deltas(no_disk, monkeypatch):
-    # this process runs on a device the table does not hold: calibrate
-    monkeypatch.setenv("PROFILE_PEAK_GFLOPS", "50")
-    monkeypatch.setenv("PROFILE_PEAK_GBS", "20")
+def test_emit_entry_metrics_publishes_counter_deltas(no_disk):
     m = Metrics(enabled=True)
     call = aot.aot_jit(_FakeJitted(_FakeExecutable(flops=1.0e6)), "duty_sign_t18b")
     call(2.0)
@@ -169,7 +157,7 @@ def test_emit_entry_metrics_publishes_counter_deltas(no_disk, monkeypatch):
     assert m.get(
         "ops_entry_flops_total", entry="duty_sign_t18b"
     ) == pytest.approx(first + 1.0e6)
-    assert m.get("ops_entry_roofline_ratio", entry="duty_sign_t18b") >= 0.0
+    assert "ops_entry_roofline_ratio" not in m.family_names()
 
 
 # ---------------------------------------------------------- plane registry
@@ -265,7 +253,7 @@ def test_debug_profile_route_shape(no_disk):
     entries = {e["entry"] for e in data["entries"]}
     assert "duty_sign_t18d" in entries
     for e in data["entries"]:
-        assert {"rank", "flops_total", "headroom", "span_family"} <= set(e)
+        assert {"rank", "calls", "flops_total", "span_family"} <= set(e)
     assert "unattributed" not in data["planes"] or data["planes"][
         "unattributed"
     ] >= 0
@@ -283,7 +271,6 @@ def test_debug_compile_gains_cost_columns(no_disk):
     ]
     assert rows and rows[0]["flops"] == 7.0
     assert rows[0]["bytes_accessed"] > 0
-    assert "roofline_ratio" in rows[0]
     # entries without recorded cost still carry the columns (as null)
     aot.aot_jit(_FakeJitted(lambda *a: None), "prof18_plain")(1)
     _s, _c, body = api._route("GET", "/debug/compile")
@@ -297,17 +284,42 @@ def test_debug_compile_gains_cost_columns(no_disk):
 # --------------------------------------------------------------- capture
 
 
+class _FakeAnnotation:
+    """Stands in for ``jax.profiler.TraceAnnotation``: books what the
+    program's spans write into the capture."""
+
+    seen: list = []
+
+    def __init__(self, name):
+        self.name = name
+
+    def __enter__(self):
+        _FakeAnnotation.seen.append(("enter", self.name))
+        return self
+
+    def __exit__(self, *exc):
+        _FakeAnnotation.seen.append(("exit", self.name))
+
+
 class _FakeTracer:
-    def __init__(self, write_bytes=64):
+    TraceAnnotation = _FakeAnnotation
+
+    def __init__(self, write_bytes=64, fail_start=False, during=None):
         self.started = self.stopped = 0
         self.write_bytes = write_bytes
+        self.fail_start = fail_start
+        self.during = during  # called once the trace is open
         self._dir = None
 
     def start_trace(self, path):
+        if self.fail_start:
+            raise RuntimeError("profiler refused to start")
         self.started += 1
         self._dir = path
         with open(os.path.join(path, "trace.pb"), "wb") as fh:
             fh.write(b"x" * self.write_bytes)
+        if self.during is not None:
+            self.during()
 
     def stop_trace(self):
         self.stopped += 1
@@ -337,6 +349,45 @@ def test_capture_runs_within_budget_and_records_instants(monkeypatch, tmp_path):
     assert "profile_capture_start" in names
     assert "profile_capture_stop" in names
     assert profile.capture_state()["last"]["bytes"] == 128
+
+
+def test_capture_annotates_spans_for_its_length_only(monkeypatch, tmp_path):
+    """The device-clock bridge: spans entered while a capture is open
+    (``_Span`` and ``BoundSpan`` timings alike) write ``span:<name>``
+    annotations and still record their histograms; before and after it,
+    and after a ``start_trace`` that raised, they write none."""
+    from lambda_ethereum_consensus_tpu import telemetry
+
+    monkeypatch.setenv("PROFILE_CAPTURE_MAX_S", "2")
+    m = Metrics(enabled=True)
+    bound = m.bound_span("ssz_hash_tree_root")
+    _FakeAnnotation.seen = []
+
+    def spans():
+        with m.span("gossip_decode", topic="t"):
+            with bound.time():
+                pass
+
+    spans()  # before: no capture, no annotation
+    assert _FakeAnnotation.seen == [] and telemetry._ANNOTATION is None
+    tracer = _FakeTracer(during=spans)
+    profile.capture_trace(0.01, out_dir=str(tmp_path), tracer=tracer)
+    assert _FakeAnnotation.seen == [
+        ("enter", "span:gossip_decode"), ("enter", "span:ssz_hash_tree_root"),
+        ("exit", "span:ssz_hash_tree_root"), ("exit", "span:gossip_decode"),
+    ]
+    assert telemetry._ANNOTATION is None  # off again after stop_trace
+    spans()
+    assert len(_FakeAnnotation.seen) == 4
+    with pytest.raises(RuntimeError, match="refused to start"):
+        profile.capture_trace(
+            0.01, out_dir=str(tmp_path), tracer=_FakeTracer(fail_start=True)
+        )
+    assert telemetry._ANNOTATION is None  # also when start_trace raised
+    spans()
+    assert len(_FakeAnnotation.seen) == 4
+    assert m.get_histogram("gossip_decode_seconds", topic="t")[3] == 4
+    assert m.get_histogram("ssz_hash_tree_root_seconds")[3] == 4
 
 
 def test_capture_over_byte_budget_deletes_trace(monkeypatch, tmp_path):
