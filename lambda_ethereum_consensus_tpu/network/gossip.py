@@ -308,10 +308,19 @@ async def _drain_decode_verify(
     raising batch drops to IGNORE, counted on
     ``gossip_batch_error_count`` — ADVICE r5: silent drops look like a
     hung pipeline — with one traceback per outage via ``owner``'s
-    latch, not one per drain), short-verdict padding, and per-message
-    verdict dispatch.  Two stage spans split the drain around the
-    handler: ``gossip_decode`` (the decode loop) and ``gossip_verdicts``
-    (every awaited ``validate_message``).
+    latch, not one per drain), short-verdict padding, and the verdict
+    hand-over: ``port.validate_message`` once per message, in order,
+    inside the port's ``verdict_batch()`` bracket — each call stages and
+    returns without suspending, and leaving the bracket sends the
+    drain's verdicts to the sidecar as ONE frame and awaits its ONE
+    acknowledgement, so the drain returns only after the sidecar has
+    them all.  At most two such round trips a drain: the REJECTs of
+    undecodable messages before the handler, everything else after it.
+    The bracket is opened on the first item's port (a node has one; a
+    verdict for any other port would go out as a batch of its own).
+    Two stage spans split the drain around the handler:
+    ``gossip_decode`` (the decode loop) and ``gossip_verdicts`` (a
+    hand-over: its loop and its round trip).
 
     ``items`` are ``(subscription, msg_id, payload, peer_id, trace)``;
     ``handler`` receives ``[(subscription, GossipMessage)]`` pairs.
@@ -337,8 +346,9 @@ async def _drain_decode_verify(
             pairs.append((sub, GossipMessage(msg_id, data, peer_id, value, trace)))
     if rejected:  # before the handler: the peer's penalty does not wait for the verify
         with span("gossip_verdicts", topic=metric_topic):
-            for sub, msg_id in rejected:
-                await sub.port.validate_message(msg_id, VERDICT_REJECT)
+            async with rejected[0][0].port.verdict_batch():
+                for sub, msg_id in rejected:
+                    await sub.port.validate_message(msg_id, VERDICT_REJECT)
     if not pairs:
         return
     handler_failed = False
@@ -361,17 +371,20 @@ async def _drain_decode_verify(
         verdicts += [VERDICT_IGNORE] * (len(pairs) - len(verdicts))
     end_ts = time.monotonic()  # one clock read for the whole batch
     end_stage = "error" if handler_failed else "done"
-    # the verdict loop and nothing else: per message one trace end and
-    # one awaited sidecar round trip
+    # the verdict hand-over and nothing else: per message one trace end
+    # and one staged validate_message (looked up on the port at call
+    # time: a harness hooks the instance attribute), then the bracket's
+    # one sidecar round trip
     with span("gossip_verdicts", topic=metric_topic):
-        for (sub, msg), verdict in zip(pairs, verdicts):
-            if msg.trace is not None:
-                msg.trace.end(
-                    end_stage,
-                    _VERDICT_END_ARGS.get(verdict) or {"verdict": str(verdict)},
-                    end_ts,
-                )
-            await sub.port.validate_message(msg.msg_id, verdict)
+        async with pairs[0][0].port.verdict_batch():
+            for (sub, msg), verdict in zip(pairs, verdicts):
+                if msg.trace is not None:
+                    msg.trace.end(
+                        end_stage,
+                        _VERDICT_END_ARGS.get(verdict) or {"verdict": str(verdict)},
+                        end_ts,
+                    )
+                await sub.port.validate_message(msg.msg_id, verdict)
 
 
 class SharedLaneSink:

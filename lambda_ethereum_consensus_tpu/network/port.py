@@ -12,6 +12,7 @@ libp2p_port.ex:232-234).
 from __future__ import annotations
 
 import asyncio
+import contextlib
 import json
 import logging
 import os
@@ -40,6 +41,10 @@ Handler = Callable[..., Awaitable[None] | None]
 # would just burn the backoff schedule).
 PORT_RETRY_MAX = 2
 PORT_RETRY_BASE_S = 0.05
+
+# pow2 size buckets 1..16384 for port_verdict_batch_size (the default
+# telemetry buckets are latency-shaped)
+VERDICT_BATCH_BUCKETS = tuple(float(1 << i) for i in range(15))
 
 
 def _retry_max() -> int:
@@ -95,6 +100,16 @@ class Port:
         # range sync idle) while the sidecar is happily connected.
         # Buffer them and replay on handler assignment.
         self._early_peer_events: list[tuple[str, tuple]] = []
+        # verdicts staged inside an open verdict_batch() bracket, by the
+        # task that opened it: another task's verdict never rides (or
+        # waits for) a bracket that is not its own
+        self._staged_verdicts: dict[asyncio.Task, list[tuple[bytes, int]]] = {}
+        try:
+            get_metrics().register_histogram(
+                "port_verdict_batch_size", VERDICT_BATCH_BUCKETS
+            )
+        except ValueError:
+            pass  # an earlier Port (restart/co-resident node) pinned them
 
     # -------------------------------------------------- peer-event handlers
 
@@ -327,9 +342,48 @@ class Port:
         await self._command(cmd)
 
     async def validate_message(self, msg_id: bytes, verdict: int) -> None:
+        """Hand the sidecar one message's verdict — the per-message entry
+        point of every caller.  Inside the calling task's
+        :meth:`verdict_batch` bracket the verdict is staged and this
+        returns without suspending (the bracket's exit sends the frame);
+        outside one it is a batch of one through the same send path."""
+        if self._staged_verdicts:
+            staged = self._staged_verdicts.get(asyncio.current_task())
+            if staged is not None:
+                staged.append((msg_id, verdict))
+                return
+        await self._send_verdicts(((msg_id, verdict),))
+
+    @contextlib.asynccontextmanager
+    async def verdict_batch(self):
+        """Bracket of one drain's verdict loop: every
+        :meth:`validate_message` the task makes inside it is staged in
+        order, and leaving the bracket sends them as ONE
+        ``validate_messages`` frame and awaits its ONE ``Result`` — so the
+        drain returns only after the sidecar acknowledged all of them.
+        Flushed by whoever staged, at the end of its loop: no timer, no
+        size threshold, nothing held across brackets.  Not re-entrant."""
+        task = asyncio.current_task()
+        staged = self._staged_verdicts[task] = []
+        try:
+            yield
+        finally:
+            # a raising loop body still owes the sidecar what it staged
+            del self._staged_verdicts[task]
+            await self._send_verdicts(staged)
+
+    async def _send_verdicts(self, verdicts) -> None:
+        """The one send path of verdicts: ``(msg_id, verdict)`` pairs as
+        one command through :meth:`_command` (its timeout, its retries —
+        the sidecar pops a pending entry the first time, so a re-sent
+        batch changes nothing).  An empty batch writes nothing."""
+        if not verdicts:
+            return
         cmd = port_pb2.Command()
-        cmd.validate_message.msg_id = msg_id
-        cmd.validate_message.verdict = verdict
+        add = cmd.validate_messages.verdicts.add
+        for msg_id, verdict in verdicts:
+            add(msg_id=msg_id, verdict=verdict)
+        get_metrics().observe("port_verdict_batch_size", len(verdicts))
         await self._command(cmd)
 
     async def set_request_handler(self, protocol_id: str, handler: Handler) -> None:

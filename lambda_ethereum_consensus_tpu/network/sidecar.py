@@ -287,6 +287,16 @@ class Sidecar:
                 cmd.validate_message.msg_id, cmd.validate_message.verdict
             )
             await self.result(cmd.id, True)
+        elif which == "validate_messages":
+            # one drain's verdicts in one frame, one Result: a verdict
+            # that raises does not keep the rest from being applied
+            error = ""
+            for v in cmd.validate_messages.verdicts:
+                try:
+                    await self.finish_validation(v.msg_id, v.verdict)
+                except Exception as e:
+                    error = error or f"{type(e).__name__}: {e}"
+            await self.result(cmd.id, not error, error=error)
         elif which == "set_request_handler":
             self.handlers.add(cmd.set_request_handler.protocol_id)
             await self.result(cmd.id, True)

@@ -162,10 +162,20 @@ class Libp2pSidecar:
             await self.gossip.publish(cmd.publish.topic, cmd.publish.payload)
             await self.result(cmd.id, True)
         elif which == "validate_message":
-            fut = self.pending_validation.pop(cmd.validate_message.msg_id, None)
-            if fut is not None and not fut.done():
-                fut.set_result(_VERDICTS.get(cmd.validate_message.verdict, IGNORE))
+            self.finish_validation(
+                cmd.validate_message.msg_id, cmd.validate_message.verdict
+            )
             await self.result(cmd.id, True)
+        elif which == "validate_messages":
+            # one drain's verdicts in one frame, one Result: a verdict
+            # that raises does not keep the rest from being applied
+            error = ""
+            for v in cmd.validate_messages.verdicts:
+                try:
+                    self.finish_validation(v.msg_id, v.verdict)
+                except Exception as e:
+                    error = error or f"{type(e).__name__}: {e}"
+            await self.result(cmd.id, not error, error=error)
         elif which == "set_request_handler":
             protocol = cmd.set_request_handler.protocol_id
             self.host.set_stream_handler(protocol, self._serve_stream)
@@ -309,6 +319,13 @@ class Libp2pSidecar:
         except asyncio.TimeoutError:
             self.pending_validation.pop(msg_id, None)
             return IGNORE
+
+    def finish_validation(self, msg_id: bytes, verdict: int) -> None:
+        """The host's verdict resolves the validator's wait; an unknown,
+        expired or already-answered ``msg_id`` is harmless."""
+        fut = self.pending_validation.pop(msg_id, None)
+        if fut is not None and not fut.done():
+            fut.set_result(_VERDICTS.get(verdict, IGNORE))
 
     # ------------------------------------------------------------ req/resp
     async def _serve_stream(self, stream, protocol: str, peer_id: PeerId) -> None:

@@ -83,7 +83,7 @@ _HELP = {
     "gossip_queue_depth": "queued gossip messages at drain start",
     "gossip_drain_seconds": "one gossip batch: decode + verify + verdicts",
     "gossip_decode_seconds": "one gossip batch's snappy + SSZ decode loop",
-    "gossip_verdicts_seconds": "one gossip batch's verdict loop: trace ends + every awaited validate_message",
+    "gossip_verdicts_seconds": "one gossip batch's verdict hand-over: trace ends + every validate_message staged, then the batch's one sidecar round trip",
     "gossip_shed_count": "gossip messages dropped at admission, by topic/reason",
     "ingest_lane_depth": "queued items per ingest scheduler lane",
     "ingest_lane_occupancy": "lane depth over lane capacity (0..1)",
@@ -112,6 +112,7 @@ _HELP = {
     "fork_choice_head_recompute_seconds": "uncached LMD-GHOST head walk",
     "ssz_hash_tree_root_seconds": "top-level SSZ Merkleization root",
     "sidecar_roundtrip_seconds": "one sidecar command round-trip",
+    "port_verdict_batch_size": "verdicts per validate_messages frame (sum/count = verdicts per sidecar round trip)",
     "device_live_arrays": "live device arrays (jax.live_arrays)",
     "device_plane_bytes": "retained PER-DEVICE bytes per accounted memory plane (sharded=1 planes divide their logical total by the live mesh spread; unattributed = jax.live_arrays() total minus the live-array planes; host/executable planes report outside that arithmetic)",
     "device_plane_bytes_watermark": "high watermark of total live device bytes",

@@ -9,6 +9,7 @@ degraded-latch edge accounting the storm scenario asserts.
 """
 
 import asyncio
+import contextlib
 
 import pytest
 
@@ -98,6 +99,10 @@ class _FakePort:
 
     async def validate_message(self, msg_id, verdict):
         self.verdicts.append((msg_id, verdict))
+
+    @contextlib.asynccontextmanager
+    async def verdict_batch(self):
+        yield  # this double books a verdict as it is handed over
 
     async def publish(self, topic, payload):
         self.published.append((topic, payload))

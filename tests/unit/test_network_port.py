@@ -2,14 +2,20 @@
 (mirror of the reference's test/unit/libp2p_port_test.exs:30-50)."""
 
 import asyncio
+import importlib.util
 
 import pytest
 
 from lambda_ethereum_consensus_tpu.network import Port
 from lambda_ethereum_consensus_tpu.network.port import (
     VERDICT_ACCEPT,
+    VERDICT_IGNORE,
     VERDICT_REJECT,
+    PortCommandError,
+    PortError,
 )
+from lambda_ethereum_consensus_tpu.network.proto import port_pb2
+from lambda_ethereum_consensus_tpu.telemetry import get_metrics
 
 
 def run(coro):
@@ -395,5 +401,297 @@ def test_early_peer_events_replay_preserves_cross_kind_order():
         assert seen == ["new"]
         port.on_peer_gone = lambda pid: seen.append("gone")
         assert seen == ["new", "gone", "new"]  # the peer ends CONNECTED
+
+    run(main())
+
+
+# ------------------- ISSUE 26: one sidecar frame for a drain's verdicts
+
+needs_libp2p_wire = pytest.mark.skipif(
+    importlib.util.find_spec("cryptography") is None,
+    reason="libp2p-wire sidecar needs the optional 'cryptography' module",
+)
+WIRES = [None, pytest.param("libp2p", marks=needs_libp2p_wire)]
+BATCH_TOPIC = "/eth2/bba4da96/beacon_aggregate_and_proof/ssz_snappy"
+
+
+def _roundtrips(command: str) -> int:
+    return sum(
+        row[4]
+        for row in get_metrics().histogram_series("sidecar_roundtrip_seconds")
+        if dict(row[0]).get("command") == command
+    )
+
+
+def _batch_sizes() -> tuple[float, int]:
+    rows = get_metrics().histogram_series("port_verdict_batch_size")
+    return sum(r[3] for r in rows), sum(r[4] for r in rows)
+
+
+async def _star(wire, n_senders):
+    """Hub B with ``n_senders`` peers publishing into it and one peer C
+    downstream of it; nobody else is connected to anybody (no peer
+    exchange), so what reaches C went through B's verdict.  On the
+    libp2p wire a peer's next message waits for the verdict of its last
+    (the validator blocks the peer's read loop), hence one message per
+    sender for several validations pending at once."""
+    kw = dict(wire=wire, fork_digest=b"\xba\xa4\xda\x96", enable_peer_exchange=False)
+    b = await Port.start(**kw)
+    c = await Port.start(**kw)
+    senders = [await Port.start(**kw) for _ in range(n_senders)]
+    for port in [c, *senders]:
+        await port.add_peer(f"127.0.0.1:{b.listen_port}")
+    return b, c, senders
+
+
+@pytest.mark.parametrize("wire", WIRES)
+def test_verdict_batch_is_one_frame_and_resolves_each_pending_validation(wire):
+    """A batch of N verdicts against a real sidecar child: one
+    ``sidecar_roundtrip``, one frame on the pipe; every pending
+    validation resolves with its OWN verdict (ACCEPT forwards and
+    rewards, REJECT downscores and never forwards, IGNORE drops);
+    unknown and duplicate ids are harmless; the same batch re-sent (a
+    retry whose first Result was lost) changes nothing; an empty batch
+    writes nothing."""
+
+    async def main():
+        m = get_metrics()
+        was = m.enabled
+        m.set_enabled(True)
+        b, c, senders = await _star(wire, 3)
+        try:
+            pending: dict[bytes, bytes] = {}  # payload -> msg_id, at B
+            all_pending = asyncio.Event()
+            at_c: list[bytes] = []
+
+            async def on_b(topic, msg_id, payload, from_peer):
+                pending[payload] = msg_id  # no verdict yet: the batch gives it
+                if len(pending) == 3:
+                    all_pending.set()
+
+            async def on_c(topic, msg_id, payload, from_peer):
+                # no verdict from C: the process-wide histograms read
+                # below must hold B's one frame and nothing else
+                at_c.append(payload)
+
+            await b.subscribe(BATCH_TOPIC, on_b)
+            await c.subscribe(BATCH_TOPIC, on_c)
+            for s in senders:
+                await s.subscribe(BATCH_TOPIC, lambda *a: None)
+            await asyncio.sleep(1.5)  # heartbeats: subscriptions spread, meshes graft
+            bodies = [b"accept-me", b"reject-me", b"ignore-me"]
+            for s, body in zip(senders, bodies):
+                await s.publish(BATCH_TOPIC, body)
+            await asyncio.wait_for(all_pending.wait(), 10)
+
+            async def scores():
+                peers = (await b.get_gossip_stats())["peers"]
+                return [peers[s.node_id.hex()]["score"] for s in senders]
+
+            before = await scores()
+            frames = []
+            write = b._proc.stdin.write
+
+            def counting_write(data):
+                frames.append(len(data))
+                write(data)
+
+            b._proc.stdin.write = counting_write
+            trips, sizes, minted = (
+                _roundtrips("validate_messages"), _batch_sizes(), b._counter
+            )
+            async with b.verdict_batch():
+                await b.validate_message(b"never-seen", VERDICT_ACCEPT)
+                await b.validate_message(pending[b"accept-me"], VERDICT_ACCEPT)
+                await b.validate_message(pending[b"reject-me"], VERDICT_REJECT)
+                await b.validate_message(pending[b"ignore-me"], VERDICT_IGNORE)
+                # a second verdict for an id the batch already answered
+                await b.validate_message(pending[b"accept-me"], VERDICT_REJECT)
+                assert frames == []  # staged: nothing written inside the bracket
+            assert len(frames) == 1
+            assert b._counter == minted + 1
+            assert _roundtrips("validate_messages") == trips + 1
+            got = _batch_sizes()
+            assert (got[0] - sizes[0], got[1] - sizes[1]) == (5, 1)
+            t0 = asyncio.get_running_loop().time()
+            while not at_c and asyncio.get_running_loop().time() - t0 < 10:
+                await asyncio.sleep(0.05)
+            await asyncio.sleep(0.5)  # anything else B forwarded would be here by now
+            assert at_c == [b"accept-me"]
+            after = await scores()
+            assert after[0] > before[0]  # ACCEPT rewards its sender
+            # REJECT downscores its own sender, once: the duplicate REJECT
+            # of the accepted id found nothing pending
+            assert before[1] - 41 < after[1] < before[1] - 39
+            assert abs(after[2] - before[2]) < 0.5  # IGNORE: neither
+
+            # the same batch again, as _command's retry would re-send it
+            await b._send_verdicts([
+                (b"never-seen", VERDICT_ACCEPT),
+                (pending[b"accept-me"], VERDICT_ACCEPT),
+                (pending[b"reject-me"], VERDICT_REJECT),
+                (pending[b"ignore-me"], VERDICT_IGNORE),
+                (pending[b"accept-me"], VERDICT_REJECT),
+            ])
+            await asyncio.sleep(0.5)
+            assert at_c == [b"accept-me"]
+            again = await scores()
+            assert abs(again[1] - after[1]) < 0.5 and again[0] >= before[0]
+
+            # an empty batch writes nothing
+            n_frames, minted = len(frames), b._counter
+            async with b.verdict_batch():
+                pass
+            await b._send_verdicts([])
+            assert (len(frames), b._counter) == (n_frames, minted)
+        finally:
+            m.set_enabled(was)
+            for port in [b, c, *senders]:
+                await port.close()
+
+    run(main())
+
+
+def test_verdict_batch_retry_resends_the_same_frame():
+    """``_command``'s retry rules hold for the batch: one transient
+    failure is retried away with the same verdicts in the same order
+    (the sidecar pops a pending entry the first time, so the second
+    application changes nothing), counted on
+    ``port_retry_total{command="validate_messages"}``."""
+
+    async def main():
+        m = get_metrics()
+        m.set_enabled(True)
+        before = m.get("port_retry_total", command="validate_messages")
+        port = _stub_port()
+        attempts = []
+
+        async def flaky(cmd, timeout):
+            attempts.append([
+                (v.msg_id, v.verdict) for v in cmd.validate_messages.verdicts
+            ])
+            if len(attempts) == 1:
+                raise PortError("transient sidecar hiccup")
+            return port_pb2.Result(ok=True)
+
+        port._roundtrip = flaky
+        batch = [(b"a", VERDICT_ACCEPT), (b"b", VERDICT_REJECT), (b"c", VERDICT_IGNORE)]
+        async with port.verdict_batch():
+            for msg_id, verdict in batch:
+                await port.validate_message(msg_id, verdict)
+        assert attempts == [batch, batch]
+        assert m.get("port_retry_total", command="validate_messages") == before + 1
+        # a verdict outside a bracket: a batch of one through the same path
+        await port.validate_message(b"solo", VERDICT_IGNORE)
+        assert attempts[-1] == [(b"solo", VERDICT_IGNORE)]
+
+    run(main())
+
+
+def test_verdict_batch_refused_by_the_sidecar_raises_out_of_the_bracket():
+    """``ok = false`` on the batch's Result is a ``PortCommandError`` at
+    the bracket's exit — never retried, and the drain that staged the
+    batch sees it."""
+
+    async def main():
+        port = _stub_port()
+        attempts = []
+
+        async def refused(cmd, timeout):
+            attempts.append(1)
+            raise PortCommandError("ValueError: first error of the batch")
+
+        port._roundtrip = refused
+        with pytest.raises(PortCommandError, match="first error"):
+            async with port.verdict_batch():
+                await port.validate_message(b"a", VERDICT_ACCEPT)
+        assert attempts == [1]
+        assert port._staged_verdicts == {}
+
+    run(main())
+
+
+class _Captured(list):
+    async def __call__(self, cmd_id, ok, payload=b"", error=""):
+        self.append((cmd_id, ok, error))
+
+
+def _batch_command(entries) -> "port_pb2.Command":
+    cmd = port_pb2.Command(id=b"\x00" * 7 + b"\x07")
+    for msg_id, verdict in entries:
+        cmd.validate_messages.verdicts.add(msg_id=msg_id, verdict=verdict)
+    return cmd
+
+
+def test_one_raising_verdict_does_not_stop_the_rest_bespoke_sidecar(monkeypatch):
+    """The bespoke sidecar's own command handler, in process: a verdict
+    whose application raises leaves the others applied, in order, and
+    the ONE Result says ``ok = false`` with the first error."""
+    from lambda_ethereum_consensus_tpu.network.sidecar import Sidecar
+
+    monkeypatch.setenv("SIDECAR_PLAINTEXT", "1")
+    monkeypatch.delenv("SIDECAR_KEY_FILE", raising=False)
+
+    async def main():
+        sc = Sidecar()
+        sc.result = results = _Captured()
+        gone = b"\x11" * 32  # a sender that already disconnected
+        sc.pending_validation[b"m1"] = ("/t", b"p1", gone, None)
+        sc.pending_validation[b"m2"] = ("not", "a pending entry")  # raises
+        sc.pending_validation[b"m3"] = ("/t", b"p3", gone, None)
+        sc.pending_validation[b"m4"] = ("also", "broken")  # raises too
+        await sc.handle_command(_batch_command([
+            (b"m1", VERDICT_REJECT), (b"m2", VERDICT_ACCEPT),
+            (b"m3", VERDICT_REJECT), (b"m4", VERDICT_ACCEPT),
+        ]))
+        assert not sc.pending_validation  # every entry was taken up
+        # both REJECTs after-and-before the raising ones were applied
+        assert sc.ban_scores[gone] == -80.0
+        assert len(results) == 1
+        cmd_id, ok, error = results[0]
+        assert cmd_id == b"\x00" * 7 + b"\x07" and ok is False
+        assert error.startswith("ValueError")
+        # the single-verdict command is still served
+        single = port_pb2.Command(id=b"\x00" * 7 + b"\x08")
+        single.validate_message.msg_id = b"unknown"
+        await sc.handle_command(single)
+        assert results[1] == (b"\x00" * 7 + b"\x08", True, "")
+
+    run(main())
+
+
+@needs_libp2p_wire
+def test_one_raising_verdict_does_not_stop_the_rest_libp2p_sidecar(monkeypatch):
+    """The libp2p sidecar's twin of the test above."""
+    from lambda_ethereum_consensus_tpu.network.libp2p import gossipsub
+    from lambda_ethereum_consensus_tpu.network.sidecar_libp2p import Libp2pSidecar
+
+    monkeypatch.delenv("SIDECAR_KEY_FILE", raising=False)
+
+    async def main():
+        sc = Libp2pSidecar()
+        sc.result = results = _Captured()
+        loop = asyncio.get_running_loop()
+        futs = {m: loop.create_future() for m in (b"m1", b"m3", b"m4")}
+        sc.pending_validation[b"m1"] = futs[b"m1"]
+        sc.pending_validation[b"m2"] = object()  # not a future: raises
+        sc.pending_validation[b"m3"] = futs[b"m3"]
+        sc.pending_validation[b"m4"] = futs[b"m4"]
+        await sc.handle_command(_batch_command([
+            (b"m1", VERDICT_ACCEPT), (b"m2", VERDICT_ACCEPT),
+            (b"m3", VERDICT_REJECT), (b"unknown", VERDICT_ACCEPT),
+            (b"m4", VERDICT_IGNORE), (b"m1", VERDICT_REJECT),
+        ]))
+        assert not sc.pending_validation
+        assert [futs[m].result() for m in (b"m1", b"m3", b"m4")] == [
+            gossipsub.ACCEPT, gossipsub.REJECT, gossipsub.IGNORE,
+        ]
+        assert len(results) == 1
+        _, ok, error = results[0]
+        assert ok is False and error.startswith("AttributeError")
+        single = port_pb2.Command(id=b"\x00" * 7 + b"\x08")
+        single.validate_message.msg_id = b"unknown"
+        await sc.handle_command(single)
+        assert results[1] == (b"\x00" * 7 + b"\x08", True, "")
 
     run(main())

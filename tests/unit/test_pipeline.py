@@ -8,6 +8,7 @@ first.
 """
 
 import asyncio
+import contextlib
 import time
 
 import pytest
@@ -540,6 +541,10 @@ class FakePort:
 
     async def validate_message(self, msg_id, verdict):
         self.verdicts.append((msg_id, verdict))
+
+    @contextlib.asynccontextmanager
+    async def verdict_batch(self):
+        yield  # this double books a verdict as it is handed over
 
 
 def test_gossip_queue_full_drop_is_counted():

@@ -3,6 +3,7 @@ per-item trace threading through the ingest pipeline, and the batched
 verify fan-in links (ISSUE 4 tentpole + satellites)."""
 
 import asyncio
+import contextlib
 import json
 import time
 
@@ -279,6 +280,10 @@ class FakePort:
 
     async def validate_message(self, msg_id, verdict):
         self.verdicts.append((msg_id, verdict))
+
+    @contextlib.asynccontextmanager
+    async def verdict_batch(self):
+        yield  # this double books a verdict as it is handed over
 
 
 def test_end_to_end_trace_admission_through_apply_with_shed():

@@ -22,6 +22,13 @@ Round 22 additions (fleet observatory):
   per-(peer, topic) delivery counters, peer scores and mesh/control
   stats, returned as a JSON payload.
 
+PR 26 addition (one sidecar frame for a drain's verdicts):
+
+- ``consensus_port.ValidateMessages`` (``repeated ValidateMessage
+  verdicts = 1``) + ``Command.validate_messages = 13``: a gossip drain
+  hands the sidecar all its verdicts in one command frame and awaits one
+  ``Result``.
+
 Usage: ``python tools/gen_proto.py`` (writes in place, prints a diffstat
 line per file).
 """
@@ -72,13 +79,16 @@ def _has_field(msg, name: str) -> bool:
     return any(f.name == name for f in msg.field)
 
 
-def _add_field(msg, name, number, ftype, type_name=None, oneof_index=None):
+def _add_field(
+    msg, name, number, ftype, type_name=None, oneof_index=None,
+    label=F.LABEL_OPTIONAL,
+):
     if _has_field(msg, name):
         return False
     f = msg.field.add()
     f.name = name
     f.number = number
-    f.label = F.LABEL_OPTIONAL
+    f.label = label
     f.type = ftype
     if type_name is not None:
         f.type_name = type_name
@@ -130,6 +140,18 @@ def mutate_port(fdp) -> bool:
     changed |= _add_field(
         cmd, "get_gossip_stats", 12,
         F.TYPE_MESSAGE, f".{fdp.package}.GetGossipStats", oneof_index=0,
+    )
+    if _message(fdp, "ValidateMessages") is None:
+        fdp.message_type.add().name = "ValidateMessages"
+        changed = True
+    changed |= _add_field(
+        _message(fdp, "ValidateMessages"), "verdicts", 1,
+        F.TYPE_MESSAGE, f".{fdp.package}.ValidateMessage",
+        label=F.LABEL_REPEATED,
+    )
+    changed |= _add_field(
+        cmd, "validate_messages", 13,
+        F.TYPE_MESSAGE, f".{fdp.package}.ValidateMessages", oneof_index=0,
     )
     return changed
 
