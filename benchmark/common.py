@@ -28,6 +28,13 @@ def expect(cond, what: str) -> None:
         raise BenchFailure(what)
 
 
+def hold(compared: dict, name: str, over, what: str) -> None:
+    """An exact comparison: book ``over`` (how far the run is off) beside its
+    limit 0 among the numbers compared, then hold the run to it."""
+    compared[name] = [over, 0]
+    expect(over == 0, what)
+
+
 def note(**fields) -> None:
     """One earlier line of standard output (never the last one)."""
     print(json.dumps({"note": fields}, default=str), flush=True)

@@ -1,10 +1,12 @@
 #!/usr/bin/env python3
-"""benchmark/hostside.py — the plain reference, in a process of its own.
+"""benchmark/hostside.py — the traffic's makers on the program's host path, in
+processes of their own.
 
 A child of ``run.py`` that never imports JAX and never touches the chip:
 BLS on the native host library, ``hashlib`` Merkleization, the non-resident
 transition.  It makes the traffic from the seed and keeps the truth each
-item is held to.  Two roles:
+item is held to.  It imports the program, so it is a second path of it and
+not a plain reference: block import is held to ``plainref.py``.  Two roles:
 
 ``lineage``  builds the seeded genesis state, roots it with ``hashlib`` and,
              on request, builds signed blocks on the host's own lineage of
@@ -35,7 +37,7 @@ import time
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
 
-# the plain reference's routing, for the whole life of this process
+# the host path's routing, for the whole life of this process
 HOST_ENV = {"JAX_PLATFORMS": "cpu", "BLS_NO_DEVICE": "1", "GRAFT_RESIDENT_EPOCH": "0"}
 
 
@@ -423,17 +425,27 @@ def build_blocks(spec, keys: Keys, view, cmd: dict, seed: int):
         signed, view = build_signed_block(
             pre, slot, keys, attestations=atts, spec=spec,
             sync_secret_keys=keys.sync_keys())
+        ssz = signed.encode(spec)
         yield {
             "kind": "block", "role": role, "slot": slot,
             "root": signed.message.hash_tree_root(spec),
-            "wire": compress(signed.encode(spec)),
+            "wire": compress(ssz), "ssz": ssz,
             "attestations": len(atts),
             "sync_members": int(spec.SYNC_COMMITTEE_SIZE),
             "post_state_root": bytes(signed.message.state_root),
             "build_s": time.perf_counter() - t0,
         }
-    yield {"kind": "lineage", "post_state_root": state_root(view, spec),
-           "slot": int(view.slot)}
+        if slot == cmd.get("prestate_after"):
+            # what the plain reference (plainref.py) starts from: this
+            # lineage's state under the first block it follows, as SSZ
+            t0 = time.perf_counter()
+            yield {"kind": "prestate", "slot": slot, "ssz": view.encode(spec),
+                   "encode_s": time.perf_counter() - t0}
+    last = {"kind": "lineage", "post_state_root": state_root(view, spec),
+            "slot": int(view.slot)}
+    if cmd.get("poststate"):  # tests only: the lineage's last state, as SSZ
+        last["ssz"] = view.encode(spec)
+    yield last
 
 
 def run_lineage(args, cfg: dict, mix: dict) -> None:

@@ -180,7 +180,12 @@ def main(argv=None) -> int:
     except BenchFailure as e:
         print(f"benchmark: FAILED: {e}", file=sys.stderr)
         result = {"correct": False, "attempted": 0, "failed": 0, "metrics": {},
-                  "device": {**device, "memory_peak_bytes": 0}, "why": str(e)}
+                  "device": {**device, "memory_peak_bytes": 0}, "why": str(e),
+                  "compared": getattr(e, "compared", {})}
+    # each number compared beside its limit: the last lines of standard error
+    for name, (value, limit) in result.get("compared", {}).items():
+        print(f"benchmark: compared {name} = {value} (limit {limit})", file=sys.stderr)
+    print(f"benchmark: correct = {result['correct']}", file=sys.stderr, flush=True)
     print(json.dumps(result), flush=True)
     return 0
 

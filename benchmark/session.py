@@ -21,8 +21,8 @@ import time
 import hostside
 import tracered
 from common import (
-    HERE, ROOT, CompileClock, Worker, counter_total, expect,
-    histogram_totals, load_json, note,
+    HERE, ROOT, BenchFailure, CompileClock, Worker, counter_total, expect,
+    histogram_totals, hold, load_json, note,
 )
 
 ERROR_COUNTERS = ("gossip_batch_error_count", "aot_errors_total", "device_fault_total")
@@ -182,6 +182,8 @@ class Context:
         self.node = self.store = self.anchor = None
         self.verdicts: dict[bytes, tuple[int, float]] = {}
         self.setup_split: dict[str, float] = {}
+        # every number `correct` compares, beside its limit: {name: [value, limit]}
+        self.compared: dict[str, list] = {}
         self.config_path = os.path.join(ROOT, self.config_entry()["file"])
         self.traffic_path = os.path.join(HERE, "traffic", cell["traffic"] + ".json")
 
@@ -278,10 +280,11 @@ def no_quiet_fallback(ctx: Context) -> dict:
     from lambda_ethereum_consensus_tpu.ops import aot
 
     fault = telemetry.device_fault_state()
-    expect(not fault["faulted"], f"device fault latched: {fault}")
+    hold(ctx.compared, "device_fault_latched", int(bool(fault["faulted"])),
+         f"device fault latched: {fault}")
     for name in ERROR_COUNTERS:
         total = counter_total(name, *ctx.registries())
-        expect(total == 0, f"{name} = {total}")
+        hold(ctx.compared, name, total, f"{name} = {total}")
     rows = aot.compile_profile()
     expect(all(r["source"] in ("disk", "compile") for r in rows),
            "an AOT row was neither loaded nor compiled")
@@ -373,7 +376,12 @@ async def run(args, bench: dict, cell: dict, cfg: dict, mix: dict, t_process: fl
                     m["value"] = None
             if facts["failed"]:
                 result["correct"] = False
+            ctx.compared["failed"] = [facts["failed"], 0]
+            result["compared"] = ctx.compared  # last in the line
             return result
+    except BenchFailure as e:
+        e.compared = ctx.compared  # what was compared before the run failed
+        raise
     finally:
         for w in ctx.workers:
             w.close()
