@@ -516,9 +516,11 @@ def _attestation_batch_cached(
 
     Per item: fork-choice validation + numpy participation split + signing
     root; then ONE ``batch_verify_each_cached`` chain per target context
-    (aggregate pubkeys never touch the host).  Entries whose missing-member
-    count exceeds the cache's correction capacity fall back to the host
-    aggregate path within the same call.  Accepted votes apply through the
+    (aggregate pubkeys never touch the host).  An entry with exactly one
+    attester goes as ``(validator_index, None, ...)``: the chain gathers
+    its pubkey from the device registry planes.  Other entries whose
+    missing-member count exceeds the cache's correction capacity fall back
+    to the host aggregate path within the same call.  Accepted votes apply through the
     vectorized batch updater.
     """
     import numpy as np
@@ -581,7 +583,13 @@ def _attestation_batch_cached(
             if sig_pt is None:
                 raise ForkChoiceError("infinity signature", reject=True)
             cache = ctx.device_cache()
-            if len(missing) <= cache.mmax:
+            if len(attesting) == 1:
+                # an unaggregated vote: its pubkey is one registry column,
+                # gathered on the device by validator index
+                entry = (int(attesting[0]), None, signing_root, sig_pt)
+                by_ctx.setdefault(id(ctx), []).append((i, attestation, attesting, entry))
+                ctxs[id(ctx)] = ctx
+            elif len(missing) <= cache.mmax:
                 entry = (cid, missing.tolist(), signing_root, sig_pt)
                 by_ctx.setdefault(id(ctx), []).append((i, attestation, attesting, entry))
                 ctxs[id(ctx)] = ctx

@@ -63,6 +63,12 @@ log = logging.getLogger("node")
 _trace_dropped_exported = 0
 
 
+# attestation channels take deep batches: the device drain's fixed
+# dispatch cost amortizes across thousands of signatures, and one mainnet
+# slot already carries ~1k aggregates
+ATT_BATCH, ATT_QUEUE = 8192, 16384
+
+
 @dataclass(frozen=True)
 class TopicSpec:
     """One row of the fork-aware gossip topic table (round 23).
@@ -192,6 +198,7 @@ class BeaconNode:
         self.forensics = ConsensusForensics()
         self._tasks: list[asyncio.Task] = []
         self._subs: list[TopicSubscription] = []
+        self._lane_sinks: dict = {}  # sink method name -> the lane's one SharedLaneSink
         self.ingest: IngestScheduler | None = None
         self._stopping = False
         # durability plane (round 20): the finalized epoch whose snapshot
@@ -513,15 +520,7 @@ class BeaconNode:
             sub.cancel()
         self._subs.clear()
         digest = self.chain.fork_digest()
-        # dedupe: Port.subscribe is keyed by topic, so a duplicated id
-        # would orphan one drain loop and double-subscribe the sidecar
-        subnets = tuple(sorted(set(self.config.attnet_subnets)))
-        attnets = bytearray(8)  # SSZ Bitvector[64], little-endian bits
-        for i in subnets:
-            if not 0 <= i < 64:
-                # fail at startup, not inside the sidecar-restart loop
-                raise ValueError(f"attestation subnet id out of range: {i}")
-            attnets[i // 8] |= 1 << (i % 8)
+        attnets = self._attnets_bitfield()
         port = await Port.start(
             listen_addr=self.config.listen_addr,
             bootnodes=self.config.bootnodes,
@@ -529,7 +528,7 @@ class BeaconNode:
             # noise identity survives restarts: bans stay bound to the key
             key_file=self.config.db_path + ".sidecar_key",
             wire=self.config.wire,
-            attnets=bytes(attnets),
+            attnets=attnets,
             syncnets=b"\x00",
         )
         if self.config.port_wrapper is not None:
@@ -563,40 +562,110 @@ class BeaconNode:
         # sidecars) activate when the chain's CURRENT fork reaches them;
         # a sidecar restart after a fork transition picks up the new rows
         # (subscriptions are rebuilt here on every (re)start).
-        from ..network.gossip import SharedLaneSink
-
-        import functools
-
         epoch = int(self.store.current_slot(self.spec)) // int(
             self.spec.SLOTS_PER_EPOCH
         )
         active_fork = FORK_ORDER.index(self.spec.fork_at_epoch(epoch))
-        sinks: dict[str, SharedLaneSink] = {}
+        self._lane_sinks = {}  # sinks bound to the dead scheduler go with it
         for ts in self._topic_table():
             if FORK_ORDER.index(ts.since_fork) > active_fork:
                 continue
-            handler = getattr(self, ts.handler)
-            if ts.subnet is not None:
-                handler = functools.partial(handler, ts.subnet)
-            sink = None
-            if sched is not None and ts.sink is not None:
-                # one sink per lane: a flush spanning N subnet topics is
-                # ONE batched verify, not N per-topic fragments
-                sink = sinks.get(ts.sink)
-                if sink is None:
-                    sink = sinks[ts.sink] = SharedLaneSink(
-                        getattr(self, ts.sink), label=f"{ts.lane}_lane"
-                    )
-            sub = TopicSubscription(
-                self.port, topic_name(digest, ts.name), handler,
-                ssz_type=ts.ssz_type, spec=self.spec,
-                max_batch=ts.max_batch, max_queue=ts.max_queue,
-                metrics=self.metrics,
-                scheduler=sched, lane=ts.lane if sched else None,
-                sink=sink, node=self.config.node_label,
-            )
-            await sub.start()
-            self._subs.append(sub)
+            await self._subscribe_row(ts)
+
+    async def _subscribe_row(self, ts: TopicSpec) -> None:
+        """Join one row of the topic table: the subscription, its lane
+        and (for a lane-shared row) the lane's one sink — what
+        ``_start_network`` does per row and what a run-time subnet
+        subscription does for the rows it adds."""
+        import functools
+
+        from ..network.gossip import SharedLaneSink
+
+        sched = self.ingest
+        handler = getattr(self, ts.handler)
+        if ts.subnet is not None:
+            handler = functools.partial(handler, ts.subnet)
+        sink = None
+        if sched is not None and ts.sink is not None:
+            # one sink per lane: a flush spanning N subnet topics is
+            # ONE batched verify, not N per-topic fragments
+            sink = self._lane_sinks.get(ts.sink)
+            if sink is None:
+                sink = self._lane_sinks[ts.sink] = SharedLaneSink(
+                    getattr(self, ts.sink), label=f"{ts.lane}_lane"
+                )
+        sub = TopicSubscription(
+            self.port, topic_name(self.chain.fork_digest(), ts.name), handler,
+            ssz_type=ts.ssz_type, spec=self.spec,
+            max_batch=ts.max_batch, max_queue=ts.max_queue,
+            metrics=self.metrics,
+            scheduler=sched, lane=ts.lane if sched else None,
+            sink=sink, node=self.config.node_label,
+        )
+        await sub.start()
+        self._subs.append(sub)
+
+    def _attnet_subnet_ids(self) -> tuple[int, ...]:
+        """The subscribed attestation subnets, deduped (``Port.subscribe``
+        is keyed by topic: a duplicated id would orphan one drain loop and
+        double-subscribe the sidecar) and range-checked — at startup, not
+        inside the sidecar-restart loop."""
+        subnets = tuple(sorted({int(i) for i in self.config.attnet_subnets}))
+        for i in subnets:
+            if not 0 <= i < constants.ATTESTATION_SUBNET_COUNT:
+                raise ValueError(f"attestation subnet id out of range: {i}")
+        return subnets
+
+    def _attnets_bitfield(self) -> bytes:
+        """ENR ``attnets``: SSZ Bitvector[64], little-endian bits."""
+        attnets = bytearray(constants.ATTESTATION_SUBNET_COUNT // 8)
+        for i in self._attnet_subnet_ids():
+            attnets[i // 8] |= 1 << (i % 8)
+        return bytes(attnets)
+
+    def _subnet_topic_row(self, i: int) -> TopicSpec:
+        """``beacon_attestation_{i}``: unaggregated votes, drained through
+        the SAME batched-RLC verify as aggregates — and, under the
+        scheduler, one SHARED lane: a flood on any subnet competes with
+        the other subnets, never with blocks."""
+        from ..types.beacon import Attestation
+
+        return TopicSpec(
+            name=f"beacon_attestation_{i}", ssz_type=Attestation,
+            handler="_on_attestation_batch", lane="subnet",
+            max_batch=ATT_BATCH, max_queue=ATT_QUEUE,
+            sink="_on_subnet_sink_batch", subnet=i,
+        )
+
+    async def set_attestation_subnets(self, subnets) -> None:
+        """Change the subscribed attestation subnets of a running node
+        (what the Beacon API's ``beacon_committee_subscriptions`` asks):
+        drops the ``beacon_attestation_{i}`` subscriptions no longer
+        wanted, adds the new ones through the same table rows
+        ``_start_network`` uses, and sizes the subnet lane to the new
+        subscription.  It updates ``config.attnet_subnets`` — what
+        ``_start_network`` reads — so ``--attnets`` at start and this call
+        end in the same subscriptions, and a sidecar restart keeps them
+        (the ``attnets`` bitfield reaches the sidecar's ENR at its start:
+        the port has no command that rewrites it on a live sidecar)."""
+        before = set(self._attnet_subnet_ids())
+        previous = self.config.attnet_subnets
+        self.config.attnet_subnets = tuple(subnets)
+        try:
+            wanted = set(self._attnet_subnet_ids())
+        except ValueError:
+            self.config.attnet_subnets = previous
+            raise
+        if self.port is None:
+            return  # not started: _start_network will read the config
+        drop = {self._subnet_topic_row(i).name for i in before - wanted}
+        for sub in [s for s in self._subs if s.topic_label in drop]:
+            await sub.stop()
+            self._subs.remove(sub)
+        if self.ingest is not None:  # the live lane and budget follow
+            self.ingest.resize_lane("subnet", *self._subnet_lane_bounds())
+        for i in sorted(wanted - before):
+            await self._subscribe_row(self._subnet_topic_row(i))
 
     def _blob_subnet_ids(self) -> tuple[int, ...]:
         count = int(self.spec.get("BLOB_SIDECAR_SUBNET_COUNT", 6))
@@ -612,13 +681,8 @@ class BeaconNode:
     def _topic_table(self) -> list[TopicSpec]:
         """The fork-aware gossip surface.  Forks append rows; nothing
         else about subscription wiring changes per fork."""
-        from ..types.beacon import Attestation
         from ..types.deneb import BlobSidecar
 
-        # attestation channels take deep batches: the device drain's
-        # fixed dispatch cost amortizes across thousands of signatures,
-        # and one mainnet slot already carries ~1k aggregates
-        ATT_BATCH, ATT_QUEUE = 8192, 16384
         table = [
             TopicSpec(
                 name="beacon_block", ssz_type=SignedBeaconBlock,
@@ -631,17 +695,9 @@ class BeaconNode:
                 max_batch=ATT_BATCH, max_queue=ATT_QUEUE,
             ),
         ]
-        # attestation subnets: unaggregated votes, one topic per subnet,
-        # drained through the SAME batched-RLC verify as aggregates —
-        # and, under the scheduler, one SHARED lane: a flood on any
-        # subnet competes with the other subnets, never with blocks
-        for i in sorted(set(self.config.attnet_subnets)):
-            table.append(TopicSpec(
-                name=f"beacon_attestation_{i}", ssz_type=Attestation,
-                handler="_on_attestation_batch", lane="subnet",
-                max_batch=ATT_BATCH, max_queue=ATT_QUEUE,
-                sink="_on_subnet_sink_batch", subnet=i,
-            ))
+        # attestation subnets: one topic per subscribed subnet
+        for i in self._attnet_subnet_ids():
+            table.append(self._subnet_topic_row(i))
         # deneb blob sidecars: one topic per sampled column, one shared
         # lane — a flush verifies in a single RLC-folded pairing check
         for i in self._blob_subnet_ids():
@@ -663,10 +719,9 @@ class BeaconNode:
         snap flush sizes to the AOT-warmed shape buckets."""
         cfg = self.config
         att_deadline = cfg.ingest_attestation_deadline_ms / 1000.0
-        att_target = min(attestation_batch_target(), 8192)
-        sched = IngestScheduler(
-            metrics=self.metrics, max_items=self.config.ingest_max_items
-        )
+        att_target = min(attestation_batch_target(), ATT_BATCH)
+        subnet_queue, max_items = self._subnet_lane_bounds()
+        sched = IngestScheduler(metrics=self.metrics, max_items=max_items)
         sched.add_lane(LaneConfig(
             name="block", priority=0, weight=64, max_batch=64, max_queue=1024,
             deadline_s=cfg.ingest_block_deadline_ms / 1000.0, coalesce_target=1,
@@ -688,13 +743,13 @@ class BeaconNode:
             shed_newest=True,
         ))
         sched.add_lane(LaneConfig(
-            name="aggregate", priority=2, weight=4096, max_batch=8192,
-            max_queue=16384, deadline_s=att_deadline,
+            name="aggregate", priority=2, weight=4096, max_batch=ATT_BATCH,
+            max_queue=ATT_QUEUE, deadline_s=att_deadline,
             coalesce_target=att_target, shape_kind="attestation_entries",
         ))
         sched.add_lane(LaneConfig(
-            name="subnet", priority=3, weight=4096, max_batch=8192,
-            max_queue=16384, deadline_s=att_deadline,
+            name="subnet", priority=3, weight=4096, max_batch=ATT_BATCH,
+            max_queue=subnet_queue, deadline_s=att_deadline,
             coalesce_target=att_target, shape_kind="attestation_entries",
         ))
         # catch-all for non-core topics (sync committees, slashings, BLS
@@ -705,6 +760,35 @@ class BeaconNode:
             deadline_s=0.2, coalesce_target=16,
         ))
         return sched
+
+    def _subnet_lane_bounds(self) -> tuple[int, int]:
+        """``(subnet lane capacity, scheduler budget)`` for the current
+        subscription: one slot's unaggregated votes of every subscribed
+        subnet must fit — the committees a slot lands on the subscribed
+        subnets times the committee size the justified checkpoint state
+        gives — so that a valid first-seen vote is never shed while the
+        lane is the only one loaded.  Never under the aggregate lane's
+        depth; the budget keeps its distance below the sum of the lane
+        caps (``NodeConfig.ingest_max_items``) and grows by what the lane
+        grew, so the cross-lane shed policy engages as before."""
+        from ..state_transition import accessors
+        from ..state_transition.mutable import BeaconStateMut
+
+        spec = self.spec
+        queue = ATT_QUEUE
+        state = self.store.block_states.get(
+            bytes(self.store.justified_checkpoint.root)
+        )
+        if state is not None:
+            epoch = misc.compute_epoch_at_slot(self.store.current_slot(spec), spec)
+            ws = BeaconStateMut(state)
+            cps = accessors.get_committee_count_per_slot(ws, epoch, spec)
+            slots = int(spec.SLOTS_PER_EPOCH)
+            committee = -(-len(ws.active_indices(epoch)) // (cps * slots))
+            per_subnet = -(-cps // constants.ATTESTATION_SUBNET_COUNT)
+            committees = min(cps, len(self._attnet_subnet_ids()) * per_subnet)
+            queue = max(queue, committees * committee)
+        return queue, self.config.ingest_max_items + queue - ATT_QUEUE
 
     # ------------------------------------------------------------- handlers
 
@@ -986,64 +1070,67 @@ class BeaconNode:
         verdicts: list[int | None] = [None] * len(tagged)
         passed, passed_pos, passed_keys = [], [], []
         batch_keys: set = set()  # dedupe same-validator cells WITHIN the batch
-        for pos, (subnet, msg) in enumerate(tagged):
-            att = msg.value
-            bits = att.aggregation_bits
-            if bits.count() != 1:
-                verdicts[pos] = VERDICT_REJECT
-                continue
-            cps_auth = self._committees_per_slot_at(att.data.target)
-            seed = None
-            if cps_auth is not None:
-                cps, authoritative, seed = cps_auth
-                if int(att.data.index) >= cps or compute_subnet_for_attestation(
-                    cps, int(att.data.slot), int(att.data.index), self.spec
-                ) != subnet:
-                    # approximate committee counts can mis-map honest
-                    # messages across a count boundary — only the real
-                    # checkpoint state justifies penalizing the sender
-                    verdicts[pos] = (
-                        VERDICT_REJECT if authoritative else VERDICT_IGNORE
+        # the p2p rules, once per flush (the batched verify below has its
+        # own spans)
+        with span("subnet_validate"):
+            for pos, (subnet, msg) in enumerate(tagged):
+                att = msg.value
+                bits = att.aggregation_bits
+                if bits.count() != 1:
+                    verdicts[pos] = VERDICT_REJECT
+                    continue
+                cps_auth = self._committees_per_slot_at(att.data.target)
+                seed = None
+                if cps_auth is not None:
+                    cps, authoritative, seed = cps_auth
+                    if int(att.data.index) >= cps or compute_subnet_for_attestation(
+                        cps, int(att.data.slot), int(att.data.index), self.spec
+                    ) != subnet:
+                        # approximate committee counts can mis-map honest
+                        # messages across a count boundary — only the real
+                        # checkpoint state justifies penalizing the sender
+                        verdicts[pos] = (
+                            VERDICT_REJECT if authoritative else VERDICT_IGNORE
+                        )
+                        continue
+                epoch = int(att.data.target.epoch)
+                tkey = (epoch, bytes(att.data.target.root))
+                hit = self._vote_cell_disc.get(tkey)
+                if hit is not None and hit[1]:
+                    disc = hit[0]  # seed-derived: sticky, keys never reflow
+                elif seed is not None:
+                    # first seed-based resolution (or an upgrade from the
+                    # provisional stand-in — no cells were recorded under it:
+                    # ACCEPT requires the target block, hence a seed source)
+                    disc = seed
+                    self._vote_cell_disc[tkey] = (seed, True)
+                else:
+                    # no state to derive the seed from yet: the target root is
+                    # the coarser stand-in (never merges distinct shufflings)
+                    disc = bytes(att.data.target.root)
+                    self._vote_cell_disc[tkey] = (disc, False)
+                key = (int(att.data.slot), int(att.data.index), bits.indices()[0], disc)
+                if (
+                    key in self._seen_subnet_votes.get(epoch, ())
+                    or (epoch, key) in batch_keys
+                ):
+                    verdicts[pos] = VERDICT_IGNORE
+                    # the IGNORE is correct for fork choice, but a duplicate
+                    # cell carrying a DIFFERENT head root is a double vote —
+                    # retained as ledger evidence instead of vanishing here
+                    self.forensics.note_vote(
+                        (epoch,) + key, bytes(att.data.beacon_block_root)
                     )
                     continue
-            epoch = int(att.data.target.epoch)
-            tkey = (epoch, bytes(att.data.target.root))
-            hit = self._vote_cell_disc.get(tkey)
-            if hit is not None and hit[1]:
-                disc = hit[0]  # seed-derived: sticky, keys never reflow
-            elif seed is not None:
-                # first seed-based resolution (or an upgrade from the
-                # provisional stand-in — no cells were recorded under it:
-                # ACCEPT requires the target block, hence a seed source)
-                disc = seed
-                self._vote_cell_disc[tkey] = (seed, True)
-            else:
-                # no state to derive the seed from yet: the target root is
-                # the coarser stand-in (never merges distinct shufflings)
-                disc = bytes(att.data.target.root)
-                self._vote_cell_disc[tkey] = (disc, False)
-            key = (int(att.data.slot), int(att.data.index), bits.indices()[0], disc)
-            if (
-                key in self._seen_subnet_votes.get(epoch, ())
-                or (epoch, key) in batch_keys
-            ):
-                verdicts[pos] = VERDICT_IGNORE
-                # the IGNORE is correct for fork choice, but a duplicate
-                # cell carrying a DIFFERENT head root is a double vote —
-                # retained as ledger evidence instead of vanishing here
+                batch_keys.add((epoch, key))
+                # first-seen root for the cell, recorded BEFORE the verify
+                # verdict lands so a same-batch twin still compares roots
                 self.forensics.note_vote(
                     (epoch,) + key, bytes(att.data.beacon_block_root)
                 )
-                continue
-            batch_keys.add((epoch, key))
-            # first-seen root for the cell, recorded BEFORE the verify
-            # verdict lands so a same-batch twin still compares roots
-            self.forensics.note_vote(
-                (epoch,) + key, bytes(att.data.beacon_block_root)
-            )
-            passed.append(msg)
-            passed_pos.append(pos)
-            passed_keys.append((epoch, key))
+                passed.append(msg)
+                passed_pos.append(pos)
+                passed_keys.append((epoch, key))
         if passed:
             inner = self._attestation_drain(
                 passed, lambda msg: msg.value, "beacon_attestation"
@@ -1064,6 +1151,10 @@ class BeaconNode:
                 k for k in self._vote_cell_disc if k[0] < current_epoch - 1
             ]:
                 del self._vote_cell_disc[tkey]
+            self.metrics.set_gauge(
+                "subnet_seen_votes",
+                sum(len(cells) for cells in self._seen_subnet_votes.values()),
+            )
         return verdicts
 
     def _on_applied(self, root: bytes, signed: SignedBeaconBlock) -> None:

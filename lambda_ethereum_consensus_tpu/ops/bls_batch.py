@@ -33,7 +33,7 @@ import numpy as np
 
 from ..crypto.bls import curve as C
 from ..crypto.bls.batch import _COEFF_BITS  # single soundness-width source
-from ..telemetry import span
+from ..telemetry import inc, span
 from . import bigint as BI
 from .bls_g1 import (
     _limbs_batch,
@@ -293,6 +293,24 @@ def make_chain_ops(interpret: bool = False):
         ax, ay = _norm_g1(X3, Y3, Z3)
         return ax, ay, inf3
 
+    def single_gather(rx, ry, idx):
+        """Per-entry pubkeys of single-signer entries: column ``idx[e]``
+        of the device registry planes, (32, E) affine.  No sum and no
+        correction — an unaggregated vote's pubkey IS its attester's
+        registry key, which already lies on the device."""
+        return jnp.take(rx, idx, axis=1), jnp.take(ry, idx, axis=1)
+
+    def single_merge(ax, ay, ainf, sx, sy, is_single):
+        """A mixed drain's pubkey planes: the gathered key where the
+        entry is a single signer, the corrected committee aggregate
+        elsewhere (registry keys are never the identity)."""
+        pick = is_single[None, :]
+        return (
+            jnp.where(pick, sx, ax),
+            jnp.where(pick, sy, ay),
+            ainf & ~is_single,
+        )
+
     def aggregate_g1(bx, by, inf):
         # operands arrive pow2-padded along the reduce axis (host side:
         # aggregate_g1_chain) so the jit cache is keyed on padded shapes;
@@ -311,6 +329,8 @@ def make_chain_ops(interpret: bool = False):
         "ladder_g2": wrap(ladder_g2, "ladder_g2"),
         "committee_sums": wrap(committee_sums, "committee_sums"),
         "agg_corrected": wrap(agg_corrected, "agg_corrected"),
+        "single_gather": wrap(single_gather, "single_gather"),
+        "single_merge": wrap(single_merge, "single_merge"),
         # host-composed (see comment above prep) — pieces are jitted
         "prep": prep,
         "finish": finish,
@@ -386,6 +406,7 @@ def chain_verify(
                 flat_sig.append(sig)
                 flat_coeff.append(coeff)
         n = len(flat_pk)
+        _count_entries(points=n)
         b, dead = _entry_budget(n, interpret)
 
         # Flat entry planes, padded with the generator at dead slots.
@@ -405,6 +426,15 @@ def chain_verify(
         jac2 = ops["ladder_g2"](sgx, sgy, kbits, live)
         ok = _dispatch_checks_tail(ops, jac1, jac2, checks, dead)
     return _fetch_flags(ok)
+
+
+def _count_entries(**by_shape: int) -> None:
+    """Book the entries entering a chained verify by the shape their
+    pubkeys take (``bls_chain_entries_total{shape}``): once per call,
+    bisection re-checks included — which path verified what."""
+    for shape, n in by_shape.items():
+        if n:
+            inc("bls_chain_entries_total", value=n, shape=shape)
 
 
 def _entry_budget(n: int, interpret: bool) -> tuple[int, int]:
@@ -517,6 +547,12 @@ def chain_verify_cached(
       entries to the host path);
     - ``sig_xy``/``coeff``: as in :func:`chain_verify`.
 
+    A **single-signer** entry is ``(validator_index, None, sig_xy,
+    coeff)``: its pubkey is column ``validator_index`` of the registry
+    planes the cache already holds on the device (``single_gather``) —
+    no committee sum, no correction table, no host point.  A call may mix
+    both shapes; each entry is routed by its own ``miss_members``.
+
     The aggregate pubkey never touches the host: ``full_sum[comm_id] -
     sum(missing)`` is computed on device and flows straight into the RLC
     ladder.  Callers must pre-reject empty-participation entries (their
@@ -548,18 +584,28 @@ def chain_verify_cached(
         b, dead = _entry_budget(n, interpret)
         pad = b - n
 
+        # ``cid``: the entry's committee, or — a single signer, whose
+        # ``miss_members`` is None — its attester's registry index
         cid = np.zeros(b, np.int32)
-        miss_idx = np.zeros((b, mmax), np.int32)
-        miss_inf = np.ones((b, mmax), bool)
+        is_single = np.zeros(b, bool)
+        miss_idx = miss_inf = None  # made where some entry is a committee aggregate
         for i, (comm_id, miss, _, _) in enumerate(flat):
+            cid[i] = comm_id
+            if miss is None:
+                is_single[i] = True
+                continue
+            if miss_idx is None:
+                miss_idx = np.zeros((b, mmax), np.int32)
+                miss_inf = np.ones((b, mmax), bool)
             mc = len(miss)
             if mc > mmax:
                 raise ValueError(
                     f"entry {i}: {mc} missing members exceeds cache capacity {mmax}"
                 )
-            cid[i] = comm_id
             miss_idx[i, :mc] = miss
             miss_inf[i, :mc] = False
+        n_single = int(is_single.sum())
+        _count_entries(single=n_single, committee=n - n_single)
 
         sgx, sgy = _g2_planes([sig for _, _, sig, _ in flat] + [C.G2_GENERATOR] * pad)
         live = np.zeros(b, bool)
@@ -571,15 +617,27 @@ def chain_verify_cached(
 
     with span("bls_dispatch"):
         ops = cache._ops
-        agg_x, agg_y, agg_inf = cache.aggregate(cid, miss_idx, miss_inf)
-        # aggregate()'s contract: infinity aggregates MUST be marked dead.
-        # Killing only the G1 lane (the signature lane stays live) leaves the
-        # check with a signature term and no matching pubkey term, so it
-        # deterministically FAILS and bisection blames the entry — the spec
-        # verdict for an infinity aggregate pubkey with a non-infinity
-        # signature (empty participation is pre-rejected by callers; a
-        # crafted identity-sum needs sks the depositor cannot prove).
-        jac1 = ops["ladder_g1"](agg_x, agg_y, kbits, live & ~agg_inf)
+        if n_single == n:
+            # a subnet drain: every pubkey is one registry column
+            agg_x, agg_y = cache.gather_single(cid)
+            g1_live = live  # a registry key is never the identity
+        else:
+            comm_ids = np.where(is_single, 0, cid) if n_single else cid
+            agg_x, agg_y, agg_inf = cache.aggregate(comm_ids, miss_idx, miss_inf)
+            if n_single:  # a mixed drain: both programs at b, then a select
+                sx, sy = cache.gather_single(np.where(is_single, cid, 0))
+                agg_x, agg_y, agg_inf = ops["single_merge"](
+                    agg_x, agg_y, agg_inf, sx, sy, jnp.asarray(is_single)
+                )
+            # aggregate()'s contract: infinity aggregates MUST be marked dead.
+            # Killing only the G1 lane (the signature lane stays live) leaves
+            # the check with a signature term and no matching pubkey term, so
+            # it deterministically FAILS and bisection blames the entry — the
+            # spec verdict for an infinity aggregate pubkey with a non-infinity
+            # signature (empty participation is pre-rejected by callers; a
+            # crafted identity-sum needs sks the depositor cannot prove).
+            g1_live = live & ~agg_inf
+        jac1 = ops["ladder_g1"](agg_x, agg_y, kbits, g1_live)
         jac2 = ops["ladder_g2"](sgx, sgy, kbits, live)
         # layout builder only reads len(entries)/h_points/group_ids — the
         # cached-entry tuples carry the same positional layout contract
@@ -926,6 +984,18 @@ class DeviceCommitteeCache:
             and s.rx is not self.rx
         ):
             self.rx, self.ry = s.rx, s.ry
+
+    def gather_single(self, indices):
+        """Affine pubkey planes of single-signer entries: registry column
+        ``indices[e]`` of the planes this cache was built against (the
+        same snapshot rule as :meth:`aggregate`, so a key replaced in the
+        store after this cache was built is never read through it)."""
+        import jax.numpy as jnp
+
+        self._refresh_planes()
+        return self._ops["single_gather"](
+            self.rx, self.ry, jnp.asarray(np.asarray(indices, np.int32))
+        )
 
     def aggregate(self, comm_ids, miss_idx, miss_inf):
         """Affine aggregate pubkey planes for one drain's entries.

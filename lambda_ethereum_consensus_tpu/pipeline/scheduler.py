@@ -32,6 +32,7 @@ synthetic feeds in scripts/bench_pipeline.py.
 from __future__ import annotations
 
 import asyncio
+import dataclasses
 import logging
 import time
 
@@ -105,6 +106,14 @@ class IngestScheduler:
         # stores shared dicts without mutating them)
         self._enqueue_args[config.name] = {"lane": config.name}
         return lane
+
+    def resize_lane(self, name: str, max_queue: int, max_items: int | None) -> None:
+        """Give a live lane another capacity and the scheduler another
+        budget (a subscription changed at run time).  Queued items stay:
+        a lane over its new capacity sheds at its next admission."""
+        lane = self.lanes[name]
+        lane.config = dataclasses.replace(lane.config, max_queue=int(max_queue))
+        self._max_items = max_items
 
     @property
     def max_items(self) -> int:

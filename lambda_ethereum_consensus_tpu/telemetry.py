@@ -99,9 +99,12 @@ _HELP = {
     "ingest_degraded": "1 while the load-shedding latch is active",
     "attestation_batch_verify_seconds": "one batched attestation signature check",
     "attestation_prepare_seconds": "cached drain: per-item validation, checkpoint state, context and participation split",
+    "subnet_validate_seconds": "one subnet flush's p2p-rule loop: one-bit rule, subnet mapping, first-seen cell per (validator, target epoch), forensics' vote note (the batched verify excluded)",
+    "subnet_seen_votes": "first-seen vote cells held by the subnet drain across the live epochs",
     "signature_decompress_seconds": "one batched G2 signature decompression + subgroup check (host)",
     "bls_host_pack_seconds": "host side of one chained verify up to its first device dispatch: hash-to-G2, entry packing, limb planes, uploads",
     "bls_dispatch_seconds": "one chained verify's program calls and the layout packing between them (the device works meanwhile)",
+    "bls_chain_entries_total": "entries entering a chained device verify, by the shape their pubkeys take (single = gathered from the registry planes by validator index, committee = cached committee sum less missing members, points = host-packed points uploaded per call); bisection re-checks included",
     "bls_device_wait_seconds": "host blocked fetching one chained verify's verdict flags from the device",
     "votes_apply_seconds": "vectorized latest-message + head-cache update for one drain's accepted votes",
     "fork_choice_on_block_seconds": "one fork-choice on_block: checks, state transition, store update",

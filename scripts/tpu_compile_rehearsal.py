@@ -93,8 +93,14 @@ def single_device_programs(n_validators: int, coeff_bits: int):
     out.append(("chain_committee_sums", ops["committee_sums"].jitted,
                 (reg, reg, sds((256, _pow2(k)), I32), sds((256, _pow2(k)), BOOL)),
                 {}))
-    # gossip drain: 1,024 aggregates over 64 messages; block: 128 over 64
-    for tag, entries, groups in (("gossip", 1024, 64), ("block", 128, 64)):
+    # gossip drain: 1,024 aggregates over 64 messages; block: 128 over 64;
+    # subnet flush: 4,096 one-bit votes of a slot's 64 committees, each
+    # pubkey gathered from the registry planes by validator index
+    b_subnet = chain_shapes(4096, 64, k=k)["b"]
+    out.append((f"chain_single_gather[subnet b={b_subnet}]",
+                ops["single_gather"].jitted, (reg, reg, sds((b_subnet,), I32)), {}))
+    for tag, entries, groups in (("gossip", 1024, 64), ("block", 128, 64),
+                                 ("subnet", 4096, 64)):
         sh = chain_shapes(entries, groups, k=k)
         b, mmax, m1, s, e, c = (sh[x] for x in ("b", "mmax", "m1", "s", "e", "c"))
         sums = sds((32, n_comm), I32)

@@ -27,6 +27,15 @@ def hs():
     return [hash_to_g2(m, DST_POP) for m in MSGS]
 
 
+def _points_entries() -> float:
+    """``bls_chain_entries_total{shape="points"}`` of the default registry."""
+    from lambda_ethereum_consensus_tpu import telemetry
+
+    lines = telemetry.get_metrics().render_prometheus(self_scrape=False).splitlines()
+    return sum(float(ln.rsplit(" ", 1)[1]) for ln in lines
+               if ln.startswith("bls_chain_entries_total{") and 'shape="points"' in ln)
+
+
 def _mk_check(hs, n=4, n_msgs=2, bad_index=None):
     """n entries over n_msgs distinct messages; entry bad_index (if any)
     carries a signature by the wrong key."""
@@ -48,6 +57,7 @@ def test_chain_verify_valid_invalid_empty(hs):
     # one device chain, four checks batched on the C axis (incl. the
     # empty check: vacuously true, same as verify_points([])); 32-bit
     # RLC coefficients keep the CI ladder short
+    before = _points_entries()
     res = BB.chain_verify(
         [
             _mk_check(hs, n=4, n_msgs=2),
@@ -59,6 +69,8 @@ def test_chain_verify_valid_invalid_empty(hs):
         coeff_bits=32,
     )
     assert res == [True, False, True, True]
+    # the uncached chain books its host-packed entries, once per call
+    assert _points_entries() - before == 8
 
 
 @pytest.mark.device

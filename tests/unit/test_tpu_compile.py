@@ -74,6 +74,9 @@ def programs(one_chip):
     pytest.param("merkle_tree_jnp[depth=19]", marks=pytest.mark.slow),
     "chain_ladder_g1[gossip b=2048]",  # one gossip drain: 1,024 entries
     "chain_norm_g1[gossip c=1 m1=127]",  # its 64 message groups
+    # one subnet flush: 4,096 one-bit votes, pubkeys gathered by index
+    "chain_single_gather[subnet b=5120]",
+    "chain_ladder_g1[subnet b=5120]",
 ])
 def test_program_compiles_for_v5e(one_chip, programs, name):
     fn, shapes, static = programs[name]
@@ -84,5 +87,6 @@ def test_program_compiles_for_v5e(one_chip, programs, name):
     footprint = (mem.argument_size_in_bytes + mem.output_size_in_bytes
                  + mem.temp_size_in_bytes)
     assert 0 < footprint < 16 << 30  # one v5e chip's HBM
-    if not name.startswith("merkle_tree_jnp"):  # plain jnp; the rest are kernels
+    # plain jnp (a tree of hashes; an index gather); the rest are kernels
+    if not name.startswith(("merkle_tree_jnp", "chain_single_gather")):
         assert "tpu_custom_call" in compiled.as_text()
