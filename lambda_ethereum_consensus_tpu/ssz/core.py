@@ -21,6 +21,7 @@ instances of :class:`Container` subclasses.
 
 from __future__ import annotations
 
+from operator import attrgetter
 from typing import Any, Callable, Sequence
 
 import numpy as np
@@ -331,8 +332,77 @@ def _pack_basics(elem: Uint | Boolean, values: Sequence, spec: ChainSpec) -> np.
     return pack_bytes(data)
 
 
+# sequences shorter than this keep the element loop: it is fine there
+# and simpler (the batched root path draws the same line)
+_BATCH_MIN = 64
+
+
+def _serialize_rows(elem, values: Sequence, spec: ChainSpec) -> np.ndarray | None:
+    """The serialized elements of a fixed-size sequence as ONE ``(n, size)``
+    byte array, for Uint<=64 / Boolean / ByteVector elements and FLAT
+    containers of those (e.g. ``Validator``): a container is filled a
+    field COLUMN at a time — one pass per field over the list, the shape
+    ``_element_roots_batched`` uses for roots — where the element loop
+    costs ~9 us a validator (8 s of a 2^20-validator state's encode).
+
+    ``None`` for a type not specialized here AND for any malformed value
+    (wrong byte length, uint out of range, boolean not 0/1, a value the
+    column conversion refuses): the caller then runs the element loop,
+    which raises the typed ``SSZError`` — validity must not depend on
+    the path.  The array may be a read-only view of joined bytes."""
+    n = len(values)
+    try:
+        if isinstance(elem, type) and issubclass(elem, Container):
+            cols = []
+            for fname, ftype in elem.__ssz_schema__.items():
+                ftype = _typ(ftype)
+                if not isinstance(ftype, (Uint, Boolean, ByteVector)):
+                    return None  # nested / variable-size field: not flat
+                col = _serialize_rows(ftype, list(map(attrgetter(fname), values)), spec)
+                if col is None:
+                    return None
+                cols.append(col)
+            if not cols:
+                return None
+            # strided column writes: np.concatenate(axis=1) is 5x slower
+            out = np.empty((n, sum(c.shape[1] for c in cols)), np.uint8)
+            at = 0
+            for col in cols:
+                out[:, at : at + col.shape[1]] = col
+                at += col.shape[1]
+            return out
+        if isinstance(elem, Uint) and elem.size <= 8:
+            # int() is the loop's own conversion; fromiter refuses what
+            # uint64 cannot hold (negative, >= 2**64) with OverflowError
+            ints = np.fromiter(map(int, values), np.uint64, n)
+            if elem.size < 8 and n and int(ints.max()) >> elem.bits:
+                return None
+            return ints.astype(f"<u{elem.size}").view(np.uint8).reshape(n, elem.size)
+        if isinstance(elem, Boolean):
+            if not set(values) <= {0, 1}:  # hash-equal to True/False too
+                return None
+            return np.fromiter(map(bool, values), np.uint8, n).reshape(n, 1)
+        if isinstance(elem, ByteVector):
+            length = _resolve(elem.length, spec)
+            # per-element checks: compensating length errors must not
+            # slip through an aggregate-only count, and len() of a
+            # buffer that is not ``bytes`` need not be its byte count
+            if not length or set(map(type, values)) != {bytes}:
+                return None
+            if set(map(len, values)) != {length}:
+                return None
+            return np.frombuffer(b"".join(values), np.uint8).reshape(n, length)
+    except (OverflowError, TypeError, ValueError, AttributeError):
+        return None  # let the loop path produce the typed error
+    return None
+
+
 def _serialize_elements(elem: SSZType, values: Sequence, spec: ChainSpec) -> bytes:
     if elem.is_fixed_size(spec):
+        if len(values) >= _BATCH_MIN:
+            rows = _serialize_rows(elem, values, spec)
+            if rows is not None:
+                return rows.tobytes()
         return b"".join(elem.serialize(v, spec) for v in values)
     parts = [elem.serialize(v, spec) for v in values]
     offset = OFFSET_SIZE * len(parts)
@@ -397,60 +467,28 @@ def _element_roots_batched(elem, values, spec, backend) -> np.ndarray | None:
         return None
     schema = elem.__ssz_schema__
     n = len(values)
-    if n < 64 or not schema:
+    if n < _BATCH_MIN or not schema:
         return None  # small lists: the loop is fine and simpler
     be = backend or get_hash_backend()
     columns: list[np.ndarray] = []
     for fname, ftype in schema.items():
         ftype = _typ(ftype)
-        col = np.zeros((n, 32), np.uint8)
-        if isinstance(ftype, (Uint, Boolean)):
-            size = ftype.size if isinstance(ftype, Uint) else 1
-            if size > 8:
-                return None  # uint128/256 packing not specialized
-            if isinstance(ftype, Boolean):
-                # validate inside the single pass: int() would coerce
-                # values (e.g. 1.5) the loop path's serialize rejects —
-                # validity must not depend on list size
-                def conv(v, _f=fname):
-                    x = getattr(v, _f)
-                    if x not in (True, False, 0, 1):
-                        raise ValueError("invalid boolean")
-                    return int(x)
-
-            else:
-                def conv(v, _f=fname):
-                    return int(getattr(v, _f))
-
-            try:
-                ints = np.fromiter((conv(v) for v in values), np.uint64, count=n)
-            except (OverflowError, TypeError, ValueError):
-                return None  # let the loop path produce the typed error
-            # range bound: Booleans admit only 0/1 (the loop path's
-            # serialize rejects 2..255 — validation must not depend on
-            # whether the list tripped the fast path)
-            bound = 2 if isinstance(ftype, Boolean) else 1 << (8 * size)
-            if n and int(ints.max()) >= bound:
-                return None  # out-of-range: loop path raises SSZError
-            col[:, :8] = ints.astype("<u8").view(np.uint8).reshape(n, 8)
-        elif isinstance(ftype, ByteVector):
-            length = _resolve(ftype.length, spec)
-            if length > 64:
-                return None
-            raws = [bytes(getattr(v, fname)) for v in values]
-            # per-element check: compensating length errors must not
-            # slip through an aggregate-only count
-            if any(len(b) != length for b in raws):
-                return None  # malformed value: let the loop path raise
-            arr = np.frombuffer(b"".join(raws), np.uint8).reshape(n, length)
-            if length <= 32:
-                col[:, :length] = arr
-            else:  # two chunks -> one batched hash level
-                pair = np.zeros((n, 64), np.uint8)
-                pair[:, :length] = arr
-                col = be.hash_level(pair)
-        else:
+        if not isinstance(ftype, (Uint, Boolean, ByteVector)):
             return None
+        # the field's serialized column; None for uint128/256 and for any
+        # malformed value — validity must not depend on list size, so the
+        # loop path then raises the typed error
+        rows = _serialize_rows(ftype, list(map(attrgetter(fname), values)), spec)
+        if rows is None or rows.shape[1] > 64:
+            return None
+        size = rows.shape[1]
+        if size <= 32:
+            col = np.zeros((n, 32), np.uint8)
+            col[:, :size] = rows
+        else:  # two chunks -> one batched hash level
+            pair = np.zeros((n, 64), np.uint8)
+            pair[:, :size] = rows
+            col = be.hash_level(pair)
         columns.append(col)
     width = 1
     while width < len(columns):
@@ -664,6 +702,33 @@ class Bitlist(SSZType):
         return f"Bitlist[{self.limit}]"
 
 
+def _assemble(cls, value, spec: ChainSpec, field_bytes=None) -> bytes:
+    """A container's SSZ from its fields' serializations: fixed parts and
+    offsets first, variable parts after.  Each field's bytes come from its
+    type's own ``serialize`` or, when given, from ``field_bytes(fname,
+    type, value)`` (any flat byte buffer): the encoded image
+    (ssz/encoded.py) hands back views of arrays it keeps — one join
+    copies them out, so the result never aliases a live buffer."""
+    head: list = []
+    variable: list = []
+    for fname, ftype in cls.__ssz_schema__.items():
+        t = _typ(ftype)
+        v = getattr(value, fname)
+        part = t.serialize(v, spec) if field_bytes is None else field_bytes(fname, t, v)
+        if t.is_fixed_size(spec):
+            head.append(part)
+        else:
+            head.append(None)
+            variable.append(part)
+    offset = sum(OFFSET_SIZE if p is None else len(p) for p in head)
+    sizes = iter(map(len, variable))
+    for i, p in enumerate(head):
+        if p is None:
+            head[i] = offset.to_bytes(OFFSET_SIZE, "little")
+            offset += next(sizes)
+    return b"".join(head + variable)
+
+
 class ContainerMeta(type):
     """Collects SSZ field descriptors from class annotations into a schema."""
 
@@ -762,29 +827,7 @@ class Container(SSZType, metaclass=ContainerMeta):
     @classmethod
     def serialize(cls, value, spec=None):
         spec = spec or get_chain_spec()
-        fixed_parts: list[bytes | None] = []
-        variable_parts: list[bytes] = []
-        for fname, ftype in cls.__ssz_schema__.items():
-            t = _typ(ftype)
-            v = getattr(value, fname)
-            if t.is_fixed_size(spec):
-                fixed_parts.append(t.serialize(v, spec))
-            else:
-                fixed_parts.append(None)
-                variable_parts.append(t.serialize(v, spec))
-        fixed_len = sum(OFFSET_SIZE if p is None else len(p) for p in fixed_parts)
-        out = bytearray()
-        offset = fixed_len
-        vi = iter(variable_parts)
-        for p in fixed_parts:
-            if p is None:
-                out += offset.to_bytes(OFFSET_SIZE, "little")
-                offset += len(next(vi))
-            else:
-                out += p
-        for p in variable_parts:
-            out += p
-        return bytes(out)
+        return _assemble(cls, value, spec)
 
     @classmethod
     def deserialize(cls, data, spec=None):

@@ -37,6 +37,12 @@ The engine is exact, not approximate: tracking degrades to ``full`` on
 any mutation it cannot describe per-index, a false-positive delta only
 costs extra hashes, and every strategy's output is pinned against the
 plain ``hash_tree_root`` oracle in tests/unit/test_incremental.py.
+
+The pushed-delta chain has three consumers, all through the one walk
+``mutable.dirty_superset``: this engine (the state's root), the resident
+epoch plane's sync, and the encoded image (ssz/encoded.py: the state's
+*serialization*, patched from the same logs so that persisting a
+post-state does not walk the registry either).
 """
 
 from __future__ import annotations
@@ -313,8 +319,9 @@ class IncrementalStateRoot:
     def _consume_delta(self, cache: _FieldCache, value) -> frozenset | None:
         """The pushed-delta channel: a superset of the indices at which
         ``value`` may differ from the cached snapshot.  One shared walk
-        (``mutable.dirty_superset``) serves this engine and the resident
-        plane's shard-aware sync; ``None`` means the chain can't vouch
+        (``mutable.dirty_superset``) serves this engine, the resident
+        plane's shard-aware sync and the encoded image (ssz/encoded.py);
+        ``None`` means the chain can't vouch
         and the caller value-diffs, which is always exact."""
         from ..state_transition.mutable import dirty_superset
 

@@ -14,9 +14,10 @@ Round 13 makes the big list fields *delta-observable*: each rides in a
 :class:`TrackedList` that logs its own touched indices and, when
 adopt-copied across freeze/thaw, points at the list it was copied from.
 A consumer that snapshotted an earlier instance — the incremental root
-engine (ssz/incremental.py) — walks that parent chain and unions the
-per-instance logs to get a provable superset of the changed leaves,
-instead of diffing a million elements per slot.  Tracking is exact by
+engine (ssz/incremental.py), the encoded image (ssz/encoded.py) — walks
+that parent chain and unions the per-instance logs to get a provable
+superset of the changed leaves, instead of diffing a million elements
+per slot.  Tracking is exact by
 construction: every mutation path goes through the list object itself
 (``balances[i] += delta``, ``participation[i] |= flag``, ``append``),
 and anything per-index logging can't describe (slices, deletions,
@@ -49,6 +50,12 @@ _LIST_FIELDS = (
     "historical_summaries",
 )
 
+
+# what rides a state lineage across freeze/thaw cycles: the incremental
+# root engine (ssz/incremental; process_slot reuses it across slots), the
+# resident transition plane (state_transition/resident) and the encoded
+# image (ssz/encoded; store/state_store.py serializes post-states from it)
+_LINEAGE_RIDERS = ("_root_engine", "_resident_plane", "_encoded_image")
 
 # ancestors older than this many copies are unreachable to consumers (a
 # consumer that roots every slot is at most one copy behind), so the
@@ -158,6 +165,14 @@ class TrackedList(list):
         self._structural()
         list.clear(self)
 
+    def sort(self, **kwargs):
+        self._structural()
+        list.sort(self, **kwargs)
+
+    def reverse(self):
+        self._structural()
+        list.reverse(self)
+
 
 def dirty_superset(value, target, stamp_gen: int) -> frozenset | None:
     """A provable superset of the indices at which ``value`` may differ
@@ -165,19 +180,21 @@ def dirty_superset(value, target, stamp_gen: int) -> frozenset | None:
     the adopt chain from ``value`` back to ``target`` and unioning the
     per-instance mutation logs.
 
-    THE one copy of the delta-chain walk, shared by both consumers: the
-    incremental root engine (ssz/incremental.py ``_consume_delta``) and
+    THE one copy of the delta-chain walk, shared by its three consumers:
+    the incremental root engine (ssz/incremental.py ``_consume_delta``),
     the resident epoch plane's shard-aware sync
     (state_transition/resident.py), which uses it to narrow the host
     mirror compare to the touched indices instead of diffing the full
-    10M-validator column per boundary.
+    10M-validator column per boundary, and the encoded image
+    (ssz/encoded.py), which re-serializes only those elements into the
+    bytes a post-state is persisted as.
 
     ``None`` means the chain can't vouch (unstamped, branched lineage,
     a structural op anywhere along the walk, or a structural op on the
-    stamped instance after the stamp) — callers then value-diff, which
-    is always exact.  The returned set over-approximates (pre-stamp
-    dirty entries ride along): safe, extra indices only cost extra
-    compares/hashes.
+    stamped instance after the stamp) — callers then value-diff or
+    rebuild whole, which is always exact.  The returned set
+    over-approximates (pre-stamp dirty entries ride along): safe, extra
+    indices only cost extra compares/hashes.
     """
     if target is None or getattr(value, "gen", None) is None:
         return None
@@ -197,14 +214,6 @@ def dirty_superset(value, target, stamp_gen: int) -> frozenset | None:
             return None
     return None
 
-    def sort(self, **kwargs):
-        self._structural()
-        list.sort(self, **kwargs)
-
-    def reverse(self):
-        self._structural()
-        list.reverse(self)
-
 
 class BeaconStateMut:
     """Working copy of a BeaconState; mutate freely, then :meth:`freeze`."""
@@ -218,13 +227,8 @@ class BeaconStateMut:
         object.__setattr__(self, "_registry_cache", None)
         object.__setattr__(self, "_active_cache", {})
         object.__setattr__(self, "_pubkey_index", None)
-        # incremental-root engine rides the state lineage (ssz/incremental):
-        # process_slot reuses it across slots AND across freeze/thaw cycles
-        object.__setattr__(self, "_root_engine", getattr(state, "_root_engine", None))
-        # resident transition plane (state_transition/resident): same ride
-        object.__setattr__(
-            self, "_resident_plane", getattr(state, "_resident_plane", None)
-        )
+        for name in _LINEAGE_RIDERS:
+            object.__setattr__(self, name, getattr(state, name, None))
 
     def __setattr__(self, name, value):
         # wholesale field replacement (epoch resets, set_balances): keep
@@ -240,10 +244,10 @@ class BeaconStateMut:
         out = object.__new__(BeaconState)
         for k, v in fields.items():
             object.__setattr__(out, k, v)
-        if self._root_engine is not None:
-            object.__setattr__(out, "_root_engine", self._root_engine)
-        if self._resident_plane is not None:
-            object.__setattr__(out, "_resident_plane", self._resident_plane)
+        for name in _LINEAGE_RIDERS:
+            rider = getattr(self, name)
+            if rider is not None:
+                object.__setattr__(out, name, rider)
         return out
 
     # -- registry columns (numpy views over the validators list)

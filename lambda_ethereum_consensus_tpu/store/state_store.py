@@ -13,6 +13,15 @@ accepting only candidates whose decoded state Merkle-roots to the
 ``state_root`` their stored block committed to — a WAL that survived a
 crash with a silently stale or damaged record can therefore never become
 the boot anchor; the node falls back to checkpoint sync instead.
+
+``store_state`` writes one record per call — the complete SSZ of that
+state, decodable alone — but builds the bytes from what changed: an
+encoded image (ssz/encoded.py) rides the state lineage beside the
+incremental root engine, the third consumer of the ``TrackedList`` delta
+chain (``state_transition.mutable.dirty_superset``), so a block's
+post-state is encoded without walking the fields the block did not touch
+(the 2^20-validator registry: 127 of the record's 148 MB).  The bytes are
+those of ``BeaconState.encode``, pinned in tests/unit/test_state_encode.py.
 """
 
 from __future__ import annotations
@@ -20,7 +29,8 @@ from __future__ import annotations
 import logging
 
 from ..config import ChainSpec, get_chain_spec
-from ..telemetry import get_metrics
+from ..ssz.encoded import EncodedImage
+from ..telemetry import get_metrics, span
 from ..types.beacon import BeaconState
 from .kv import KvStore
 
@@ -60,8 +70,19 @@ class StateStore:
         spec: ChainSpec | None = None,
     ) -> None:
         spec = spec or get_chain_spec()
-        self._kv.put(_STATE + block_root, state.encode(spec))
-        self._kv.put(_slot_key(state.slot), block_root)
+        with span("state_encode"):
+            image = getattr(state, "_encoded_image", None)
+            if image is None:
+                # first state of its lineage to be stored: descendants
+                # carry the image through freeze/thaw (mutable.py)
+                image = EncodedImage(BeaconState)
+                object.__setattr__(state, "_encoded_image", image)
+            # an immutable snapshot, never a view of the image: the kv
+            # engine may keep the object it is given
+            raw = image.encode(state, spec)
+        with span("state_kv_put"):
+            self._kv.put(_STATE + block_root, raw)
+            self._kv.put(_slot_key(state.slot), block_root)
 
     def has_state(self, block_root: bytes) -> bool:
         return self._kv.get(_STATE + block_root) is not None

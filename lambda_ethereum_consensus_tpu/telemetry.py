@@ -108,6 +108,9 @@ _HELP = {
     "bls_device_wait_seconds": "host blocked fetching one chained verify's verdict flags from the device",
     "votes_apply_seconds": "vectorized latest-message + head-cache update for one drain's accepted votes",
     "fork_choice_on_block_seconds": "one fork-choice on_block: checks, state transition, store update",
+    "state_encode_seconds": "one stored state's complete SSZ built from the encoded image riding its lineage: fields patched from the TrackedList delta chain, then one join",
+    "state_kv_put_seconds": "kv.put of one stored state's record (the complete SSZ) and of its slot-index key",
+    "state_encode_fields_total": "big fields of a stored state by how their encoded image was brought level: reused (no delta), patched (logged elements re-serialized), rebuilt (column-wise full build: no chain to vouch)",
     "block_transition_seconds": "full state transition of one block (slots + block + state-root check)",
     "epoch_transition_seconds": "one epoch-boundary processing pass (resident or host path)",
     "resident_plane_validators": "validators held as resident device columns by the transition plane",

@@ -398,3 +398,54 @@ def test_block_and_state_store_roundtrip(tmp_path):
         assert latest_root == root
         assert latest_state.hash_tree_root(spec) == state.hash_tree_root(spec)
         kv.close()
+
+
+def test_store_state_encodes_each_post_state_from_the_delta(kv):
+    """Every applied block leaves ONE complete record, byte-identical to
+    the element-loop serializer's, while the encoded image riding the
+    lineage walks the registry once: ``validators`` is rebuilt for the
+    first state stored and reused for every post-state after it."""
+    from lambda_ethereum_consensus_tpu.state_transition import state_transition
+    from lambda_ethereum_consensus_tpu.store.state_store import _STATE
+    from lambda_ethereum_consensus_tpu.telemetry import get_metrics
+    from lambda_ethereum_consensus_tpu.types.beacon import BeaconState
+    from lambda_ethereum_consensus_tpu.validator import build_signed_block
+
+    from .test_state_encode import loop_oracle
+
+    m = get_metrics()
+
+    def fields(path):
+        return m.get("state_encode_fields_total", field="validators", path=path)
+
+    def samples(family):
+        hist = m.get_histogram(family)
+        return 0 if hist is None else hist[3]
+
+    with use_chain_spec(minimal_spec()) as spec:
+        sks = [(i + 1).to_bytes(32, "big") for i in range(64)]
+        state = build_genesis_state([bls.sk_to_pk(sk) for sk in sks], spec=spec)
+        blocks, states = BlockStore(kv), StateStore(kv)
+        before = {p: fields(p) for p in ("rebuilt", "patched", "reused")}
+        spans = {f: samples(f) for f in ("state_encode_seconds", "state_kv_put_seconds")}
+        stored = []
+        for slot in range(1, 5):
+            signed, _post = build_signed_block(state, slot, sks, spec=spec)
+            state = state_transition(state, signed, validate_result=True, spec=spec)
+            root = blocks.store_block(signed, spec)
+            states.store_state(root, state, spec)
+            stored.append((root, state))
+            assert fields("rebuilt") - before["rebuilt"] == 1
+            assert fields("reused") - before["reused"] == slot - 1
+            for family, had in spans.items():
+                assert samples(family) - had == slot
+        assert fields("patched") == before["patched"]
+        for root, post in stored:  # none skipped, deferred or stored as a delta
+            raw = kv.get(_STATE + root)
+            assert raw == loop_oracle(post, spec)
+            assert BeaconState.decode(raw, spec).hash_tree_root(spec) == bytes(
+                blocks.get_block(root, spec).message.state_root
+            )
+        resumed_root, resumed = states.get_latest_verified_state(blocks, spec)
+        assert resumed_root == stored[-1][0]
+        assert resumed.encode(spec) == kv.get(_STATE + resumed_root)
