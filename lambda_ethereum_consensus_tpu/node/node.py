@@ -10,6 +10,7 @@ provided genesis, ref: fork_choice/supervisor.ex:16-44) -> fork-choice store
 from __future__ import annotations
 
 import asyncio
+import gc
 import logging
 import os
 import time
@@ -17,25 +18,14 @@ from dataclasses import dataclass, field
 
 from ..api.beacon_api import BeaconApiServer
 from ..config import ChainSpec, constants, get_chain_spec
-from ..config.presets import FORK_ORDER
 from ..da import DataAvailability
-from ..fork_choice import (
-    ConsensusForensics,
-    Store,
-    attestation_batch_target,
-    get_forkchoice_store,
-    get_head,
-    on_attestation_batch,
-    on_tick,
-)
+from ..fork_choice import ConsensusForensics, Store, get_forkchoice_store, get_head, on_tick
 from ..network import Port
 from ..network.gossip import TopicSubscription, _topic_short, topic_name
 from ..network.peerbook import Peerbook
-from ..network.port import VERDICT_ACCEPT, VERDICT_IGNORE, VERDICT_REJECT
 from ..network.reqresp import BlockDownloader, ReqRespServer
-from ..pipeline import IngestScheduler, LaneConfig
+from ..pipeline import IngestScheduler
 from ..slo import get_engine
-from ..state_transition import misc
 from ..store import (
     BlockStore,
     KvStore,
@@ -43,56 +33,24 @@ from ..store import (
     get_finalized_anchor,
     set_finalized_anchor,
 )
-from ..tracing import (
-    SlotClock,
-    get_recorder,
-    observe_block_arrival,
-    observe_head_update,
-)
+from ..tracing import SlotClock, get_recorder, observe_head_update
 from ..types.beacon import BeaconBlock, BeaconBlockBody, BeaconState, SignedBeaconBlock
-from ..types.validator import SignedAggregateAndProof
 from .chain import LiveChainView
+from .ingest import GossipIngest, IngestContext, SubnetChannel, TopicSpec, attestation_subnet_ids
 from .pending_blocks import PendingBlocks
 from .sync import SyncBlocks
 from .telemetry import Metrics, span, telemetry_enabled
 
 log = logging.getLogger("node")
 
+# the young generation holds a flush's working set (a few objects a message, thousands of messages):
+# CPython's 700 promotes every flush's messages to the oldest generation, whose collections then
+# come every ~20 s and each walks the registry, one object a validator, on the loop thread
+GC_YOUNG_OBJECTS = 200_000
+
 # recorder-overwrite counter cursor (see _device_telemetry_tick): the
 # flight recorder is process-wide, so the export cursor must be too
 _trace_dropped_exported = 0
-
-
-# attestation channels take deep batches: the device drain's fixed
-# dispatch cost amortizes across thousands of signatures, and one mainnet
-# slot already carries ~1k aggregates
-ATT_BATCH, ATT_QUEUE = 8192, 16384
-
-
-@dataclass(frozen=True)
-class TopicSpec:
-    """One row of the fork-aware gossip topic table (round 23).
-
-    ``_start_network`` used to hard-code the capella topic set inline;
-    every fork since would have meant another copy of the subscription
-    boilerplate.  Now forks only ADD rows: a row joins the mesh when the
-    chain's current fork (``spec.fork_at_epoch``) has reached
-    ``since_fork``.  ``handler``/``sink`` are bound-method NAMES so the
-    table itself is a frozen value (rebuilt per network (re)start)."""
-
-    name: str  # short topic name (topic_name() adds digest + ssz_snappy)
-    ssz_type: object
-    handler: str  # BeaconNode method: async (batch) -> verdicts
-    lane: str = "other"  # ingest-scheduler lane
-    since_fork: str = "phase0"
-    max_batch: int = 64
-    max_queue: int = 1024
-    # shared-lane sink method: one flush spanning every topic of the
-    # lane (gossip.SharedLaneSink); None = per-topic flushes
-    sink: str | None = None
-    # subnet id baked into the handler (functools.partial) for
-    # subnet-family topics; None for singleton topics
-    subnet: int | None = None
 
 
 @dataclass
@@ -118,11 +76,6 @@ class NodeConfig:
     # thread at startup (node/warmup.py) — overlaps the ~tens of seconds
     # of first-dispatch program loading with anchor load + sidecar boot
     warm_drain_shapes: object | None = None
-    # shared priority ingest scheduler (pipeline/): one drain over all
-    # gossip topics with deficit-weighted lanes, deadline coalescing and
-    # admission-time shedding.  False reverts to the round-4 per-topic
-    # greedy drains (debug escape hatch).
-    ingest_scheduler: bool = True
     # per-lane flush deadlines: blocks drain near-immediately; the
     # attestation lanes trade up to this much latency for device-sized
     # batches under light load (the shed/deadline regimes are measured
@@ -184,7 +137,6 @@ class BeaconNode:
         self.peerbook = Peerbook()
         self.pending: PendingBlocks | None = None
         self.da: DataAvailability | None = None
-        self._kzg_setup = None  # lazily-built trusted setup (spec width)
         self.api: BeaconApiServer | None = None
         self.slot_clock: SlotClock | None = None
         self.duties = None  # DutyScheduler when config.duty_keys is set
@@ -198,7 +150,9 @@ class BeaconNode:
         self.forensics = ConsensusForensics()
         self._tasks: list[asyncio.Task] = []
         self._subs: list[TopicSubscription] = []
-        self._lane_sinks: dict = {}  # sink method name -> the lane's one SharedLaneSink
+        # the gossip channels (node/ingest.py), built once in start();
+        # _start_network (re)builds the scheduler and the subscriptions
+        self.channels: GossipIngest | None = None
         self.ingest: IngestScheduler | None = None
         self._stopping = False
         # durability plane (round 20): the finalized epoch whose snapshot
@@ -210,18 +164,6 @@ class BeaconNode:
         self.device_backend = None
         self._prev_hash_backend = None
         self._warmer = None
-        # subnet gossip validation state: committees-per-slot + shuffling
-        # seed memo and the one-vote-per-validator-per-epoch IGNORE cache
-        # (epoch -> cells)
-        self._cps_memo: dict[tuple[int, bytes], tuple[int, bool, bytes]] = {}
-        self._cps_fallback_memo: dict[tuple[int, bytes], tuple[int, bytes]] = {}
-        # per-target vote-cell discriminator, (value, is_seed): sticky
-        # once seed-derived so recorded cell keys never change; a
-        # provisional target-root stand-in (no state yet) upgrades to the
-        # seed — safe because cells are only recorded for ACCEPTed votes,
-        # which require the target block (hence a seed source) to be known
-        self._vote_cell_disc: dict[tuple[int, bytes], tuple[bytes, bool]] = {}
-        self._seen_subnet_votes: dict[int, set] = {}
         # per-peer gossip-health plumbing (round 22): the last sidecar
         # stats snapshot (served at /debug/peers), counter cursors for
         # delta emission (the sidecar reports totals; a restart resets
@@ -237,15 +179,15 @@ class BeaconNode:
 
     async def start(self) -> None:
         spec = self.spec
+        self._gc_threshold = gc.get_threshold()  # process-wide, as the hash backend; stop() undoes
+        gc.set_threshold(GC_YOUNG_OBJECTS, *self._gc_threshold[1:])
         self._install_device_paths()
         self.kv = KvStore(self.config.db_path)
         self.blocks_db = BlockStore(self.kv)
         self.states_db = StateStore(self.kv)
 
         anchor_state, anchor_block, anchor_root = await self._select_anchor()
-        self.store = get_forkchoice_store(
-            anchor_state, anchor_block, spec, anchor_root=anchor_root
-        )
+        self.store = get_forkchoice_store(anchor_state, anchor_block, spec, anchor_root=anchor_root)
         self.store.forensics = self.forensics
         # catch the store up to wall clock immediately (ref: on_tick_now at
         # fork_choice/store.ex:65-82) so blocks are acceptable before the
@@ -262,32 +204,29 @@ class BeaconNode:
         if self.config.duty_keys:
             from ..validator import DutyScheduler
 
-            self.duties = DutyScheduler(
-                self.config.duty_keys, spec, clock=self.slot_clock
-            )
-            log.info(
-                "duty scheduler armed: %d keys", len(self.config.duty_keys)
-            )
+            self.duties = DutyScheduler(self.config.duty_keys, spec, clock=self.slot_clock)
+            log.info("duty scheduler armed: %d keys", len(self.config.duty_keys))
         anchor_root = anchor_root or anchor_block.hash_tree_root(spec)
-        self.blocks_db.store_block(
-            SignedBeaconBlock(message=anchor_block), spec, root=anchor_root
-        )
+        self.blocks_db.store_block(SignedBeaconBlock(message=anchor_block), spec, root=anchor_root)
         self.states_db.store_state(anchor_root, anchor_state, spec)
 
         self.chain = LiveChainView(self.store, self.blocks_db, spec)
         # the DA gate exists on every node (pre-deneb it simply never
         # registers an expectation, so is_available is always True) —
-        # the pending-blocks scan and the blob drain share this instance
+        # the pending-blocks scan and the blob channel share this instance
         self.da = DataAvailability(spec, subnets=self.config.blob_subnets)
+        # _start_network binds its downloader to the live port
+        self.pending = PendingBlocks(self.store, spec, on_applied=self._on_applied, da_gate=self.da)
+        self.channels = GossipIngest(IngestContext(
+            store=self.store, spec=spec, config=self.config,
+            metrics=self.metrics, forensics=self.forensics,
+            pending=self.pending, da=self.da, slot_clock=self.slot_clock,
+            head_moved=self._observe_head_transition,
+        ))
+        # the subnet channel's flushes go through the name a benchmark file
+        # replaces on this class (see the method)
+        self.channels.subnet.drain = self._subnet_attestation_drain
         await self._start_network()
-
-        self.pending = PendingBlocks(
-            self.store,
-            spec,
-            downloader=self.downloader,
-            on_applied=self._on_applied,
-            da_gate=self.da,
-        )
         self.pending.start()
 
         self._tasks.append(asyncio.ensure_future(self._tick_loop()))
@@ -540,102 +479,35 @@ class BeaconNode:
         self.port.on_peer_gone = self._on_peer_gone
         self.port.on_exit = self._on_sidecar_exit
         self.downloader = BlockDownloader(self.port, self.peerbook, self.spec)
-        if self.pending is not None:  # restart: rebind to the live port
-            self.pending.downloader = self.downloader
+        self.pending.downloader = self.downloader  # (re)bind to the live port
         self.reqresp = ReqRespServer(self.port, self.chain, self.spec)
         await self.reqresp.register()
 
-        # the shared ingest scheduler: one priority drain over every
-        # topic (pipeline/) — a sidecar restart rebuilds it so no lane
-        # holds items bound to dead subscriptions
+        # the channels outlive the sidecar; what is bound to it is rebuilt:
+        # the scheduler (no lane may hold items of dead subscriptions) and
+        # the subscriptions, from the topic table at the chain's CURRENT
+        # fork — a restart after a fork transition picks up the new rows
         if self.ingest is not None:
             await self.ingest.stop()
-            self.ingest = None
-        sched = None
-        if self.config.ingest_scheduler:
-            self.ingest = sched = self._build_ingest_scheduler()
-            sched.start()
+        self.ingest = self.channels.build_scheduler()
+        self.ingest.start()
+        for row in self.channels.topic_table():
+            await self._subscribe_row(row)
 
-        # gossip topics (ref: gossipsub.ex:16-34), now table-driven: one
-        # fork-aware TopicSpec row per topic instead of a hard-coded
-        # capella set.  Rows gated behind a later fork (deneb blob
-        # sidecars) activate when the chain's CURRENT fork reaches them;
-        # a sidecar restart after a fork transition picks up the new rows
-        # (subscriptions are rebuilt here on every (re)start).
-        epoch = int(self.store.current_slot(self.spec)) // int(
-            self.spec.SLOTS_PER_EPOCH
-        )
-        active_fork = FORK_ORDER.index(self.spec.fork_at_epoch(epoch))
-        self._lane_sinks = {}  # sinks bound to the dead scheduler go with it
-        for ts in self._topic_table():
-            if FORK_ORDER.index(ts.since_fork) > active_fork:
-                continue
-            await self._subscribe_row(ts)
-
-    async def _subscribe_row(self, ts: TopicSpec) -> None:
-        """Join one row of the topic table: the subscription, its lane
-        and (for a lane-shared row) the lane's one sink — what
+    async def _subscribe_row(self, row: TopicSpec) -> None:
+        """Join one row of the channels' topic table — what
         ``_start_network`` does per row and what a run-time subnet
         subscription does for the rows it adds."""
-        import functools
-
-        from ..network.gossip import SharedLaneSink
-
-        sched = self.ingest
-        handler = getattr(self, ts.handler)
-        if ts.subnet is not None:
-            handler = functools.partial(handler, ts.subnet)
-        sink = None
-        if sched is not None and ts.sink is not None:
-            # one sink per lane: a flush spanning N subnet topics is
-            # ONE batched verify, not N per-topic fragments
-            sink = self._lane_sinks.get(ts.sink)
-            if sink is None:
-                sink = self._lane_sinks[ts.sink] = SharedLaneSink(
-                    getattr(self, ts.sink), label=f"{ts.lane}_lane"
-                )
-        sub = TopicSubscription(
-            self.port, topic_name(self.chain.fork_digest(), ts.name), handler,
-            ssz_type=ts.ssz_type, spec=self.spec,
-            max_batch=ts.max_batch, max_queue=ts.max_queue,
-            metrics=self.metrics,
-            scheduler=sched, lane=ts.lane if sched else None,
-            sink=sink, node=self.config.node_label,
-        )
-        await sub.start()
-        self._subs.append(sub)
-
-    def _attnet_subnet_ids(self) -> tuple[int, ...]:
-        """The subscribed attestation subnets, deduped (``Port.subscribe``
-        is keyed by topic: a duplicated id would orphan one drain loop and
-        double-subscribe the sidecar) and range-checked — at startup, not
-        inside the sidecar-restart loop."""
-        subnets = tuple(sorted({int(i) for i in self.config.attnet_subnets}))
-        for i in subnets:
-            if not 0 <= i < constants.ATTESTATION_SUBNET_COUNT:
-                raise ValueError(f"attestation subnet id out of range: {i}")
-        return subnets
+        self._subs.append(await self.channels.subscribe(
+            row, self.port, self.chain.fork_digest(), self.ingest
+        ))
 
     def _attnets_bitfield(self) -> bytes:
         """ENR ``attnets``: SSZ Bitvector[64], little-endian bits."""
         attnets = bytearray(constants.ATTESTATION_SUBNET_COUNT // 8)
-        for i in self._attnet_subnet_ids():
+        for i in attestation_subnet_ids(self.config):
             attnets[i // 8] |= 1 << (i % 8)
         return bytes(attnets)
-
-    def _subnet_topic_row(self, i: int) -> TopicSpec:
-        """``beacon_attestation_{i}``: unaggregated votes, drained through
-        the SAME batched-RLC verify as aggregates — and, under the
-        scheduler, one SHARED lane: a flood on any subnet competes with
-        the other subnets, never with blocks."""
-        from ..types.beacon import Attestation
-
-        return TopicSpec(
-            name=f"beacon_attestation_{i}", ssz_type=Attestation,
-            handler="_on_attestation_batch", lane="subnet",
-            max_batch=ATT_BATCH, max_queue=ATT_QUEUE,
-            sink="_on_subnet_sink_batch", subnet=i,
-        )
 
     async def set_attestation_subnets(self, subnets) -> None:
         """Change the subscribed attestation subnets of a running node
@@ -648,147 +520,32 @@ class BeaconNode:
         end in the same subscriptions, and a sidecar restart keeps them
         (the ``attnets`` bitfield reaches the sidecar's ENR at its start:
         the port has no command that rewrites it on a live sidecar)."""
-        before = set(self._attnet_subnet_ids())
+        before = set(attestation_subnet_ids(self.config))
         previous = self.config.attnet_subnets
         self.config.attnet_subnets = tuple(subnets)
         try:
-            wanted = set(self._attnet_subnet_ids())
+            wanted = set(attestation_subnet_ids(self.config))
         except ValueError:
             self.config.attnet_subnets = previous
             raise
         if self.port is None:
             return  # not started: _start_network will read the config
-        drop = {self._subnet_topic_row(i).name for i in before - wanted}
+        subnet = self.channels.subnet
+        drop = {subnet.topic(i) for i in before - wanted}
         for sub in [s for s in self._subs if s.topic_label in drop]:
             await sub.stop()
             self._subs.remove(sub)
-        if self.ingest is not None:  # the live lane and budget follow
-            self.ingest.resize_lane("subnet", *self._subnet_lane_bounds())
+        # the live lane and budget follow
+        self.ingest.resize_lane("subnet", *subnet.lane_bounds())
         for i in sorted(wanted - before):
-            await self._subscribe_row(self._subnet_topic_row(i))
+            await self._subscribe_row(subnet.row(i))
 
-    def _blob_subnet_ids(self) -> tuple[int, ...]:
-        count = int(self.spec.get("BLOB_SIDECAR_SUBNET_COUNT", 6))
-        if self.config.blob_subnets is None:
-            return tuple(range(count))
-        subs = tuple(sorted({int(s) for s in self.config.blob_subnets}))
-        for s in subs:
-            if not 0 <= s < count:
-                # fail at startup, not inside the sidecar-restart loop
-                raise ValueError(f"blob subnet id out of range: {s}")
-        return subs
-
-    def _topic_table(self) -> list[TopicSpec]:
-        """The fork-aware gossip surface.  Forks append rows; nothing
-        else about subscription wiring changes per fork."""
-        from ..types.deneb import BlobSidecar
-
-        table = [
-            TopicSpec(
-                name="beacon_block", ssz_type=SignedBeaconBlock,
-                handler="_on_block_batch", lane="block",
-            ),
-            TopicSpec(
-                name="beacon_aggregate_and_proof",
-                ssz_type=SignedAggregateAndProof,
-                handler="_on_aggregate_batch", lane="aggregate",
-                max_batch=ATT_BATCH, max_queue=ATT_QUEUE,
-            ),
-        ]
-        # attestation subnets: one topic per subscribed subnet
-        for i in self._attnet_subnet_ids():
-            table.append(self._subnet_topic_row(i))
-        # deneb blob sidecars: one topic per sampled column, one shared
-        # lane — a flush verifies in a single RLC-folded pairing check
-        for i in self._blob_subnet_ids():
-            table.append(TopicSpec(
-                name=f"blob_sidecar_{i}", ssz_type=BlobSidecar,
-                handler="_on_blob_sidecar_batch", lane="blob",
-                since_fork="deneb",
-                sink="_on_blob_sink_batch", subnet=i,
-            ))
-        return table
-
-    def _build_ingest_scheduler(self) -> IngestScheduler:
-        """Lane model (ISSUE 3 tentpole): blocks > aggregates > subnet
-        attestations > other.  Deficit weights keep the attestation
-        lanes from starving each other while strict priority order
-        keeps block import latency bounded under any flood; the
-        attestation lanes coalesce to the device path's minimum
-        worthwhile batch (fork_choice.attestation_batch_target) and
-        snap flush sizes to the AOT-warmed shape buckets."""
-        cfg = self.config
-        att_deadline = cfg.ingest_attestation_deadline_ms / 1000.0
-        att_target = min(attestation_batch_target(), ATT_BATCH)
-        subnet_queue, max_items = self._subnet_lane_bounds()
-        sched = IngestScheduler(metrics=self.metrics, max_items=max_items)
-        sched.add_lane(LaneConfig(
-            name="block", priority=0, weight=64, max_batch=64, max_queue=1024,
-            deadline_s=cfg.ingest_block_deadline_ms / 1000.0, coalesce_target=1,
-            # blocks chain parent-first: a full lane drops the incoming
-            # message (the old queue-full behavior) rather than evicting
-            # a queued ancestor and orphaning its descendants
-            shed_newest=True,
-        ))
-        # blob sidecars sit between blocks and attestations: a block
-        # cannot apply until its sampled columns verify, so sidecars must
-        # not starve behind an attestation flood — but they coalesce to a
-        # block's worth so a flush is ONE RLC-folded pairing check.  A
-        # full lane sheds the incoming message (withholding adversaries
-        # must not evict queued honest sidecars).
-        sched.add_lane(LaneConfig(
-            name="blob", priority=1, weight=64, max_batch=64, max_queue=1024,
-            deadline_s=cfg.ingest_blob_deadline_ms / 1000.0,
-            coalesce_target=int(self.spec.get("MAX_BLOBS_PER_BLOCK", 6)),
-            shed_newest=True,
-        ))
-        sched.add_lane(LaneConfig(
-            name="aggregate", priority=2, weight=4096, max_batch=ATT_BATCH,
-            max_queue=ATT_QUEUE, deadline_s=att_deadline,
-            coalesce_target=att_target, shape_kind="attestation_entries",
-        ))
-        sched.add_lane(LaneConfig(
-            name="subnet", priority=3, weight=4096, max_batch=ATT_BATCH,
-            max_queue=subnet_queue, deadline_s=att_deadline,
-            coalesce_target=att_target, shape_kind="attestation_entries",
-        ))
-        # catch-all for non-core topics (sync committees, slashings, BLS
-        # changes — future subscriptions); empty until one is wired, and
-        # excluded from the budget picture by the explicit max_items
-        sched.add_lane(LaneConfig(
-            name="other", priority=4, weight=64, max_batch=64, max_queue=1024,
-            deadline_s=0.2, coalesce_target=16,
-        ))
-        return sched
-
-    def _subnet_lane_bounds(self) -> tuple[int, int]:
-        """``(subnet lane capacity, scheduler budget)`` for the current
-        subscription: one slot's unaggregated votes of every subscribed
-        subnet must fit — the committees a slot lands on the subscribed
-        subnets times the committee size the justified checkpoint state
-        gives — so that a valid first-seen vote is never shed while the
-        lane is the only one loaded.  Never under the aggregate lane's
-        depth; the budget keeps its distance below the sum of the lane
-        caps (``NodeConfig.ingest_max_items``) and grows by what the lane
-        grew, so the cross-lane shed policy engages as before."""
-        from ..state_transition import accessors
-        from ..state_transition.mutable import BeaconStateMut
-
-        spec = self.spec
-        queue = ATT_QUEUE
-        state = self.store.block_states.get(
-            bytes(self.store.justified_checkpoint.root)
-        )
-        if state is not None:
-            epoch = misc.compute_epoch_at_slot(self.store.current_slot(spec), spec)
-            ws = BeaconStateMut(state)
-            cps = accessors.get_committee_count_per_slot(ws, epoch, spec)
-            slots = int(spec.SLOTS_PER_EPOCH)
-            committee = -(-len(ws.active_indices(epoch)) // (cps * slots))
-            per_subnet = -(-cps // constants.ATTESTATION_SUBNET_COUNT)
-            committees = min(cps, len(self._attnet_subnet_ids()) * per_subnet)
-            queue = max(queue, committees * committee)
-        return queue, self.config.ingest_max_items + queue - ATT_QUEUE
+    def _subnet_attestation_drain(self, tagged) -> list[int]:
+        """The subnet channel's drain under the name
+        ``benchmark/tests/faults_subnet.py`` replaces on this class to
+        plant an altered verdict: the channel's flushes pass through it
+        (``start`` binds it)."""
+        return SubnetChannel.drain(self.channels.subnet, tagged)
 
     # ------------------------------------------------------------- handlers
 
@@ -808,354 +565,6 @@ class BeaconNode:
         await asyncio.sleep(1.0)
         if not self._stopping:
             await self._start_network()
-
-    async def _on_block_batch(self, batch) -> list[int]:
-        """Batched gossip blocks -> pending set (one decode pass; signature
-        verification happens in on_block)."""
-        verdicts = []
-        head_slot = self.store.current_slot(self.spec)
-        for msg in batch:
-            block = msg.value
-            self.metrics.inc("network_gossip_count", type="beacon_block")
-            if self.slot_clock is not None:
-                # arrival offset into the block's OWN slot: the slot-
-                # phase histogram that says whether blocks reach us in
-                # time to attest (decode follows admission within the
-                # flush deadline, so this is admission-accurate)
-                offset = observe_block_arrival(
-                    self.slot_clock, int(block.message.slot)
-                )
-                # weight-event log: a late block that later flips the
-                # head is named (with this offset) in the ReorgRecord's
-                # attribution.  No root here — merkleizing on the gossip
-                # admission path would break the O(1)-per-event budget;
-                # the forensic join keys on (slot, arrival offset).
-                self.forensics.note_block_arrival(
-                    None, int(block.message.slot), offset
-                )
-                if msg.trace is not None:
-                    msg.trace.event(
-                        "slot_phase",
-                        slot=int(block.message.slot),
-                        offset_s=round(offset, 4),
-                    )
-            # within-one-epoch window check (ref: gossip_handler.ex:21)
-            if abs(block.message.slot - head_slot) <= self.spec.SLOTS_PER_EPOCH:
-                self.pending.add_block(block)
-                if msg.trace is not None:
-                    msg.trace.event("apply", kind="pending_queue")
-                verdicts.append(VERDICT_ACCEPT)
-            else:
-                verdicts.append(VERDICT_IGNORE)
-        return verdicts
-
-    def _attestation_drain(self, batch, extract, metric_type: str) -> list[int]:
-        """Shared drain for both attestation channels: one batched RLC
-        signature check (fork_choice.on_attestation_batch) and the
-        three-way verdict mapping — invalid signatures REJECT (the
-        sidecar downscores and eventually disconnects the sender; round 1
-        conflated invalid with ignore and never penalized anyone)."""
-        self.metrics.inc("network_gossip_count", value=len(batch), type=metric_type)
-        results = on_attestation_batch(
-            self.store,
-            [extract(msg) for msg in batch],
-            is_from_block=False,
-            spec=self.spec,
-            # fan-in link: the ONE batched verify span records its
-            # member item traces (and each accepted member observes the
-            # admission->apply slot-phase histogram)
-            traces=[msg.trace for msg in batch],
-        )
-        # an attestation batch can reorg the head onto an already-applied
-        # block with no _on_applied involved — observe that too
-        self._observe_head_transition()
-        return [
-            VERDICT_ACCEPT
-            if err is None
-            else (VERDICT_REJECT if getattr(err, "reject", False) else VERDICT_IGNORE)
-            for err in results
-        ]
-
-    async def _on_aggregate_batch(self, batch) -> list[int]:
-        return self._attestation_drain(
-            batch, lambda msg: msg.value.message.aggregate, "aggregate_and_proof"
-        )
-
-    def _committees_per_slot_at(
-        self, target
-    ) -> tuple[int, bool, bytes] | None:
-        """``(committees_per_slot, authoritative, shuffling_seed)`` for the
-        target epoch.
-
-        ``authoritative`` is True only when the materialized checkpoint
-        state answered — approximations (target block's post-state, the
-        justified state during sync) can cross a committee-count boundary,
-        and a REJECT issued from one would penalize honest peers, so the
-        caller must downgrade mismatches to IGNORE for those.  A
-        non-authoritative memo entry upgrades itself once the checkpoint
-        state materializes.  The attester shuffling seed rides along (from
-        the same resolved state) as the one-vote-cell discriminator."""
-        from ..config import constants
-        from ..fork_choice.store import checkpoint_key
-        from ..state_transition import accessors
-
-        key = checkpoint_key(target)
-        hit = self._cps_memo.get(key)
-        if hit is not None and (hit[1] or key not in self.store.checkpoint_states):
-            return hit
-        state = self.store.checkpoint_states.get(key)
-        authoritative = state is not None
-        if state is None:
-            state = self.store.block_states.get(bytes(target.root))
-        if state is None:
-            # sync-time fallback: the justified state, memoized under its
-            # own key so gossip doesn't pay an O(registry) active-set scan
-            # per message while targets are still being fetched
-            epoch = int(target.epoch)
-            jroot = bytes(self.store.justified_checkpoint.root)
-            fhit = self._cps_fallback_memo.get((epoch, jroot))
-            if fhit is not None:
-                return fhit[0], False, fhit[1]
-            jstate = self.store.block_states.get(jroot)
-            if jstate is None:
-                return None
-            cps = accessors.get_committee_count_per_slot(jstate, epoch, self.spec)
-            seed = accessors.get_seed(
-                jstate, epoch, constants.DOMAIN_BEACON_ATTESTER, self.spec
-            )
-            if len(self._cps_fallback_memo) > 64:
-                self._cps_fallback_memo.clear()
-            self._cps_fallback_memo[(epoch, jroot)] = (cps, seed)
-            return cps, False, seed
-        cps = accessors.get_committee_count_per_slot(
-            state, int(target.epoch), self.spec
-        )
-        seed = accessors.get_seed(
-            state, int(target.epoch), constants.DOMAIN_BEACON_ATTESTER, self.spec
-        )
-        if len(self._cps_memo) > 64:
-            self._cps_memo.clear()
-        self._cps_memo[key] = (cps, authoritative, seed)
-        return cps, authoritative, seed
-
-    async def _on_attestation_batch(self, subnet: int, batch) -> list[int]:
-        """Standalone-mode entry: one subnet topic's own drain."""
-        return self._subnet_attestation_drain([(subnet, msg) for msg in batch])
-
-    async def _on_subnet_sink_batch(self, pairs) -> list[int]:
-        """Scheduler-mode entry: ONE flush spanning every subscribed
-        subnet topic (gossip.SharedLaneSink) — all votes land in a
-        single batched RLC verify instead of per-topic fragments.  Each
-        vote's subnet comes from its topic name (``beacon_attestation_{i}``,
-        the same authority the per-topic handlers bind at wiring time),
-        so a subscription needs no side-channel attribute to join the
-        sink."""
-        return self._subnet_attestation_drain(
-            [(int(sub.topic_label.rsplit("_", 1)[1]), msg) for sub, msg in pairs]
-        )
-
-    async def _on_blob_sidecar_batch(self, subnet: int, batch) -> list[int]:
-        """Standalone-mode entry: one blob subnet topic's own drain."""
-        return self._blob_sidecar_drain([(subnet, msg) for msg in batch])
-
-    async def _on_blob_sink_batch(self, pairs) -> list[int]:
-        """Scheduler-mode entry: ONE flush spanning every subscribed
-        blob_sidecar topic (gossip.SharedLaneSink) — all sidecars in the
-        flush verify in a single RLC-folded pairing check."""
-        return self._blob_sidecar_drain(
-            [(int(sub.topic_label.rsplit("_", 1)[1]), msg) for sub, msg in pairs]
-        )
-
-    def _kzg_trusted_setup(self):
-        if self._kzg_setup is None:
-            from ..da import trusted_setup
-
-            self._kzg_setup = trusted_setup(self.spec)
-        return self._kzg_setup
-
-    def _blob_sidecar_drain(self, tagged) -> list[int]:
-        """blob_sidecar_{i} gossip validation (p2p spec deneb):
-
-        - REJECT structurally misrouted sidecars (index beyond
-          MAX_BLOBS_PER_BLOCK, or on the wrong subnet for its index) —
-          compliant peers penalize a node that re-propagates these
-        - REJECT commitment-linkage mismatches against a block's
-          advertised commitment list (the DA gate's expectation)
-        - the whole flush's KZG proofs fold into ONE pairing check
-          (da.kzg.verify_blob_batch); only a failing fold pays the
-          per-item bisect, so the all-valid common case is one pairing
-        - verified sidecars feed the DA gate: the sidecar that completes
-          a block's sampled column set unparks it in pending-blocks
-        """
-        from ..da import verify_blob_batch, verify_blob_proof
-        from ..telemetry import inc
-
-        spec = self.spec
-        max_blobs = int(spec.get("MAX_BLOBS_PER_BLOCK", 6))
-        subnet_count = int(spec.get("BLOB_SIDECAR_SUBNET_COUNT", 6))
-        verdicts: list[int | None] = [None] * len(tagged)
-        items = []  # (pos, root, sidecar, msg)
-        for pos, (subnet, msg) in enumerate(tagged):
-            sc = msg.value
-            self.metrics.inc("network_gossip_count", type="blob_sidecar")
-            index = int(sc.index)
-            if index >= max_blobs or index % subnet_count != subnet:
-                verdicts[pos] = VERDICT_REJECT
-                continue
-            root = sc.signed_block_header.message.hash_tree_root(spec)
-            # linkage pre-check against an already-registered block
-            # expectation: an advertised-commitment mismatch REJECTs
-            # before paying for the pairing check
-            expected = self.da.expected_commitment(root, index)
-            if expected is not None and expected != bytes(sc.kzg_commitment):
-                inc("da_sidecars_total", 1, result="mismatch")
-                verdicts[pos] = VERDICT_REJECT
-                continue
-            items.append((pos, root, sc, msg))
-        if items:
-            setup = self._kzg_trusted_setup()
-            blobs = [bytes(sc.blob) for _, _, sc, _ in items]
-            comms = [bytes(sc.kzg_commitment) for _, _, sc, _ in items]
-            proofs = [bytes(sc.kzg_proof) for _, _, sc, _ in items]
-            if verify_blob_batch(blobs, comms, proofs, setup=setup):
-                ok = [True] * len(items)
-            else:
-                # one bad sidecar must not take honest flush-mates down
-                # with it: re-check each item on its own
-                ok = [
-                    verify_blob_proof(b, c, p, setup=setup)
-                    for b, c, p in zip(blobs, comms, proofs)
-                ]
-            for (pos, root, sc, msg), valid in zip(items, ok):
-                if not valid:
-                    verdicts[pos] = VERDICT_REJECT
-                    continue
-                linkage = self.da.on_sidecar(
-                    root, int(sc.index), bytes(sc.kzg_commitment)
-                )
-                if linkage == "mismatch":
-                    verdicts[pos] = VERDICT_REJECT
-                elif linkage == "duplicate":
-                    verdicts[pos] = VERDICT_IGNORE
-                else:  # accept | complete | orphan (block not seen yet)
-                    verdicts[pos] = VERDICT_ACCEPT
-                if msg.trace is not None and linkage == "complete":
-                    msg.trace.event("apply", kind="da_complete")
-        return [VERDICT_IGNORE if v is None else v for v in verdicts]
-
-    def _subnet_attestation_drain(self, tagged) -> list[int]:
-        """Subnet gossip validation (p2p spec beacon_attestation_{i}; ADVICE
-        r4: without these REJECTs the node re-propagates misrouted messages
-        compliant peers penalize) then the shared batched drain:
-
-        - REJECT unless exactly one aggregation bit is set
-        - REJECT when the committee maps to a different subnet
-        - IGNORE duplicate (validator, epoch) votes — keyed by the
-          (epoch, slot, index, bit, shuffling-seed) cell.  The cell only
-          pins one validator per epoch UNDER ONE SHUFFLING: the seed
-          discriminates competing forks whose different shufflings put a
-          DIFFERENT validator in the same (slot, index, bit) cell (an
-          honest first-seen vote on the other fork is not IGNOREd), while
-          forks that share the shuffling (divergence after the seed's
-          randao mix) still collide — the same validator's second vote at
-          one epoch stays IGNOREd, as the p2p spec requires.  The
-          discriminator is sticky once seed-derived (recorded cell keys
-          must never reflow); a provisional target-root stand-in (no
-          state can answer yet) upgrades to the seed, which is safe
-          because only ACCEPTed votes record cells and acceptance
-          requires the target block — hence a seed source — to be known
-        """
-        from ..state_transition.misc import compute_subnet_for_attestation
-
-        verdicts: list[int | None] = [None] * len(tagged)
-        passed, passed_pos, passed_keys = [], [], []
-        batch_keys: set = set()  # dedupe same-validator cells WITHIN the batch
-        # the p2p rules, once per flush (the batched verify below has its
-        # own spans)
-        with span("subnet_validate"):
-            for pos, (subnet, msg) in enumerate(tagged):
-                att = msg.value
-                bits = att.aggregation_bits
-                if bits.count() != 1:
-                    verdicts[pos] = VERDICT_REJECT
-                    continue
-                cps_auth = self._committees_per_slot_at(att.data.target)
-                seed = None
-                if cps_auth is not None:
-                    cps, authoritative, seed = cps_auth
-                    if int(att.data.index) >= cps or compute_subnet_for_attestation(
-                        cps, int(att.data.slot), int(att.data.index), self.spec
-                    ) != subnet:
-                        # approximate committee counts can mis-map honest
-                        # messages across a count boundary — only the real
-                        # checkpoint state justifies penalizing the sender
-                        verdicts[pos] = (
-                            VERDICT_REJECT if authoritative else VERDICT_IGNORE
-                        )
-                        continue
-                epoch = int(att.data.target.epoch)
-                tkey = (epoch, bytes(att.data.target.root))
-                hit = self._vote_cell_disc.get(tkey)
-                if hit is not None and hit[1]:
-                    disc = hit[0]  # seed-derived: sticky, keys never reflow
-                elif seed is not None:
-                    # first seed-based resolution (or an upgrade from the
-                    # provisional stand-in — no cells were recorded under it:
-                    # ACCEPT requires the target block, hence a seed source)
-                    disc = seed
-                    self._vote_cell_disc[tkey] = (seed, True)
-                else:
-                    # no state to derive the seed from yet: the target root is
-                    # the coarser stand-in (never merges distinct shufflings)
-                    disc = bytes(att.data.target.root)
-                    self._vote_cell_disc[tkey] = (disc, False)
-                key = (int(att.data.slot), int(att.data.index), bits.indices()[0], disc)
-                if (
-                    key in self._seen_subnet_votes.get(epoch, ())
-                    or (epoch, key) in batch_keys
-                ):
-                    verdicts[pos] = VERDICT_IGNORE
-                    # the IGNORE is correct for fork choice, but a duplicate
-                    # cell carrying a DIFFERENT head root is a double vote —
-                    # retained as ledger evidence instead of vanishing here
-                    self.forensics.note_vote(
-                        (epoch,) + key, bytes(att.data.beacon_block_root)
-                    )
-                    continue
-                batch_keys.add((epoch, key))
-                # first-seen root for the cell, recorded BEFORE the verify
-                # verdict lands so a same-batch twin still compares roots
-                self.forensics.note_vote(
-                    (epoch,) + key, bytes(att.data.beacon_block_root)
-                )
-                passed.append(msg)
-                passed_pos.append(pos)
-                passed_keys.append((epoch, key))
-        if passed:
-            inner = self._attestation_drain(
-                passed, lambda msg: msg.value, "beacon_attestation"
-            )
-            current_epoch = misc.compute_epoch_at_slot(
-                self.store.current_slot(self.spec), self.spec
-            )
-            for pos, verdict, (epoch, key) in zip(passed_pos, inner, passed_keys):
-                verdicts[pos] = verdict
-                if verdict == VERDICT_ACCEPT:
-                    self._seen_subnet_votes.setdefault(epoch, set()).add(key)
-            # prune epochs that can no longer appear on gossip
-            for epoch in [
-                e for e in self._seen_subnet_votes if e < current_epoch - 1
-            ]:
-                del self._seen_subnet_votes[epoch]
-            for tkey in [
-                k for k in self._vote_cell_disc if k[0] < current_epoch - 1
-            ]:
-                del self._vote_cell_disc[tkey]
-            self.metrics.set_gauge(
-                "subnet_seen_votes",
-                sum(len(cells) for cells in self._seen_subnet_votes.values()),
-            )
-        return verdicts
 
     def _on_applied(self, root: bytes, signed: SignedBeaconBlock) -> None:
         self.blocks_db.store_block(signed, self.spec)
@@ -1293,7 +702,6 @@ class BeaconNode:
         if not produced or self.port is None:
             return
         from ..network.gossip import publish_ssz
-        from ..state_transition.misc import compute_subnet_for_attestation
 
         digest = self.chain.fork_digest()
         try:
@@ -1306,19 +714,13 @@ class BeaconNode:
                     self.port, topic_name(digest, "beacon_block"),
                     signed, self.spec, node=self.config.node_label,
                 )
-            subscribed = set(self.config.attnet_subnets)
             cps = int(produced.get("committees_per_slot") or 1)
             for att in produced.get("attestations", ()):
-                # votes for unsubscribed subnets stay pooled (the
-                # aggregation duty still covers them); publishing to a
-                # mesh we are not part of would just be dropped
-                subnet = compute_subnet_for_attestation(
-                    cps, int(att.data.slot), int(att.data.index), self.spec
-                )
-                if subnet in subscribed:
+                # votes for unsubscribed subnets stay pooled
+                topic = self.channels.subnet.publish_topic(att, cps)
+                if topic is not None:
                     await publish_ssz(
-                        self.port,
-                        topic_name(digest, f"beacon_attestation_{subnet}"),
+                        self.port, topic_name(digest, topic),
                         att, self.spec, node=self.config.node_label,
                     )
             agg_topic = topic_name(digest, "beacon_aggregate_and_proof")
@@ -1559,6 +961,7 @@ class BeaconNode:
 
     async def stop(self) -> None:
         self._stopping = True
+        gc.set_threshold(*getattr(self, "_gc_threshold", gc.get_threshold()))
         if self._warmer is not None:
             # the drain-warmer is daemonized and bounded, but a stop()
             # that returns while it still compiles programs races the

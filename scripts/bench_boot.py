@@ -70,6 +70,7 @@ def main() -> None:
 
         from lambda_ethereum_consensus_tpu.config import constants
         from lambda_ethereum_consensus_tpu.node import BeaconNode, NodeConfig
+        from lambda_ethereum_consensus_tpu.node.ingest import attestation_drain
         from lambda_ethereum_consensus_tpu.state_transition import accessors, misc
         from lambda_ethereum_consensus_tpu.state_transition.genesis import (
             build_genesis_state,
@@ -163,8 +164,8 @@ def main() -> None:
                     )
                 )
             note("first drain dispatching")
-            verdicts = node._attestation_drain(
-                batch, lambda m: m.value, "aggregate_and_proof"
+            verdicts = attestation_drain(
+                node.channels.ctx, batch, lambda m: m.value, "aggregate_and_proof"
             )
             note("first drain done")
             ok = sum(1 for v in verdicts if v == 0)

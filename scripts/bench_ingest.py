@@ -5,7 +5,7 @@ measured messages/s through the PRODUCTION path.  This bench drives the
 real pipeline end to end:
 
     snappy + SSZ decode          (network/gossip.py TopicSubscription)
-    -> the node's drain          (node.BeaconNode._on_aggregate_batch)
+    -> the aggregate channel     (node.ingest.AggregateChannel.drain)
     -> fork-choice batch verify  (handlers._attestation_batch_cached:
        native signature decompression, EpochAttestationContext numpy
        participation split, chain_verify_cached device drain)
@@ -19,7 +19,7 @@ node-path rate within 2x of the ops-level headline at the same shapes.
 
 What is NOT covered (documented, not hidden): outer SignedAggregateAndProof
 signatures and selection proofs are not verified by the node's aggregate
-drain (only the inner aggregate — matching node._on_aggregate_batch), and
+drain (only the inner aggregate — matching AggregateChannel.drain), and
 the asyncio loop is blocked during a drain, so drains do not overlap.
 
 Ref: SURVEY §3.2 hot loop (gossip in -> verified -> fork choice), served
@@ -109,11 +109,13 @@ def run(
 
     with use_chain_spec(spec):
         from lambda_ethereum_consensus_tpu.config import constants
-        from lambda_ethereum_consensus_tpu.fork_choice import on_tick
+        from lambda_ethereum_consensus_tpu.fork_choice import ConsensusForensics, on_tick
         from lambda_ethereum_consensus_tpu.fork_choice.store import (
             get_forkchoice_store,
         )
-        from lambda_ethereum_consensus_tpu.node import BeaconNode, NodeConfig
+        from lambda_ethereum_consensus_tpu.node import NodeConfig
+        from lambda_ethereum_consensus_tpu.node.ingest import AggregateChannel, IngestContext
+        from lambda_ethereum_consensus_tpu.telemetry import Metrics, telemetry_enabled
         from lambda_ethereum_consensus_tpu.state_transition import (
             accessors,
             misc,
@@ -154,16 +156,20 @@ def run(
         # clock: epoch 1, slot 1 — every epoch-0 attestation is timely
         on_tick(store, store.genesis_time + (slots + 1) * spec.SECONDS_PER_SLOT, spec)
 
-        # the node object whose REAL drain we feed (no network start)
-        node = BeaconNode(NodeConfig(db_path="/dev/null"), spec)
-        node.store = store
+        # the aggregate channel whose REAL drain we feed (no node, no network)
+        channel = AggregateChannel(IngestContext(
+            store=store, spec=spec, config=NodeConfig(),
+            metrics=Metrics(enabled=telemetry_enabled()),
+            forensics=ConsensusForensics(), pending=None, da=None,
+            slot_clock=None, head_moved=lambda: None,
+        ))
 
         port = StubPort()
         topic = topic_name(b"\x00\x00\x00\x00", "beacon_aggregate_and_proof")
         sub = TopicSubscription(
             port,
             topic,
-            node._on_aggregate_batch,
+            channel.drain,
             ssz_type=SignedAggregateAndProof,
             spec=spec,
             max_batch=16384,

@@ -336,8 +336,10 @@ def test_slo_check_tightened_budget_exits_nonzero():
     assert out.returncode == 1
     report = json.loads(out.stdout)
     assert report["ok"] is False
-    (v,) = report["violations"]
-    assert v["series"] == "ingest_flush_wait_seconds"
+    # its own violation, picked out of the list: a loaded machine adds
+    # others (the duty deadlines) that this test says nothing about
+    (v,) = [v for v in report["violations"]
+            if v["series"] == "ingest_flush_wait_seconds"]
     assert v["window"] == "cumulative"
     assert v["observed"] > v["budget"]
     assert "SLO VIOLATION" in out.stderr

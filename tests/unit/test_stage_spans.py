@@ -544,6 +544,50 @@ def test_node_tick_span_once_per_tick(tmp_path):
     assert 0.0 < total < 2.2
 
 
+def test_node_holds_a_flush_in_the_young_generation(tmp_path):
+    """While a node runs, a flush's working set (tens of thousands of live
+    message objects) meets no collection, so none of it is promoted to the
+    generation whose collection walks the registry; stop() restores."""
+    import gc
+
+    from lambda_ethereum_consensus_tpu import types as T
+    from lambda_ethereum_consensus_tpu.config import minimal_spec
+    from lambda_ethereum_consensus_tpu.node import BeaconNode, NodeConfig
+    from lambda_ethereum_consensus_tpu.node.node import GC_YOUNG_OBJECTS
+    from lambda_ethereum_consensus_tpu.state_transition.genesis import (
+        build_genesis_state,
+    )
+
+    async def main(spec):
+        genesis = build_genesis_state(
+            [bls.sk_to_pk(k) for k in SKS], genesis_time=int(time.time()) - 26,
+            spec=spec,
+        )
+        node = BeaconNode(NodeConfig(
+            db_path=str(tmp_path / "n.wal"), genesis_state=genesis, wire=None,
+            enable_range_sync=False,
+        ))
+        await node.start()
+        collections = []
+        watch = lambda phase, info: collections.append(info["generation"])  # noqa: E731
+        try:
+            running = gc.get_threshold()
+            gc.callbacks.append(watch)
+            flush = [T.Checkpoint(epoch=i, root=bytes(32)) for i in range(40_000)]
+            del flush
+        finally:
+            gc.callbacks.remove(watch)
+            await node.stop()
+        return running, collections
+
+    before = gc.get_threshold()
+    with use_chain_spec(minimal_spec()) as spec:
+        running, collections = asyncio.run(asyncio.wait_for(main(spec), 120))
+    assert running == (GC_YOUNG_OBJECTS, *before[1:]) and GC_YOUNG_OBJECTS >= 100_000
+    assert collections == []
+    assert gc.get_threshold() == before
+
+
 # ------------------------------------------------------- (e) no-op mode
 
 

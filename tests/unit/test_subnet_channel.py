@@ -28,7 +28,7 @@ from lambda_ethereum_consensus_tpu.config import constants, minimal_spec, use_ch
 from lambda_ethereum_consensus_tpu.crypto import bls
 from lambda_ethereum_consensus_tpu.crypto.bls import batch as batch_mod
 from lambda_ethereum_consensus_tpu.node import BeaconNode, NodeConfig
-from lambda_ethereum_consensus_tpu.node import node as node_mod
+from lambda_ethereum_consensus_tpu.node import ingest as ingest_mod
 from lambda_ethereum_consensus_tpu.ops import bls_batch as BB
 from lambda_ethereum_consensus_tpu.state_transition import accessors, misc
 from lambda_ethereum_consensus_tpu.state_transition.genesis import build_genesis_state
@@ -108,7 +108,7 @@ async def scenario(tmp, mp) -> dict:
 
         # tiny lanes, so that the capacity rule has to act: one subnet's
         # committee (4) fits the floor, a slot of every subnet (8) does not
-        mp.setattr(node_mod, "ATT_QUEUE", 4)
+        mp.setattr(ingest_mod, "ATT_QUEUE", 4)
         node = BeaconNode(NodeConfig(
             db_path=os.path.join(tmp, "b.wal"), genesis_state=genesis, wire=None,
             enable_range_sync=False, attnet_subnets=(0,), ingest_max_items=6))
