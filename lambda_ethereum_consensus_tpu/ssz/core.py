@@ -21,6 +21,9 @@ instances of :class:`Container` subclasses.
 
 from __future__ import annotations
 
+import struct
+import threading
+from functools import partial
 from operator import attrgetter
 from typing import Any, Callable, Sequence
 
@@ -418,12 +421,16 @@ def _serialize_elements(elem: SSZType, values: Sequence, spec: ChainSpec) -> byt
 def _deserialize_elements(elem: SSZType, data: bytes, spec: ChainSpec) -> list:
     if len(data) == 0:
         return []
+    plan = _plan_of(elem, spec)  # a container element decodes by its plan
     if elem.is_fixed_size(spec):
         size = elem.fixed_length(spec)
         if size == 0 or len(data) % size:
             raise SSZError(f"sequence length {len(data)} not a multiple of element size {size}")
+        if plan is not None:
+            return plan.decode_rows(data)
         return [elem.deserialize(data[i : i + size], spec) for i in range(0, len(data), size)]
     # variable-size elements: offset table
+    decode = plan.decode if plan is not None else partial(elem.deserialize, spec=spec)
     first = int.from_bytes(data[:OFFSET_SIZE], "little")
     if first == 0 or first % OFFSET_SIZE or first > len(data):
         raise SSZError("bad first offset")
@@ -437,7 +444,7 @@ def _deserialize_elements(elem: SSZType, data: bytes, spec: ChainSpec) -> list:
         a, b = offsets[i], offsets[i + 1]
         if a > b or b > len(data):
             raise SSZError("offsets not monotonic or out of bounds")
-        values.append(elem.deserialize(data[a:b], spec))
+        values.append(decode(data[a:b]))
     return values
 
 
@@ -729,6 +736,161 @@ def _assemble(cls, value, spec: ChainSpec, field_bytes=None) -> bytes:
     return b"".join(head + variable)
 
 
+def _container_cls(t) -> "type[Container] | None":
+    """The Container class a schema entry stands for (the class itself or
+    its adapter), else None."""
+    if isinstance(t, _ContainerAdapter):
+        return t.cls
+    return t if isinstance(t, type) and issubclass(t, Container) else None
+
+
+def _late_sizes(t) -> tuple:
+    """Every size under ``t`` that is resolved against a ChainSpec (a
+    constant's name or a callable), in schema order."""
+    cls = _container_cls(t)
+    if cls is not None:
+        return cls.__ssz_late_sizes__
+    sizes = [getattr(t, a) for a in ("length", "limit") if hasattr(t, a)]
+    late = tuple(n for n in sizes if not isinstance(n, int))
+    return late + (_late_sizes(t.elem) if hasattr(t, "elem") else ())
+
+
+def _plan_of(t, spec: ChainSpec) -> "_DecodePlan | None":
+    """The decode plan of a schema entry that is a container, else None."""
+    cls = _container_cls(t)
+    return None if cls is None else cls._decode_plan(spec)
+
+
+def _boolean_of(byte: int) -> bool:
+    # struct's "?" takes any non-zero byte for true: SSZ takes 0 and 1
+    if byte > 1:
+        raise SSZError(f"invalid boolean encoding: {byte:#04x}")
+    return byte == 1
+
+
+_STRUCT_CODES = {1: "B", 2: "H", 4: "I", 8: "Q"}
+_PLAN_LOCK = threading.RLock()  # re-entrant: a plan builds its fields' plans
+_new = object.__new__
+
+
+class _DecodePlan:
+    """What decoding one container class under one spec needs, derived
+    from the schema once (``Container._decode_plan``) and not per message.
+
+    The fixed part is ONE ``struct.Struct``: a ``Uint`` <= 64, ``Boolean``
+    or ``ByteVector`` field is one struct item, a nested fixed-size
+    container is its own items in line (``AttestationData`` with its two
+    ``Checkpoint``s: ``QQ32sQ32sQ32s``), any other fixed-size field
+    (``uint128/256``, ``Vector``, ``Bitvector``) is an ``Ns`` item — the
+    slice its own type's ``deserialize`` is then given (``convs``) — and a
+    variable-size field is its ``I`` offset, replaced after the offset
+    checks by what its decoder makes of its span (``var``).  The instances
+    are then built from the flat item sequence in schema order, without
+    ``__init__`` (as ``Container.copy``).
+
+    ``kind``: ``flat`` — every item is a struct leaf, the container is one
+    struct; ``fields`` — none is (offsets and slices only); else ``mixed``.
+    """
+
+    __slots__ = (
+        "cls", "kind", "fixed_len", "codes", "convs", "var", "leaves",
+        "names", "subs", "unpack_from", "iter_unpack",
+    )
+
+    def __init__(self, cls, spec: ChainSpec):
+        self.cls = cls
+        self.codes: list[str] = []  # struct item per slot
+        self.convs: list[tuple[int, Callable]] = []  # (slot, bytes -> value)
+        self.var: list[tuple[int, Callable]] = []  # (offset's slot, decoder)
+        self.leaves = 0
+        self.names = tuple(cls.__ssz_schema__)
+        subs = []  # per field: a nested fixed container's build, else None
+        for ftype in cls.__ssz_schema__.values():
+            t = _typ(ftype)
+            sub = _plan_of(t, spec)
+            at = len(self.codes)
+            if not t.is_fixed_size(spec):
+                self.codes.append("I")
+                decode = sub.decode if sub is not None else partial(t.deserialize, spec=spec)
+                self.var.append((at, decode))
+                subs.append(None)
+            elif sub is not None:
+                self.codes += sub.codes
+                self.convs += [(at + i, conv) for i, conv in sub.convs]
+                self.leaves += sub.leaves
+                subs.append(sub.build)
+            else:
+                size = t.fixed_length(spec)
+                if isinstance(t, Uint) and size in _STRUCT_CODES:
+                    self.codes.append(_STRUCT_CODES[size])
+                    self.leaves += 1
+                elif isinstance(t, Boolean):
+                    self.codes.append("B")
+                    self.convs.append((at, _boolean_of))
+                    self.leaves += 1
+                else:
+                    self.codes.append(f"{size}s")
+                    if isinstance(t, ByteVector):
+                        self.leaves += 1
+                    else:
+                        self.convs.append((at, partial(t.deserialize, spec=spec)))
+                subs.append(None)
+        self.subs = tuple(subs) if any(subs) else None
+        fixed = struct.Struct("<" + "".join(self.codes))
+        self.fixed_len = fixed.size
+        self.unpack_from = fixed.unpack_from
+        self.iter_unpack = fixed.iter_unpack
+        if not self.leaves:
+            self.kind = "fields"
+        else:
+            self.kind = "flat" if self.leaves == len(self.codes) else "mixed"
+        _METRICS.inc("ssz_decode_plans_total", type=cls.__name__, kind=self.kind)
+
+    def decode(self, data: bytes):
+        n = len(data)
+        if n < self.fixed_len:
+            raise SSZError(f"{self.cls.__name__}: truncated ({n} < {self.fixed_len})")
+        if not self.var and n != self.fixed_len:
+            raise SSZError(f"{self.cls.__name__}: {n - self.fixed_len} trailing bytes")
+        return self._finish(self.unpack_from(data), data)
+
+    def decode_rows(self, data: bytes) -> list:
+        """Fixed-size elements back to back (a multiple of ``fixed_len``)."""
+        finish = self._finish
+        return [finish(row, None) for row in self.iter_unpack(data)]
+
+    def _finish(self, slots, data):
+        if self.convs or self.var:
+            slots = list(slots)
+            for at, conv in self.convs:
+                slots[at] = conv(slots[at])
+            if self.var:
+                starts = [slots[at] for at, _ in self.var]
+                if starts[0] != self.fixed_len:
+                    raise SSZError(
+                        f"{self.cls.__name__}: first offset {starts[0]} != fixed size {self.fixed_len}"
+                    )
+                starts.append(len(data))
+                if starts != sorted(starts):  # each span ends where the next starts
+                    raise SSZError(f"{self.cls.__name__}: invalid offsets")
+                for (at, decode), a, b in zip(self.var, starts, starts[1:]):
+                    slots[at] = decode(data[a:b])
+        return self.build(iter(slots))
+
+    def build(self, slots):
+        """One instance from the iterator of slot values, which it leaves
+        just past its own."""
+        obj = _new(self.cls)
+        if self.subs is None:
+            # zip stops at the names' end and takes no slot beyond
+            obj.__dict__.update(zip(self.names, slots))
+        else:
+            fields = obj.__dict__
+            for name, sub in zip(self.names, self.subs):
+                fields[name] = next(slots) if sub is None else sub(slots)
+        return obj
+
+
 class ContainerMeta(type):
     """Collects SSZ field descriptors from class annotations into a schema."""
 
@@ -749,6 +911,11 @@ class ContainerMeta(type):
                     "annotations` — are not supported in container modules)"
                 )
         cls.__ssz_schema__ = schema
+        # the decode plans' key and cache (Container._decode_plan), per class
+        cls.__ssz_late_sizes__ = tuple(
+            dict.fromkeys(n for t in schema.values() for n in _late_sizes(t))
+        )
+        cls.__ssz_decode_plans__ = {}
         return cls
 
 
@@ -831,49 +998,30 @@ class Container(SSZType, metaclass=ContainerMeta):
 
     @classmethod
     def deserialize(cls, data, spec=None):
-        spec = spec or get_chain_spec()
-        data = bytes(data)
-        fixed_sizes: list[int | None] = []
-        for ftype in cls.__ssz_schema__.values():
-            t = _typ(ftype)
-            fixed_sizes.append(t.fixed_length(spec) if t.is_fixed_size(spec) else None)
-        fixed_len = sum(OFFSET_SIZE if s is None else s for s in fixed_sizes)
-        if len(data) < fixed_len:
-            raise SSZError(f"{cls.__name__}: truncated ({len(data)} < {fixed_len})")
-        # first pass: slice fixed parts, collect offsets
-        pos = 0
-        slices: list[tuple[str, bytes | None]] = []
-        offsets: list[int] = []
-        for (fname, ftype), size in zip(cls.__ssz_schema__.items(), fixed_sizes):
-            if size is None:
-                offsets.append(int.from_bytes(data[pos : pos + OFFSET_SIZE], "little"))
-                slices.append((fname, None))
-                pos += OFFSET_SIZE
-            else:
-                slices.append((fname, data[pos : pos + size]))
-                pos += size
-        if offsets:
-            if offsets[0] != fixed_len:
-                raise SSZError(f"{cls.__name__}: first offset {offsets[0]} != fixed size {fixed_len}")
-            bounds = offsets + [len(data)]
-            for a, b in zip(bounds, bounds[1:]):
-                if a > b or b > len(data):
-                    raise SSZError(f"{cls.__name__}: invalid offsets")
-        elif len(data) != fixed_len:
-            raise SSZError(f"{cls.__name__}: {len(data) - fixed_len} trailing bytes")
-        # second pass: decode
-        kwargs = {}
-        oi = 0
-        for (fname, ftype), (fname2, chunk) in zip(cls.__ssz_schema__.items(), slices):
-            t = _typ(ftype)
-            if chunk is None:
-                a = offsets[oi]
-                b = offsets[oi + 1] if oi + 1 < len(offsets) else len(data)
-                kwargs[fname] = t.deserialize(data[a:b], spec)
-                oi += 1
-            else:
-                kwargs[fname] = t.deserialize(chunk, spec)
-        return cls(**kwargs)
+        if spec is None:  # not ``or``: a Mapping's truth is a Python call
+            spec = get_chain_spec()
+        return cls._decode_plan(spec).decode(bytes(data))
+
+    @classmethod
+    def _decode_plan(cls, spec) -> "_DecodePlan":
+        """The decode plan of this class under ``spec``, compiled on first
+        use.  Keyed on the spec's name AND on every late-bound size of the
+        schema resolved against it: two specs share a plan only when both
+        agree (``ChainSpec.replace`` keeps the name)."""
+        key = (spec.name, *[_resolve(n, spec) for n in cls.__ssz_late_sizes__])
+        plan = cls.__ssz_decode_plans__.get(key)
+        if plan is None:
+            with _PLAN_LOCK:  # a plan is built, and counted, once
+                plan = cls.__ssz_decode_plans__.get(key)
+                if plan is None:
+                    plan = cls.__ssz_decode_plans__[key] = _DecodePlan(cls, spec)
+        return plan
+
+    @classmethod
+    def decode_plan_kind(cls, spec=None) -> str:
+        """``flat`` / ``mixed`` / ``fields``: how ``decode`` reads this type
+        (see :class:`_DecodePlan`); builds the plan if there is none yet."""
+        return cls._decode_plan(spec or get_chain_spec()).kind
 
     @classmethod
     def _hash_tree_root_of(cls, value, spec=None, backend=None):

@@ -83,6 +83,7 @@ _HELP = {
     "gossip_queue_depth": "queued gossip messages at drain start",
     "gossip_drain_seconds": "one gossip batch: decode + verify + verdicts",
     "gossip_decode_seconds": "one gossip batch's snappy + SSZ decode loop",
+    "gossip_decoded_total": "gossip messages decoded (snappy + SSZ) on their way to a handler, one increment a drain, by the decode plan kind of the topic's SSZ type (flat|mixed|fields)",
     "gossip_verdicts_seconds": "one gossip batch's verdict hand-over: trace ends + every validate_message staged, then the batch's one sidecar round trip",
     "gossip_shed_count": "gossip messages dropped at admission, by topic/reason",
     "ingest_lane_depth": "queued items per ingest scheduler lane",
@@ -116,6 +117,7 @@ _HELP = {
     "resident_plane_validators": "validators held as resident device columns by the transition plane",
     "resident_plane_sync_elems": "cumulative per-epoch delta elements scattered to the resident columns",
     "fork_choice_head_recompute_seconds": "uncached LMD-GHOST head walk",
+    "ssz_decode_plans_total": "SSZ decode plans built, one per (container type, chain spec) on its first decode: flat = the container is one struct, mixed = struct items beside per-field decoders, fields = per-field decoders only",
     "ssz_hash_tree_root_seconds": "top-level SSZ Merkleization root",
     "sidecar_roundtrip_seconds": "one sidecar command round-trip",
     "port_verdict_batch_size": "verdicts per validate_messages frame (sum/count = verdicts per sidecar round trip)",

@@ -388,3 +388,414 @@ def test_batched_element_roots_match_loop(mainnet):
         [np.frombuffer(Validator.hash_tree_root(v, spec, be), np.uint8) for v in vals]
     )
     assert (fast == slow).all()
+
+
+# --- the decode plan against the per-call schema walk it replaced (ISSUE 31) ----
+#
+# ``oracle_decode`` is the decoder ``Container.deserialize`` and
+# ``_deserialize_elements`` were before the plan: it derives sizes, slices
+# and offsets from the schema on every call, at every nesting level, and
+# builds through ``cls(**kwargs)``.  It lives here as the reference only.
+
+import importlib
+import pkgutil
+import random
+
+from lambda_ethereum_consensus_tpu.config import mainnet_spec
+from lambda_ethereum_consensus_tpu.ssz import Bits, BitlistValue, BitvectorValue, Container
+from lambda_ethereum_consensus_tpu.ssz import core as ssz_core
+from lambda_ethereum_consensus_tpu.telemetry import get_metrics
+
+OFFSET = ssz_core.OFFSET_SIZE
+
+
+def _container_of(t):
+    """The Container class behind a schema entry, or None."""
+    t = getattr(t, "cls", t)
+    return t if isinstance(t, type) and issubclass(t, Container) else None
+
+
+def oracle_elements(elem, data, spec):
+    if len(data) == 0:
+        return []
+    if elem.is_fixed_size(spec):
+        size = elem.fixed_length(spec)
+        if size == 0 or len(data) % size:
+            raise SSZError("sequence length not a multiple of element size")
+        return [oracle_decode(elem, data[i : i + size], spec) for i in range(0, len(data), size)]
+    first = int.from_bytes(data[:OFFSET], "little")
+    if first == 0 or first % OFFSET or first > len(data):
+        raise SSZError("bad first offset")
+    count = first // OFFSET
+    offsets = [
+        int.from_bytes(data[i * OFFSET : (i + 1) * OFFSET], "little") for i in range(count)
+    ] + [len(data)]
+    values = []
+    for i in range(count):
+        a, b = offsets[i], offsets[i + 1]
+        if a > b or b > len(data):
+            raise SSZError("offsets not monotonic or out of bounds")
+        values.append(oracle_decode(elem, data[a:b], spec))
+    return values
+
+
+def oracle_decode(t, data, spec):
+    cls = _container_of(t)
+    if cls is None:
+        if isinstance(t, Vector):
+            values = oracle_elements(t.elem, data, spec)
+            t._check_len(values, spec)
+            return values
+        if isinstance(t, List):
+            values = oracle_elements(t.elem, data, spec)
+            t._check_limit(values, spec)
+            return values
+        return t.deserialize(data, spec)  # a leaf: its own type's decoder
+    data = bytes(data)
+    fixed_sizes = []
+    for ftype in cls.__ssz_schema__.values():
+        ft = ssz_core._typ(ftype)
+        fixed_sizes.append(ft.fixed_length(spec) if ft.is_fixed_size(spec) else None)
+    fixed_len = sum(OFFSET if s is None else s for s in fixed_sizes)
+    if len(data) < fixed_len:
+        raise SSZError(f"{cls.__name__}: truncated ({len(data)} < {fixed_len})")
+    pos = 0
+    slices = []
+    offsets = []
+    for fname, size in zip(cls.__ssz_schema__, fixed_sizes):
+        if size is None:
+            offsets.append(int.from_bytes(data[pos : pos + OFFSET], "little"))
+            slices.append((fname, None))
+            pos += OFFSET
+        else:
+            slices.append((fname, data[pos : pos + size]))
+            pos += size
+    if offsets:
+        if offsets[0] != fixed_len:
+            raise SSZError(f"{cls.__name__}: first offset {offsets[0]} != fixed size {fixed_len}")
+        bounds = offsets + [len(data)]
+        for a, b in zip(bounds, bounds[1:]):
+            if a > b or b > len(data):
+                raise SSZError(f"{cls.__name__}: invalid offsets")
+    elif len(data) != fixed_len:
+        raise SSZError(f"{cls.__name__}: {len(data) - fixed_len} trailing bytes")
+    kwargs = {}
+    oi = 0
+    for ftype, (fname, chunk) in zip(cls.__ssz_schema__.values(), slices):
+        if chunk is None:
+            a = offsets[oi]
+            b = offsets[oi + 1] if oi + 1 < len(offsets) else len(data)
+            kwargs[fname] = oracle_decode(ftype, data[a:b], spec)
+            oi += 1
+        else:
+            kwargs[fname] = oracle_decode(ftype, chunk, spec)
+    return cls(**kwargs)
+
+
+def _types_containers():
+    """Every Container subclass defined under ``types/``, in a fixed order."""
+    found = {}
+    for info in pkgutil.iter_modules(T.__path__):
+        module = importlib.import_module(f"{T.__name__}.{info.name}")
+        for name, obj in vars(module).items():
+            if _container_of(obj) is not None and obj.__module__ == module.__name__:
+                found[f"{info.name}.{name}"] = obj
+    return [found[k] for k in sorted(found)]
+
+
+TYPES_CONTAINERS = _types_containers()
+SPECS = {"minimal": minimal_spec(), "mainnet": mainnet_spec()}
+
+
+def random_value(t, spec, rng):
+    cls = _container_of(t)
+    if cls is not None:
+        return cls(**{f: random_value(ft, spec, rng) for f, ft in cls.__ssz_schema__.items()})
+    resolve = lambda n: ssz_core._resolve(n, spec)  # noqa: E731
+    if isinstance(t, ssz.Uint):
+        return rng.choice([0, (1 << t.bits) - 1, rng.randrange(1 << t.bits)])
+    if isinstance(t, ssz.Boolean):
+        return rng.random() < 0.5
+    if isinstance(t, ByteVector):
+        return rng.randbytes(resolve(t.length))
+    if isinstance(t, ByteList):
+        return rng.randbytes(rng.randint(0, min(resolve(t.limit), 40)))
+    if isinstance(t, Vector):
+        return [random_value(t.elem, spec, rng) for _ in range(resolve(t.length))]
+    if isinstance(t, List):
+        return [random_value(t.elem, spec, rng) for _ in range(rng.randint(0, min(resolve(t.limit), 3)))]
+    if isinstance(t, Bitvector):
+        return BitvectorValue.from_bools(rng.random() < 0.5 for _ in range(resolve(t.length)))
+    if isinstance(t, Bitlist):
+        n = rng.randint(0, min(resolve(t.limit), 70))
+        return BitlistValue.from_bools(rng.random() < 0.5 for _ in range(n))
+    raise AssertionError(f"no generator for {t!r}")
+
+
+def same_leaf_types(a, b):
+    if type(a) is not type(b):
+        return False
+    if isinstance(a, Container):
+        return all(same_leaf_types(getattr(a, f), getattr(b, f)) for f in type(a).__ssz_schema__)
+    if isinstance(a, list):
+        return len(a) == len(b) and all(map(same_leaf_types, a, b))
+    return True
+
+
+def sites(t, data, a, b, spec):
+    """Where a corruption can bite inside the encoding ``data[a:b]`` of a
+    valid ``t`` value: ``("offset", pos, base)`` for every 4-byte offset (it
+    points at ``base + value``), ``("boolean", pos)``, ``("bitlist", a, b)``
+    and ``("bitvector", a, b, bits)``, nested levels included."""
+    cls = _container_of(t)
+    if cls is not None:
+        pos = a
+        var = []
+        for ftype in cls.__ssz_schema__.values():
+            ft = ssz_core._typ(ftype)
+            if ft.is_fixed_size(spec):
+                size = ft.fixed_length(spec)
+                yield from sites(ftype, data, pos, pos + size, spec)
+                pos += size
+            else:
+                yield ("offset", pos, a)
+                var.append((ftype, a + int.from_bytes(data[pos : pos + OFFSET], "little")))
+                pos += OFFSET
+        for (ftype, start), end in zip(var, [s for _, s in var[1:]] + [b]):
+            yield from sites(ftype, data, start, end, spec)
+    elif isinstance(t, (Vector, List)):
+        if _container_of(t.elem) is None and not isinstance(t.elem, (ssz.Boolean, Bitlist, ByteList)):
+            return  # nothing to corrupt in a run of uints or byte vectors
+        if a == b:
+            return
+        if t.elem.is_fixed_size(spec):
+            size = t.elem.fixed_length(spec)
+            for at in range(a, b, size):
+                yield from sites(t.elem, data, at, at + size, spec)
+        else:
+            count = int.from_bytes(data[a : a + OFFSET], "little") // OFFSET
+            starts = [a + int.from_bytes(data[a + OFFSET * i : a + OFFSET * (i + 1)], "little")
+                      for i in range(count)]
+            for i in range(count):
+                yield ("offset", a + OFFSET * i, a)
+            for start, end in zip(starts, starts[1:] + [b]):
+                yield from sites(t.elem, data, start, end, spec)
+    elif isinstance(t, ssz.Boolean):
+        yield ("boolean", a)
+    elif isinstance(t, Bitlist):
+        yield ("bitlist", a, b)
+    elif isinstance(t, Bitvector):
+        yield ("bitvector", a, b, ssz_core._resolve(t.length, spec))
+
+
+def corruptions(cls, enc, spec, rng):
+    """Seeded corruptions of a valid encoding, as ``(label, bytes)``."""
+    n = len(enc)
+
+    def patched(at, new):
+        return enc[:at] + new + enc[at + len(new):]
+
+    cuts = {n - 1, cls._decode_plan(spec).fixed_len - 1, cls._decode_plan(spec).fixed_len + 1}
+    yield "append-00", enc + b"\x00"
+    yield "append-01", enc + b"\x01"
+    by_kind = {}
+    for site in sites(cls, enc, 0, n, spec):
+        by_kind.setdefault(site[0], []).append(site)
+    # a big value: every kind still, a seeded sample of each
+    found = [s for group in by_kind.values() for s in rng.sample(group, min(len(group), 16))]
+    for site in found:
+        if site[0] == "offset":
+            _, pos, base = site
+            value = int.from_bytes(enc[pos : pos + OFFSET], "little")
+            cuts.update((base + value - 1, base + value, base + value + 1))
+            for new in {value + 1, value - 1, value ^ 4, 0, n - base + 1, 0xFFFFFFFF} - {value, -1}:
+                yield f"offset@{pos}={new}", patched(pos, new.to_bytes(OFFSET, "little"))
+        elif site[0] == "boolean":
+            yield f"boolean@{site[1]}=2", patched(site[1], b"\x02")
+            yield f"boolean@{site[1]}=ff", patched(site[1], b"\xff")
+        elif site[0] == "bitlist":
+            _, a, b = site
+            top = enc[b - 1]
+            # the sentinel cleared: a shorter list, trailing zero bytes or none at all
+            yield f"bitlist@{a}-sentinel", patched(b - 1, bytes([top ^ (1 << (top.bit_length() - 1))]))
+            yield f"bitlist@{a}-zeroed", patched(a, bytes(b - a))
+            if b == n:  # the encoding's last span: more bytes lengthen it past its limit
+                limit = max(
+                    ssz_core._resolve(size, spec) for size in cls.__ssz_late_sizes__
+                ) if cls.__ssz_late_sizes__ else 4096
+                yield f"bitlist@{a}-over-limit", enc[: b - 1] + b"\xff" * (limit // 8 + 2)
+        elif site[0] == "bitvector":
+            _, a, b, bits = site
+            if bits % 8:
+                yield f"bitvector@{a}-padding", patched(b - 1, bytes([enc[b - 1] | 0x80]))
+    for cut in sorted(c for c in cuts if 0 <= c < n):
+        yield f"truncate@{cut}", enc[:cut]
+
+
+def outcome(decode, data):
+    try:
+        return decode(data)
+    except SSZError:
+        return SSZError
+
+
+CASES = [
+    pytest.param(cls, spec, id=f"{cls.__name__}-{name}")
+    for cls in TYPES_CONTAINERS
+    for name, spec in SPECS.items()
+]
+
+
+@pytest.mark.parametrize("cls, spec", CASES)
+def test_plan_decodes_what_the_schema_walk_decoded(cls, spec):
+    rng = random.Random(f"{cls.__name__}/{spec.name}")
+    for _ in range(3):
+        value = random_value(cls, spec, rng)
+        enc = value.encode(spec)
+        got = cls.decode(enc, spec)
+        want = oracle_decode(cls, enc, spec)
+        assert got == want == value
+        assert same_leaf_types(got, want)
+        assert list(got.__dict__) == list(cls.__ssz_schema__)  # filled in schema order
+        assert got.encode(spec) == enc
+
+
+@pytest.mark.parametrize("cls, spec", CASES)
+def test_plan_rejects_what_the_schema_walk_rejected(cls, spec):
+    rng = random.Random(f"{cls.__name__}/{spec.name}/corrupt")
+    enc = random_value(cls, spec, rng).encode(spec)
+    rejected = 0
+    for label, bad in corruptions(cls, enc, spec, rng):
+        got = outcome(lambda d: cls.decode(d, spec), bad)
+        want = outcome(lambda d: oracle_decode(cls, d, spec), bad)
+        assert got == want, label  # both accept, equal, or both raise SSZError
+        rejected += want is SSZError
+    # appended bytes never pass a fixed-size container; a variable one takes
+    # them into its last field or refuses them there, as the walk does
+    assert rejected >= (2 if cls.is_fixed_size(spec) else 1), rejected
+
+
+# --- the plan cache ------------------------------------------------------------
+
+
+def _plans_built(cls, kind):
+    return get_metrics().get("ssz_decode_plans_total", type=cls.__name__, kind=kind)
+
+
+@pytest.fixture
+def counting():
+    m = get_metrics()
+    was = m.enabled
+    m.set_enabled(True)
+    yield
+    m.set_enabled(was)
+
+
+def test_a_plan_is_built_once_per_type_and_spec(counting):
+    class Vote(ssz.Container):  # a class of this test: no other has decoded it
+        bits: Bitlist("MAX_VALIDATORS_PER_COMMITTEE")
+        root: ByteVector(32)
+        roots: Vector(ByteVector(32), "SLOTS_PER_HISTORICAL_ROOT")
+
+    encodings = {}
+    for name, spec in SPECS.items():
+        value = Vote(
+            bits=[True] * 5, root=b"\x07" * 32,
+            roots=[bytes([i % 256]) * 32 for i in range(spec.SLOTS_PER_HISTORICAL_ROOT)],
+        )
+        encodings[name] = value.encode(spec)
+        before = _plans_built(Vote, "mixed")
+        for _ in range(50):
+            assert Vote.decode(encodings[name], spec) == value
+        assert _plans_built(Vote, "mixed") == before + 1
+    # the vector's length differs: neither spec decodes by the other's plan
+    assert len(encodings["minimal"]) != len(encodings["mainnet"])
+    minimal, mainnet = SPECS["minimal"], SPECS["mainnet"]
+    assert Vote._decode_plan(minimal) is not Vote._decode_plan(mainnet)
+    assert Vote._decode_plan(minimal).fixed_len == 4 + 32 + 32 * 64
+    with pytest.raises(SSZError):
+        Vote.decode(encodings["mainnet"], minimal)
+    with pytest.raises(SSZError):
+        Vote.decode(encodings["minimal"], mainnet)
+    # a spec of the same NAME with another size is another plan too
+    wider = minimal.replace(SLOTS_PER_HISTORICAL_ROOT=128)
+    assert wider.name == minimal.name
+    assert Vote._decode_plan(wider).fixed_len == 4 + 32 + 32 * 128
+    assert Vote._decode_plan(minimal.replace(SECONDS_PER_SLOT=3)) is Vote._decode_plan(minimal)
+    # and the bitlist's limit is the decoding spec's own
+    tight = minimal.replace(MAX_VALIDATORS_PER_COMMITTEE=4)
+    with pytest.raises(SSZError, match="over limit"):
+        Vote.decode(encodings["minimal"], tight)
+
+
+class _Empty(ssz.Container):
+    pass
+
+
+class _OnlyVariable(ssz.Container):
+    xs: List(uint64, 8)
+    blob: ByteList(16)
+
+
+class _Wide(ssz.Container):
+    a: uint8
+    big: uint256
+    flag: boolean
+    cp: T.Checkpoint
+
+
+class _FlatOuter(ssz.Container):
+    cp: T.Checkpoint
+    flag: boolean
+    n: uint16
+
+
+class _BigOnly(ssz.Container):
+    big: uint256
+    votes: Vector(uint64, 2)
+
+
+@pytest.mark.parametrize(
+    "cls, kind, value",
+    [
+        (_Empty, "fields", _Empty()),
+        (_OnlyVariable, "fields", _OnlyVariable(xs=[1, 2**64 - 1], blob=b"\x00\x01")),
+        (_OnlyVariable, "fields", _OnlyVariable()),
+        (_BigOnly, "fields", _BigOnly(big=2**256 - 1, votes=[3, 4])),
+        (_Wide, "mixed", _Wide(a=255, big=2**255 + 1, flag=True,
+                               cp=T.Checkpoint(epoch=9, root=b"\x09" * 32))),
+        (_FlatOuter, "flat", _FlatOuter(cp=T.Checkpoint(epoch=1, root=b"\x01" * 32),
+                                        flag=False, n=513)),
+        (T.Validator, "flat", T.Validator(pubkey=b"\x05" * 48, slashed=True, exit_epoch=2**64 - 1)),
+        (T.Attestation, "mixed", T.Attestation(aggregation_bits=[False, True, False])),
+        (T.SignedAggregateAndProof, "mixed", T.SignedAggregateAndProof(signature=b"\x06" * 96)),
+    ],
+    ids=lambda v: v if isinstance(v, str) else getattr(v, "__name__", type(v).__name__),
+)
+def test_plan_kinds_and_edge_shapes(cls, kind, value, minimal):
+    assert cls.decode_plan_kind(minimal) == kind
+    enc = value.encode(minimal)
+    got = cls.decode(enc, minimal)
+    assert got == value == oracle_decode(cls, enc, minimal)
+    assert same_leaf_types(got, oracle_decode(cls, enc, minimal))
+    for bad in (enc + b"\x00", enc[:-1] if enc else b"\x01"):
+        assert outcome(lambda d: cls.decode(d, minimal), bad) == outcome(
+            lambda d: oracle_decode(cls, d, minimal), bad
+        )
+
+
+def test_list_of_flat_containers_decodes_by_the_elements_plan(minimal):
+    validators = [
+        T.Validator(pubkey=bytes([i]) * 48, slashed=bool(i % 2), effective_balance=i)
+        for i in range(70)
+    ]
+    registry = List(T.Validator, "VALIDATOR_REGISTRY_LIMIT")
+    enc = registry.serialize(validators, minimal)
+    assert registry.deserialize(enc, minimal) == validators == oracle_decode(registry, enc, minimal)
+    size = T.Validator.fixed_length(minimal)
+    bad = bytearray(enc)
+    bad[3 * size + 48 + 32 + 8] = 2  # the fourth validator's ``slashed`` byte
+    with pytest.raises(SSZError):
+        registry.deserialize(bytes(bad), minimal)
+    with pytest.raises(SSZError):
+        registry.deserialize(enc[:-1], minimal)

@@ -354,6 +354,61 @@ def test_undecodable_message_is_rejected_before_the_handler():
     assert got["gossip_verdicts_seconds"][1] == 2
 
 
+def test_drain_decodes_by_the_plan_and_counts_a_flush_once(monkeypatch):
+    """A flush of snappy+SSZ ``Attestation``s (ISSUE 31): a corrupt SSZ
+    body and a corrupt snappy body are REJECTed before the handler, the
+    rest reach it equal to what was encoded, and ``gossip_decoded_total``
+    gains their count under the type's plan kind in ONE increment."""
+    from lambda_ethereum_consensus_tpu.config import minimal_spec
+
+    spec = minimal_spec()
+    votes = [
+        Attestation(
+            aggregation_bits=[j == i for j in range(9 + i)],
+            data=AttestationData(
+                slot=i, index=i % 4, beacon_block_root=bytes([i]) * 32,
+                source=Checkpoint(epoch=i, root=b"\x01" * 32),
+                target=Checkpoint(epoch=i + 1, root=b"\x02" * 32),
+            ),
+            signature=bytes([0xA0 + i]) * 96,
+        )
+        for i in range(6)
+    ]
+    wire = [compress(v.encode(spec)) for v in votes]
+    no_sentinel = bytearray(votes[0].encode(spec))
+    no_sentinel[228:] = bytes(len(no_sentinel) - 228)  # the bitlist, zeroed
+    wire.insert(2, compress(bytes(no_sentinel)))  # m2: snappy fine, SSZ not
+    wire.insert(5, b"\xff\xff\xff not snappy")  # m5
+    seen = []
+
+    async def handler(batch):
+        seen.extend(msg.value for msg in batch)
+        return [VERDICT_ACCEPT] * len(batch)
+
+    with registry_on() as m:
+        incs = []
+        inc = m.inc
+
+        def spy(name, value=1, **labels):
+            if name == "gossip_decoded_total":
+                incs.append((value, labels))
+            inc(name, value, **labels)
+
+        monkeypatch.setattr(m, "inc", spy)
+        label = dict(topic="beacon_aggregate_and_proof", kind="mixed")
+        before = m.get("gossip_decoded_total", **label)
+        verdicts = asyncio.run(asyncio.wait_for(
+            flush_through_scheduler(wire, handler, spec), 60
+        ))
+        assert m.get("gossip_decoded_total", **label) == before + 6
+    assert verdicts[:2] == [(b"m2", VERDICT_REJECT), (b"m5", VERDICT_REJECT)]
+    assert [v for _, v in verdicts[2:]] == [VERDICT_ACCEPT] * 6
+    assert seen == votes
+    assert [type(v.data.source) for v in seen] == [Checkpoint] * 6
+    assert incs == [(6, label)]
+    assert Attestation.decode_plan_kind(spec) == "mixed"
+
+
 # ------------------------------- one frame for a drain's verdicts (ISSUE 26)
 
 BAD = b"\xff\xff\xff not snappy"
