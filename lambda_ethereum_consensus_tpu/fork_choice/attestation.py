@@ -32,7 +32,7 @@ from ..config import ChainSpec, constants, get_chain_spec
 from ..state_transition import accessors, misc
 from ..state_transition.errors import SpecError
 from ..state_transition.mutable import BeaconStateMut
-from ..telemetry import get_metrics
+from ..telemetry import get_metrics, span
 
 __all__ = [
     "EpochAttestationContext",
@@ -128,6 +128,11 @@ class EpochAttestationContext:
     """Everything attestation verification needs about one target epoch."""
 
     def __init__(self, target_state, epoch: int, spec: ChainSpec):
+        # with device_cache() below, the two halves of one epoch's build
+        with span("epoch_committees_build", part="shuffle"):
+            self._build(target_state, epoch, spec)
+
+    def _build(self, target_state, epoch: int, spec: ChainSpec) -> None:
         self.spec = spec
         self.epoch = int(epoch)
         self.state = target_state
@@ -220,13 +225,14 @@ class EpochAttestationContext:
         if self._device_cache is None:
             from ..ops.bls_batch import DeviceCommitteeCache
 
-            store = device_plane_store(self.state, self.spec)
-            self._device_cache = DeviceCommitteeCache(
-                store,
-                self.committees,
-                lengths=self.lengths,
-                chunk=min(256, max(1, self.count)),
-            )
+            with span("epoch_committees_build", part="device_cache"):
+                store = device_plane_store(self.state, self.spec)
+                self._device_cache = DeviceCommitteeCache(
+                    store,
+                    self.committees,
+                    lengths=self.lengths,
+                    chunk=min(256, max(1, self.count)),
+                )
         return self._device_cache
 
 

@@ -189,6 +189,15 @@ class BeaconNode:
         anchor_state, anchor_block, anchor_root = await self._select_anchor()
         self.store = get_forkchoice_store(anchor_state, anchor_block, spec, anchor_root=anchor_root)
         self.store.forensics = self.forensics
+        if self.device_backend is not None and self._warmer is None:
+            # no drain shapes to warm (a node that catches up), but epoch
+            # boundaries come all the same: the resident plane's programs
+            from .warmup import start_transition_warmer
+
+            self.warmer_stats = {}
+            self._warmer = start_transition_warmer(
+                len(anchor_state.validators), self.warmer_stats
+            )
         # catch the store up to wall clock immediately (ref: on_tick_now at
         # fork_choice/store.ex:65-82) so blocks are acceptable before the
         # first timer tick

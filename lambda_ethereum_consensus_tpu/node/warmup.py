@@ -29,6 +29,7 @@ __all__ = [
     "warm_kzg",
     "warm_sharded_programs",
     "warm_transition",
+    "start_transition_warmer",
     "warm_witness",
     "start_warmer",
 ]
@@ -208,6 +209,33 @@ def warm_witness() -> float:
     dt = warm_witness_programs()
     observe("warmup_phase_seconds", dt, phase="witness")
     return dt
+
+
+def start_transition_warmer(
+    n_validators: int, stats: dict | None = None
+) -> threading.Thread | None:
+    """:func:`warm_transition` alone on a daemon thread, for a node started
+    without drain shapes (a node that catches up: no gossip drain to warm).
+    It crosses an epoch boundary every 32 slots all the same, and the
+    plane's delta scatters are first dispatched at the second boundary
+    that finds a block behind it: unwarmed, those donated programs (no
+    disk tier) are lowered and compiled inside that block's import.
+    ``None`` where the resident path is off for this registry size."""
+    from ..state_transition.resident import resident_enabled
+
+    if not resident_enabled(n_validators):
+        return None
+    stats = stats if stats is not None else {}
+
+    def run():
+        try:
+            stats["transition_s"] = round(warm_transition(n_validators), 1)
+        except Exception as e:  # visible, never fatal to boot
+            stats["error"] = f"{type(e).__name__}: {e}"
+
+    t = threading.Thread(target=run, daemon=True, name="transition-warmer")
+    t.start()
+    return t
 
 
 def start_warmer(

@@ -51,6 +51,7 @@ import hashlib
 
 import numpy as np
 
+from ..telemetry import inc, span
 from .core import (
     ByteVector,
     List,
@@ -154,12 +155,13 @@ def _cap_root(levels: list[np.ndarray], limit_chunks: int) -> bytes:
 
 class _FieldCache:
     __slots__ = (
-        "strategy", "prev", "chunks", "levels", "count", "root",
+        "strategy", "name", "prev", "chunks", "levels", "count", "root",
         "last_list", "stamp_gen",
     )
 
     def __init__(self, strategy: str):
         self.strategy = strategy
+        self.name = ""  # the field it serves now (a rotated cache changes fields)
         self.prev = None  # identity snapshot (object-element strategies)
         self.chunks = None  # packed (m, 32) leaf chunks — ALWAYS levels[0]
         self.levels = None
@@ -199,6 +201,10 @@ class IncrementalStateRoot:
 
     # ------------------------------------------------------------- public
     def root(self, state, spec=None) -> bytes:
+        with span("state_root_incremental"):
+            return self._root(state, spec)
+
+    def _root(self, state, spec=None) -> bytes:
         from ..config import get_chain_spec
 
         spec = spec or get_chain_spec()
@@ -295,6 +301,7 @@ class IncrementalStateRoot:
         cache = self._fields.get(fname)
         if cache is None or cache.strategy != strategy:
             cache = self._fields[fname] = _FieldCache(strategy)
+        cache.name = fname
         if strategy == "uint":
             return self._uint_field(cache, ftype, value, spec, backend)
         return self._object_field(cache, ftype, value, spec, backend)
@@ -315,6 +322,26 @@ class IncrementalStateRoot:
                 # leaf per element, identity-diffed
                 return "object"
         return "small"
+
+    def _rebuild(self, cache: _FieldCache, leaves: np.ndarray, backend):
+        """A whole field's levels through the configured backend above the
+        device floor, else on the host; counted by where it was hashed."""
+        m = leaves.shape[0]
+        chosen = backend if m > _device_chunk_floor() else self._host
+        if m:
+            inc(
+                "state_root_rebuilt_chunks_total", m, field=cache.name,
+                where="host" if isinstance(chosen, HashlibBackend) else "device",
+            )
+        return _build_levels(leaves, chosen)
+
+    @staticmethod
+    def _repath(cache: _FieldCache, dirty: np.ndarray) -> None:
+        inc(
+            "state_root_rebuilt_chunks_total", int(dirty.shape[0]),
+            field=cache.name, where="paths",
+        )
+        _update_paths(cache.levels, dirty)
 
     def _consume_delta(self, cache: _FieldCache, value) -> frozenset | None:
         """The pushed-delta channel: a superset of the indices at which
@@ -365,8 +392,8 @@ class IncrementalStateRoot:
                         view[i] = v
                         dirty_chunks.add(i // per_chunk)
                     if dirty_chunks:
-                        _update_paths(
-                            cache.levels,
+                        self._repath(
+                            cache,
                             np.fromiter(dirty_chunks, np.int64, len(dirty_chunks)),
                         )
                 self._stamp(cache, value)
@@ -384,22 +411,18 @@ class IncrementalStateRoot:
         chunks = np.frombuffer(raw + b"\x00" * pad, np.uint8).reshape(-1, 32)
         if cache.chunks is None or cache.count != m:
             cw = chunks.copy()  # writable: the pushed-delta path edits in place
-            cache.levels = _build_levels(
-                cw, backend if m > _device_chunk_floor() else self._host
-            )
+            cache.levels = self._rebuild(cache, cw, backend)
             cache.chunks, cache.count = cw, m
         else:
             dirty = np.nonzero(np.any(cache.chunks != chunks, axis=1))[0]
             if dirty.size:
                 if dirty.size > m // _REBUILD_FRACTION:
                     cw = chunks.copy()
-                    cache.levels = _build_levels(
-                        cw, backend if m > _device_chunk_floor() else self._host
-                    )
+                    cache.levels = self._rebuild(cache, cw, backend)
                     cache.chunks = cw
                 else:
                     cache.chunks[dirty] = chunks[dirty]
-                    _update_paths(cache.levels, dirty)
+                    self._repath(cache, dirty)
         self._stamp(cache, value)
         root = _cap_root(cache.levels, limit_chunks)
         return mix_in_length(root, n) if is_list else root
@@ -433,7 +456,7 @@ class IncrementalStateRoot:
                         elem, [value[i] for i in dirty], spec, self._host
                     )
                     cache.levels[0][dirty] = sub
-                    _update_paths(cache.levels, np.asarray(dirty, np.int64))
+                    self._repath(cache, np.asarray(dirty, np.int64))
                     for i in dirty:
                         cache.prev[i] = value[i]
                 self._stamp(cache, value)
@@ -442,9 +465,7 @@ class IncrementalStateRoot:
 
         if cache.prev is None or cache.count != n:
             leaves = self._element_leaves(elem, value, spec, backend)
-            cache.levels = _build_levels(
-                leaves, backend if n > _device_chunk_floor() else self._host
-            )
+            cache.levels = self._rebuild(cache, leaves, backend)
             cache.prev, cache.count = list(value), n
         else:
             prev = cache.prev
@@ -452,15 +473,13 @@ class IncrementalStateRoot:
             if dirty:
                 if len(dirty) > max(n // _REBUILD_FRACTION, 8):
                     leaves = self._element_leaves(elem, value, spec, backend)
-                    cache.levels = _build_levels(
-                        leaves, backend if n > _device_chunk_floor() else self._host
-                    )
+                    cache.levels = self._rebuild(cache, leaves, backend)
                 else:
                     sub = self._element_leaves(
                         elem, [value[i] for i in dirty], spec, self._host
                     )
                     cache.levels[0][dirty] = sub
-                    _update_paths(cache.levels, np.asarray(dirty, np.int64))
+                    self._repath(cache, np.asarray(dirty, np.int64))
                 cache.prev = list(value)
         self._stamp(cache, value)
         root = _cap_root(cache.levels, limit_chunks)

@@ -48,7 +48,7 @@ from ..ops import shard_rules
 from ..ops.aot import aot_jit, compile_context, register_shape_bucket
 from ..ops.mesh import state_shard_enabled
 from ..ops.profile import register_plane
-from ..telemetry import observe, set_gauge
+from ..telemetry import observe, set_gauge, span
 from .math import integer_squareroot
 
 __all__ = [
@@ -985,7 +985,9 @@ def process_epoch_resident(state, plane: ResidentEpochPlane,
     ):
         plane.stats["fallbacks"] += 1
         return False
-    if not plane.sync(state, spec):
+    with span("epoch_plane_sync"):
+        synced = plane.sync(state, spec)
+    if not synced:
         plane.stats["fallbacks"] += 1
         return False
 
@@ -996,99 +998,104 @@ def process_epoch_resident(state, plane: ResidentEpochPlane,
         reg, prev_epoch, curr_epoch
     )
 
-    # device sums first, then EVERY remaining guard — no state mutation
-    # may precede a possible False return, or the host fallback would
-    # re-apply passes the resident path already ran
-    sums = plane.epoch_sums(efb_incr, active_prev, active_cur, slashed)
-    total_active = max(increment, sums[0] * increment)
-    brpi = (
-        increment * spec.BASE_REWARD_FACTOR // integer_squareroot(total_active)
-    )
-    flag_incr = [
-        max(increment, sums[1 + f] * increment) // increment for f in range(3)
-    ]
-    # probe with in_leak=False (the LARGER table values; the leak
-    # variant zeroes rewards) so the overflow guard can run before
-    # justification mutates the state
-    luts = _reward_tables(
-        spec, brpi, False, total_active // increment, flag_incr
-    )
-    if luts is None:
-        plane.stats["fallbacks"] += 1
-        return False
-
-    # (1) justification and finalization, from the device sums
-    if curr_epoch > constants.GENESIS_EPOCH + 1:
-        weigh_justification_and_finalization(
-            state,
-            total_active,
-            max(increment, sums[2] * increment),
-            max(increment, sums[4] * increment),
-            spec,
+    # one span from the first dispatch (the sums) to the last fetched
+    # result (the hysteresis mask): the donated sweep runs on the device
+    # under the host's justification, registry updates and slashings
+    with span("epoch_plane_sweep"):
+        # device sums first, then EVERY remaining guard — no state mutation
+        # may precede a possible False return, or the host fallback would
+        # re-apply passes the resident path already ran
+        sums = plane.epoch_sums(efb_incr, active_prev, active_cur, slashed)
+        total_active = max(increment, sums[0] * increment)
+        brpi = (
+            increment * spec.BASE_REWARD_FACTOR // integer_squareroot(total_active)
         )
-
-    # (2)+(3) inactivity updates + rewards/penalties, one donated sweep.
-    # in_leak reads the finalized checkpoint just/fin may have moved.
-    in_leak = accessors.is_in_inactivity_leak(state, spec)
-    do_epoch = curr_epoch != constants.GENESIS_EPOCH
-    if in_leak:
+        flag_incr = [
+            max(increment, sums[1 + f] * increment) // increment for f in range(3)
+        ]
+        # probe with in_leak=False (the LARGER table values; the leak
+        # variant zeroes rewards) so the overflow guard can run before
+        # justification mutates the state
         luts = _reward_tables(
-            spec, brpi, True, total_active // increment, flag_incr
+            spec, brpi, False, total_active // increment, flag_incr
         )
-    mult, shift = factors
-    plane.sweep(
-        efb_incr, eligible, active_prev, slashed,
-        [
-            int(in_leak), int(do_epoch), int(do_epoch),
-            spec.INACTIVITY_SCORE_BIAS, spec.INACTIVITY_SCORE_RECOVERY_RATE,
-            mult, shift,
-        ],
-        luts,
-    )
+        if luts is None:
+            plane.stats["fallbacks"] += 1
+            return False
 
-    # (4) registry updates: sequential churn/queue logic, host exact
-    process_registry_updates(state, spec)
+        # (1) justification and finalization, from the device sums
+        if curr_epoch > constants.GENESIS_EPOCH + 1:
+            weigh_justification_and_finalization(
+                state,
+                total_active,
+                max(increment, sums[2] * increment),
+                max(increment, sums[4] * increment),
+                spec,
+            )
 
-    # (5) slashings: rare targets, exact >64-bit host arithmetic
-    targets = np.nonzero(
-        slashed
-        & (curr_epoch + spec.EPOCHS_PER_SLASHINGS_VECTOR // 2
-           == reg["withdrawable_epoch"])
-    )[0]
-    if targets.size:
-        adjusted_total = min(
-            sum(state.slashings) * spec.PROPORTIONAL_SLASHING_MULTIPLIER_BELLATRIX,
-            total_active,
-        )
-        plane.slash_fixup(targets, efb_incr, adjusted_total, total_active, increment)
-
-    process_eth1_data_reset(state, spec)
-
-    # (7) effective-balance hysteresis: device mask, host fixups.  The
-    # mask reads the post-sweep/post-slashing resident balances.
-    mask = plane.hysteresis_mask(
-        efb_incr,
-        increment // spec.HYSTERESIS_QUOTIENT * spec.HYSTERESIS_DOWNWARD_MULTIPLIER,
-        increment // spec.HYSTERESIS_QUOTIENT * spec.HYSTERESIS_UPWARD_MULTIPLIER,
-        increment,
-    )
-    balances = plane.balances_to_host()
-    scores = plane.scores_to_host()
-    for i in np.nonzero(mask)[0]:
-        b = int(balances[i])
-        state.update_validator(
-            int(i),
-            effective_balance=min(b - b % increment, spec.MAX_EFFECTIVE_BALANCE),
+        # (2)+(3) inactivity updates + rewards/penalties, one donated sweep.
+        # in_leak reads the finalized checkpoint just/fin may have moved.
+        in_leak = accessors.is_in_inactivity_leak(state, spec)
+        do_epoch = curr_epoch != constants.GENESIS_EPOCH
+        if in_leak:
+            luts = _reward_tables(
+                spec, brpi, True, total_active // increment, flag_incr
+            )
+        mult, shift = factors
+        plane.sweep(
+            efb_incr, eligible, active_prev, slashed,
+            [
+                int(in_leak), int(do_epoch), int(do_epoch),
+                spec.INACTIVITY_SCORE_BIAS, spec.INACTIVITY_SCORE_RECOVERY_RATE,
+                mult, shift,
+            ],
+            luts,
         )
 
-    # the deltas flow back: balances/scores lists adopt the device
-    # results (the incremental engine rebuilds those two columns through
-    # its backend), participation rotates structurally on all three
-    # tiers — host lists, root-engine subtrees, resident buffers.
-    state.set_balances(balances)
-    state.inactivity_scores = [int(s) for s in scores]
-    plane.mirror_bal = balances.copy()
-    plane.mirror_scores = scores.copy()
+        # (4) registry updates: sequential churn/queue logic, host exact
+        process_registry_updates(state, spec)
+
+        # (5) slashings: rare targets, exact >64-bit host arithmetic
+        targets = np.nonzero(
+            slashed
+            & (curr_epoch + spec.EPOCHS_PER_SLASHINGS_VECTOR // 2
+               == reg["withdrawable_epoch"])
+        )[0]
+        if targets.size:
+            adjusted_total = min(
+                sum(state.slashings) * spec.PROPORTIONAL_SLASHING_MULTIPLIER_BELLATRIX,
+                total_active,
+            )
+            plane.slash_fixup(targets, efb_incr, adjusted_total, total_active, increment)
+
+        process_eth1_data_reset(state, spec)
+
+        # (7) effective-balance hysteresis: device mask, host fixups.  The
+        # mask reads the post-sweep/post-slashing resident balances.
+        mask = plane.hysteresis_mask(
+            efb_incr,
+            increment // spec.HYSTERESIS_QUOTIENT * spec.HYSTERESIS_DOWNWARD_MULTIPLIER,
+            increment // spec.HYSTERESIS_QUOTIENT * spec.HYSTERESIS_UPWARD_MULTIPLIER,
+            increment,
+        )
+    with span("epoch_writeback"):
+        balances = plane.balances_to_host()
+        scores = plane.scores_to_host()
+        for i in np.nonzero(mask)[0]:
+            b = int(balances[i])
+            state.update_validator(
+                int(i),
+                effective_balance=min(b - b % increment, spec.MAX_EFFECTIVE_BALANCE),
+            )
+
+        # the deltas flow back: balances/scores lists adopt the device
+        # results (the incremental engine rebuilds those two columns through
+        # its backend), participation rotates structurally on all three
+        # tiers — host lists, root-engine subtrees, resident buffers.
+        state.set_balances(balances)
+        state.inactivity_scores = [int(s) for s in scores]
+        plane.mirror_bal = balances.copy()
+        plane.mirror_scores = scores.copy()
 
     process_slashings_reset(state, spec)
     process_randao_mixes_reset(state, spec)

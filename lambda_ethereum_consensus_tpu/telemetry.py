@@ -114,6 +114,12 @@ _HELP = {
     "state_encode_fields_total": "big fields of a stored state by how their encoded image was brought level: reused (no delta), patched (logged elements re-serialized), rebuilt (column-wise full build: no chain to vouch)",
     "block_transition_seconds": "full state transition of one block (slots + block + state-root check)",
     "epoch_transition_seconds": "one epoch-boundary processing pass (resident or host path)",
+    "epoch_plane_sync_seconds": "resident epoch path: the plane's sync, which ships the columns' deltas since the last boundary (first boundary of a lineage: the full upload)",
+    "epoch_plane_sweep_seconds": "resident epoch path: first dispatch (the epoch sums) to the last fetched result (the hysteresis mask); the donated sweep runs under the host's justification, registry updates and slashings",
+    "epoch_writeback_seconds": "resident epoch path: balances and scores fetched to the host, effective-balance fix-ups, the two lists and the mirrors replaced",
+    "epoch_committees_build_seconds": "one epoch's attestation context built, by part: shuffle (active set, swap-or-not permutation, committee table) and device_cache (registry planes grown, committee sums on the device)",
+    "state_root_incremental_seconds": "one IncrementalStateRoot.root call: every slot's root and every block's state-root check",
+    "state_root_rebuilt_chunks_total": "leaves of a big state field hashed by the incremental root, by field and where: device or host (a whole-field rebuild through the configured backend or hashlib: the device floor decides) or paths (dirty leaves re-hashed up their paths on the host)",
     "resident_plane_validators": "validators held as resident device columns by the transition plane",
     "resident_plane_sync_elems": "cumulative per-epoch delta elements scattered to the resident columns",
     "fork_choice_head_recompute_seconds": "uncached LMD-GHOST head walk",
