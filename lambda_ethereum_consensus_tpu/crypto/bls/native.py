@@ -188,7 +188,8 @@ def hash_to_g2_batch(msgs: list[bytes], dst: bytes):
     lens = (ctypes.c_size_t * n)(*[len(m) for m in msgs])
     out = ctypes.create_string_buffer(192 * n)
     _LIB.bls381_hash_to_g2_batch(b"".join(msgs), lens, n, dst, len(dst), out, 0)
-    return [_g2_from(out.raw[i * 192 : (i + 1) * 192]) for i in range(n)]
+    points = out.raw  # .raw copies the whole buffer: once, not per point
+    return [_g2_from(points[i * 192 : (i + 1) * 192]) for i in range(n)]
 
 
 def rlc_available() -> bool:
@@ -242,10 +243,12 @@ def _decompress_batch(fn, insz: int, outsz: int, blobs, subgroup_check, from_buf
     out = ctypes.create_string_buffer(outsz * m)
     ok = ctypes.create_string_buffer(m)
     fn(buf, m, out, ok, 1 if subgroup_check else 0, 0)
+    # a ctypes buffer's .raw copies the WHOLE buffer per access: once each
+    points, flags = out.raw, ok.raw
     for j, i in enumerate(keep):
-        flag = ok.raw[j]
+        flag = flags[j]
         if flag == 1:
-            res[i] = from_buf(out.raw[j * outsz : (j + 1) * outsz])
+            res[i] = from_buf(points[j * outsz : (j + 1) * outsz])
         elif flag == 2:
             res[i] = None  # canonical infinity (g*_from_bytes semantics)
     return res

@@ -104,33 +104,25 @@ static inline void fp_neg(Fp& out, const Fp& a) {
     }
 }
 
-// Montgomery multiplication (CIOS)
+// Montgomery multiplication (CIOS; p's top limb is under 2^63, so the two
+// carry chains of a row close into one limb and no t[6], t[7] is needed).
+// Needs a < p; b may be any 384-bit value: every row leaves t < 2p.
 static void fp_mul(Fp& out, const Fp& a, const Fp& b) {
-    u64 t[NLIMBS + 2] = {0};
+    u64 t[NLIMBS] = {0};
+#pragma GCC unroll 6
     for (int i = 0; i < NLIMBS; i++) {
-        u128 carry = 0;
-        for (int j = 0; j < NLIMBS; j++) {
-            u128 s = (u128)t[j] + (u128)a.l[j] * b.l[i] + carry;
-            t[j] = (u64)s;
-            carry = s >> 64;
-        }
-        u128 s = (u128)t[NLIMBS] + carry;
-        t[NLIMBS] = (u64)s;
-        t[NLIMBS + 1] = (u64)(s >> 64);
-
-        u64 m = t[0] * P_INV;
-        carry = ((u128)t[0] + (u128)m * P[0]) >> 64;
+        u128 A = (u128)a.l[0] * b.l[i] + t[0];
+        u64 m = (u64)A * P_INV;
+        u128 C = (u128)m * P[0] + (u64)A;
         for (int j = 1; j < NLIMBS; j++) {
-            u128 s2 = (u128)t[j] + (u128)m * P[j] + carry;
-            t[j - 1] = (u64)s2;
-            carry = s2 >> 64;
+            A = (u128)a.l[j] * b.l[i] + t[j] + (u64)(A >> 64);
+            C = (u128)m * P[j] + (u64)A + (u64)(C >> 64);
+            t[j - 1] = (u64)C;
         }
-        s = (u128)t[NLIMBS] + carry;
-        t[NLIMBS - 1] = (u64)s;
-        t[NLIMBS] = t[NLIMBS + 1] + (u64)(s >> 64);
+        t[NLIMBS - 1] = (u64)(C >> 64) + (u64)(A >> 64);
     }
     for (int i = 0; i < NLIMBS; i++) out.l[i] = t[i];
-    if (t[NLIMBS] || fp_cmp_p(out) >= 0) {
+    if (fp_cmp_p(out) >= 0) {
         u64 borrow = 0;
         for (int i = 0; i < NLIMBS; i++) {
             u128 d = (u128)out.l[i] - P[i] - borrow;
@@ -146,18 +138,43 @@ static const Fp FP_ZERO = {{0, 0, 0, 0, 0, 0}};
 
 static Fp FP_ONE;  // R mod p (Montgomery one), initialized below
 
-static void fp_pow(Fp& out, const Fp& base, const u64* exp, int explimbs) {
-    Fp result = FP_ONE;
-    Fp b = base;
-    for (int i = 0; i < explimbs; i++) {
-        u64 e = exp[i];
-        for (int bit = 0; bit < 64; bit++) {
-            if (e & 1) fp_mul(result, result, b);
-            fp_sq(b, b);
-            e >>= 1;
-        }
+// base^e by a fixed 4-bit window; digit(i) is e's i-th base-16 digit,
+// most significant first (the exponents are public: no constant time)
+template <class Digit>
+static void fp_pow_window(Fp& out, const Fp& base, size_t ndigits, Digit digit) {
+    Fp tbl[16];
+    tbl[1] = base;
+    for (int k = 2; k < 16; k++) {
+        if (k & 1)
+            fp_mul(tbl[k], tbl[k - 1], base);
+        else
+            fp_sq(tbl[k], tbl[k / 2]);
     }
-    out = result;
+    size_t i = 0;
+    while (i < ndigits && digit(i) == 0) i++;
+    if (i == ndigits) {
+        out = FP_ONE;
+        return;
+    }
+    Fp acc = tbl[digit(i++)];
+    for (; i < ndigits; i++) {
+        fp_sq(acc, acc);
+        fp_sq(acc, acc);
+        fp_sq(acc, acc);
+        fp_sq(acc, acc);
+        unsigned d = digit(i);
+        if (d) fp_mul(acc, acc, tbl[d]);
+    }
+    out = acc;
+}
+
+// exp as little-endian limbs
+static void fp_pow(Fp& out, const Fp& base, const u64* exp, int explimbs) {
+    size_t nd = (size_t)explimbs * 16;
+    fp_pow_window(out, base, nd, [&](size_t i) {
+        size_t k = nd - 1 - i;  // digit index from the least significant
+        return (unsigned)(exp[k / 16] >> (4 * (k % 16))) & 15u;
+    });
 }
 
 // p - 2, for inversion by Fermat
@@ -192,7 +209,7 @@ static void fp_from_bytes(Fp& out, const uint8_t* be48) {
     }
     Fp r2;
     memcpy(r2.l, R2, sizeof(R2));
-    fp_mul(out, raw, r2);  // to Montgomery form
+    fp_mul(out, r2, raw);  // to Montgomery form (raw may be >= p: second operand)
 }
 
 static void fp_to_bytes(uint8_t* be48, const Fp& a) {
@@ -1031,6 +1048,7 @@ static Fq2 SSWU_A, SSWU_B, SSWU_Z;       // E2' params: A'=(0,240) B'=(1012,1012
 static Fq2 ISO_XN[4], ISO_XD[3], ISO_YN[4], ISO_YD[4];
 static Fp INV2;                          // 1/2
 static u64 P_PLUS_1_DIV_4[NLIMBS];       // fq sqrt exponent (p ≡ 3 mod 4)
+static u64 P_MINUS_3_DIV_4[NLIMBS];      // (p+1)/4 - 1: fq2 sqrt's 1/sqrt exponent
 static Fp G1_GEN_NEG_X, G1_GEN_NEG_Y;    // -G1 generator (for RLC checks)
 static Fq2 PSI_CX, PSI_CY;               // G2 endomorphism ψ coefficients
 static Fq2 SSWU_NB_DIV_A, SSWU_B_DIV_ZA; // -B'/A', B'/(Z·A') precomputed
@@ -1140,7 +1158,12 @@ static bool fq_sqrt(Fp& out, const Fp& a) {
     return true;
 }
 
-// sqrt in Fq2 via the complex method (mirrors fields.py::fq2_sqrt)
+// sqrt in Fq2 via the complex method (the roots of fields.py::fq2_sqrt up to
+// sign: every caller fixes the sign itself), in two exponentiations: with
+// delta = (a0 + sqrt(norm))/2 and w = delta^((p-3)/4), x0 = w*delta has
+// x0^2 = +-delta and 1/x0 = +-w (+ when delta is a residue).  A residue
+// gives the root (x0, a1/(2 x0)); a non-residue gives x0^2 = (s - a0)/2 —
+// the imaginary part of the other candidate — and the root (a1/(2 x0), x0).
 static bool fq2_sqrt(Fq2& out, const Fq2& a) {
     if (fp_is_zero(a.c1)) {
         Fp s;
@@ -1163,19 +1186,22 @@ static bool fq2_sqrt(Fq2& out, const Fq2& a) {
     fp_sq(t, a.c1);
     fp_add(alpha, alpha, t);  // norm
     if (!fq_sqrt(s, alpha)) return false;
-    Fp delta, x0;
+    Fp delta, w, x0, x1;
     fp_add(delta, a.c0, s);
     fp_mul(delta, delta, INV2);
-    if (!fq_sqrt(x0, delta)) {
-        fp_sub(delta, a.c0, s);
-        fp_mul(delta, delta, INV2);
-        if (!fq_sqrt(x0, delta)) return false;
-    }
-    Fp x0inv, x1;
-    fp_inv(x0inv, x0);
+    fp_pow(w, delta, P_MINUS_3_DIV_4, NLIMBS);
+    fp_mul(x0, w, delta);
+    fp_mul(t, x0, w);  // delta^((p-1)/2): 1 for a residue, else -1
     fp_mul(x1, a.c1, INV2);
-    fp_mul(x1, x1, x0inv);
-    Fq2 cand = {x0, x1}, sq;
+    fp_mul(x1, x1, w);  // a1/(2 x0) up to that same sign
+    Fq2 cand, sq;
+    if (fp_eq(t, FP_ONE)) {
+        cand.c0 = x0;
+        cand.c1 = x1;
+    } else {
+        fp_neg(cand.c0, x1);
+        cand.c1 = x0;
+    }
     fq2_sq(sq, cand);
     if (!fq2_eq(sq, a)) return false;
     out = cand;
@@ -1226,6 +1252,8 @@ static void h2c_init() {
         P_PLUS_1_DIV_4[i] = (u64)(cur / 4);
         rem = cur % 4;
     }
+    memcpy(P_MINUS_3_DIV_4, P_PLUS_1_DIV_4, sizeof(P_PLUS_1_DIV_4));
+    P_MINUS_3_DIV_4[0] -= 1;  // no borrow: (p+1)/4 ends ...aaab
     // h_eff bytes
     size_t hl = strlen(H_EFF_HEX);
     H_EFF_LEN = (hl + 1) / 2;
@@ -1648,14 +1676,9 @@ void bls381_fp_powmod(uint8_t* out48, const uint8_t* base48,
     bls381_init();
     Fp base, acc;
     fp_from_bytes(base, base48);
-    acc = FP_ONE;
-    for (size_t i = 0; i < exp_len; i++) {
-        uint8_t byte = exp[i];
-        for (int bit = 7; bit >= 0; bit--) {
-            fp_sq(acc, acc);
-            if ((byte >> bit) & 1) fp_mul(acc, acc, base);
-        }
-    }
+    fp_pow_window(acc, base, exp_len * 2, [&](size_t i) {
+        return (unsigned)(exp[i / 2] >> ((i & 1) ? 0 : 4)) & 15u;
+    });
     fp_to_bytes(out48, acc);
 }
 

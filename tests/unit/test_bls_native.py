@@ -6,6 +6,7 @@ divergence would go unnoticed.  Every test here runs both paths on the same
 inputs and requires identical results.
 """
 
+import functools
 import random
 
 import pytest
@@ -22,6 +23,18 @@ pytestmark = pytest.mark.skipif(
 )
 
 RNG = random.Random(1234)
+
+
+@pytest.fixture
+def pure_python(monkeypatch):
+    """Takes the native library out from under fields.py and curve.py: the
+    Python oracle then shares no code with what it is compared with."""
+    monkeypatch.setattr(F, "_fq_powmod", lambda base, exp: pow(base, exp, F.P))
+    object.__setattr__(C.g1, "native_mul", None)
+    object.__setattr__(C.g2, "native_mul", None)
+    yield
+    object.__setattr__(C.g1, "native_mul", native.g1_mul)
+    object.__setattr__(C.g2, "native_mul", native.g2_mul)
 
 
 def python_pairing_check(pairs) -> bool:
@@ -80,24 +93,18 @@ def test_pairing_check_matches_python(seed):
     assert python_pairing_check(bad) is False
 
 
-def test_verify_same_through_both_paths(monkeypatch):
+def test_verify_same_through_both_paths(request, monkeypatch):
     sk = b"\x2a" * 32
     pk = bls.sk_to_pk(sk)
     sig = bls.sign(sk, b"both paths")
     assert bls.verify(pk, b"both paths", sig)
     assert not bls.verify(pk, b"other", sig)
     # force the pure-Python path everywhere and require identical verdicts
+    request.getfixturevalue("pure_python")
     monkeypatch.setattr(native, "_LIB", None)
-    monkeypatch.setattr(F, "_fq_powmod", lambda base, exp: pow(base, exp, F.P))
-    object.__setattr__(C.g1, "native_mul", None)
-    object.__setattr__(C.g2, "native_mul", None)
-    try:
-        assert not native.available()
-        assert bls.verify(pk, b"both paths", sig)
-        assert not bls.verify(pk, b"other", sig)
-    finally:
-        object.__setattr__(C.g1, "native_mul", native.g1_mul)
-        object.__setattr__(C.g2, "native_mul", native.g2_mul)
+    assert not native.available()
+    assert bls.verify(pk, b"both paths", sig)
+    assert not bls.verify(pk, b"other", sig)
 
 
 # ---------------------------------------------------------- hash_to_g2
@@ -287,3 +294,258 @@ class TestDecompressBatch:
         assert g2_from_bytes_batch([C.g2_to_bytes(C.G2_GENERATOR)]) == [
             C.G2_GENERATOR
         ]
+
+
+# ------------------------------------- the field kernel under decompression
+#
+# fq2_sqrt (two exponentiations, shared with SSWU), the windowed fp_pow
+# (fp_inv and fp_powmod ride it) and the Montgomery multiply are held to the
+# PURE-Python oracle (``pure_python``), so a fault in the library cannot
+# agree with itself.
+
+
+def _oracle_g2(blob: bytes, subgroup_check: bool = True):
+    try:
+        return C.g2_from_bytes(blob, subgroup_check)
+    except C.DeserializationError:
+        return False
+
+
+def _g2_blob(x: F.Fq2, sign: bool) -> bytes:
+    raw = bytearray(x[1].to_bytes(48, "big") + x[0].to_bytes(48, "big"))
+    raw[0] |= 0x80 | (0x20 if sign else 0)
+    return bytes(raw)
+
+
+def _g2_y2(x: F.Fq2) -> F.Fq2:
+    return F.fq2_add(F.fq2_mul(F.fq2_sq(x), x), (4, 4))
+
+
+def _root_branch(blob: bytes) -> str:
+    """The branch of the native fq2_sqrt this encoding's y^2 takes: ``real``
+    (c1 == 0), else by the Legendre symbol of delta = (a0 + sqrt(norm))/2."""
+    x1 = int.from_bytes(blob[:48], "big") & ((1 << 381) - 1)
+    a0, a1 = _g2_y2((int.from_bytes(blob[48:], "big"), x1))
+    if a1 == 0:
+        return "real"
+    s = pow((a0 * a0 + a1 * a1) % F.P, (F.P + 1) // 4, F.P)
+    delta = (a0 + s) * ((F.P + 1) // 2) % F.P
+    return "residue" if pow(delta, (F.P - 1) // 2, F.P) == 1 else "non_residue"
+
+
+@functools.lru_cache(maxsize=None)
+def _signatures(n: int, tag: bytes) -> tuple[bytes, ...]:
+    """n seeded signatures sk_i * H(m_i), compressed by the Python encoder."""
+    from lambda_ethereum_consensus_tpu.crypto.bls.hash_to_curve import DST_POP
+
+    rng = random.Random(tag)
+    hs = native.hash_to_g2_batch([tag + b"-%d" % i for i in range(n)], DST_POP)
+    return tuple(C.g2_to_bytes(native.g2_mul(h, rng.randrange(1, R))) for h in hs)
+
+
+def _first_x(rng, want_root: bool) -> F.Fq2:
+    """First seeded x whose y^2 has (or has not) a root in Fq2."""
+    while True:
+        x = (rng.randrange(F.P), rng.randrange(F.P))
+        if (F.fq2_sqrt(_g2_y2(x)) is not None) == want_root:
+            return x
+
+
+def _x_real() -> F.Fq2:
+    """Smallest x = (x0, 0) on the twist."""
+    return next(
+        (x0, 0) for x0 in range(1, 64) if F.fq2_sqrt(_g2_y2((x0, 0))) is not None
+    )
+
+
+def _x_with_real_y2() -> F.Fq2:
+    """x = (a, b) with Im(x^3) = 3a^2 b - b^3 = -4, so y^2 is real and the
+    root takes fq2_sqrt's c1 == 0 branch (a real always has a root in Fq2)."""
+    for b in range(1, 64):
+        a2 = (b**3 - 4) * pow(3 * b, F.P - 2, F.P) % F.P
+        a = F.fq_sqrt(a2)
+        if a is not None:
+            assert _g2_y2((a, b))[1] == 0
+            return (a, b)
+    raise AssertionError("no x with a real y^2 among b < 64")
+
+
+_INFINITY = bytes([0xC0]) + b"\x00" * 95
+
+# name -> (blobs, what the oracle must say with the subgroup check on)
+_G2_EDGE_CASES = {
+    "y2_non_square": (
+        lambda: [_g2_blob(_first_x(random.Random(5), False), s) for s in (0, 1)],
+        "rejected",
+    ),
+    "on_curve_off_subgroup": (
+        lambda: [_g2_blob(_first_x(random.Random(6), True), s) for s in (0, 1)],
+        "rejected",
+    ),
+    "x_c1_zero": (lambda: [_g2_blob(_x_real(), s) for s in (0, 1)], "rejected"),
+    "y2_c1_zero": (
+        lambda: [_g2_blob(_x_with_real_y2(), s) for s in (0, 1)],
+        "rejected",
+    ),
+    "infinity": (lambda: [_INFINITY], "infinity"),
+    "infinity_sign_bit": (lambda: [bytes([0xE0]) + b"\x00" * 95], "rejected"),
+    "infinity_low_bits": (lambda: [bytes([0xC1]) + b"\x00" * 95], "rejected"),
+    "infinity_trailing_byte": (
+        lambda: [_INFINITY[:95] + b"\x01", _INFINITY[:48] + b"\x80" + b"\x00" * 47],
+        "rejected",
+    ),
+    "x_c1_not_below_p": (
+        lambda: [_g2_blob((7, F.P), 0), _g2_blob((7, (1 << 381) - 1), 1)],
+        "rejected",
+    ),
+    "x_c0_not_below_p": (
+        lambda: [_g2_blob((F.P, 7), 0), _g2_blob(((1 << 384) - 1, 7), 1)],
+        "rejected",
+    ),
+    "no_compression_bit": (
+        lambda: [
+            bytes([b[0] & 0x7F]) + b[1:] for b in _signatures(2, b"nobit")
+        ],
+        "rejected",
+    ),
+}
+
+
+@pytest.mark.skipif(
+    not native.decompress_available(), reason="decompress entry points absent"
+)
+class TestDecompressKernel:
+    CHUNKS, PER_CHUNK = 8, 32  # 256 seeded signatures
+
+    @pytest.mark.parametrize("chunk", range(CHUNKS))
+    def test_valid_signatures_match_python(self, chunk, pure_python):
+        sigs = _signatures(self.CHUNKS * self.PER_CHUNK, b"kernel")
+        part = sigs[chunk * self.PER_CHUNK : (chunk + 1) * self.PER_CHUNK]
+        got = native.g2_decompress_batch(part)
+        want = [C.g2_from_bytes(b) for b in part]  # raises on a rejection
+        assert got == want
+
+    def test_valid_corpus_covers_both_roots_and_signs(self):
+        sigs = _signatures(self.CHUNKS * self.PER_CHUNK, b"kernel")
+        seen = {(_root_branch(b), bool(b[0] & 0x20)) for b in sigs}
+        assert seen >= {
+            (branch, sign)
+            for branch in ("residue", "non_residue")
+            for sign in (False, True)
+        }
+
+    @pytest.mark.parametrize("subgroup_check", [True, False])
+    @pytest.mark.parametrize("name", sorted(_G2_EDGE_CASES))
+    def test_edge_encodings_match_python(self, name, subgroup_check, pure_python):
+        build, expect = _G2_EDGE_CASES[name]
+        blobs = build()
+        want = [_oracle_g2(b, subgroup_check) for b in blobs]
+        if subgroup_check:
+            assert want == [None if expect == "infinity" else False] * len(blobs)
+        if name in ("on_curve_off_subgroup", "x_c1_zero", "y2_c1_zero"):
+            # on the twist: only the subgroup check turns them away
+            assert subgroup_check or all(isinstance(w, tuple) for w in want)
+        if name == "y2_c1_zero":
+            assert {_root_branch(b) for b in blobs} == {"real"}
+        assert native.g2_decompress_batch(blobs, subgroup_check) == want
+
+    def test_wrong_length_items_fail_alone(self):
+        sigs = _signatures(6, b"lengths")
+        pts = native.g2_decompress_batch(sigs)
+        assert all(isinstance(p, tuple) for p in pts)
+        batch = [
+            sigs[0], sigs[1][:95], sigs[2], sigs[3] + b"\x00", b"",
+            bytearray(sigs[4]), _INFINITY, sigs[5],
+        ]
+        assert native.g2_decompress_batch(batch) == [
+            pts[0], False, pts[2], False, False, pts[4], None, pts[5]
+        ]
+        assert native.g2_decompress_batch([b"", sigs[0][:10]]) == [False, False]
+
+    def test_batch_of_4096_equals_its_quarters(self):
+        n, q = 4096, 1024
+        blobs = [
+            C.g2_to_bytes(native.g2_mul(C.G2_GENERATOR, 3 + k)) for k in range(n)
+        ]
+        # every kind of slot next to each quarter's edge
+        for edge in (0, q, 2 * q, 3 * q):
+            blobs[edge + 1] = _INFINITY
+            blobs[edge + q - 2] = blobs[edge + q - 2][:-1]
+            blobs[edge + q - 1] = bytes([0xE0]) + b"\x00" * 95
+        whole = native.g2_decompress_batch(blobs)
+        apart = []
+        for edge in (0, q, 2 * q, 3 * q):
+            apart += native.g2_decompress_batch(blobs[edge : edge + q])
+        assert whole == apart
+        assert len(set(p for p in whole if isinstance(p, tuple))) == n - 12
+        for k in (0, 2, q - 3, q, n - 3):
+            assert whole[k] == C.g2_from_bytes(blobs[k])
+
+
+def _sswu_first_branch(u: F.Fq2) -> bool:
+    """Whether g(x1) is a square for this u (else SSWU takes x2 = Z u^2 x1)."""
+    from lambda_ethereum_consensus_tpu.crypto.bls import hash_to_curve as H
+
+    zu2 = F.fq2_mul(H._Z, F.fq2_sq(u))
+    x1 = F.fq2_mul(
+        F.fq2_mul(F.fq2_neg(H._B), F.fq2_inv(H._A)),
+        F.fq2_add(F.FQ2_ONE, F.fq2_inv(F.fq2_add(F.fq2_sq(zu2), zu2))),
+    )
+    gx1 = F.fq2_add(F.fq2_add(F.fq2_mul(F.fq2_sq(x1), x1), F.fq2_mul(H._A, x1)), H._B)
+    return F.fq2_sqrt(gx1) is not None
+
+
+@pytest.mark.skipif(
+    not native.hash_available(), reason="native hash_to_g2 not built"
+)
+class TestHashToG2Kernel:
+    CHUNKS, PER_CHUNK = 4, 8  # 32 seeded messages
+
+    @staticmethod
+    def _messages(chunk: int) -> list[bytes]:
+        rng = random.Random(7000 + chunk)
+        return [rng.randbytes(rng.randrange(0, 96)) for _ in range(8)]
+
+    @pytest.mark.parametrize("chunk", range(CHUNKS))
+    def test_batch_matches_python_hash_to_g2(self, chunk, pure_python, monkeypatch):
+        from lambda_ethereum_consensus_tpu.crypto.bls import hash_to_curve as H
+
+        msgs = self._messages(chunk)
+        got = native.hash_to_g2_batch(msgs, H.DST_POP)
+        monkeypatch.setattr(native, "hash_available", lambda: False)
+        assert got == [H.hash_to_g2(m) for m in msgs]
+
+    def test_messages_take_both_sswu_branches(self):
+        from lambda_ethereum_consensus_tpu.crypto.bls import hash_to_curve as H
+
+        branches = {
+            _sswu_first_branch(u)
+            for chunk in range(self.CHUNKS)
+            for m in self._messages(chunk)
+            for u in H.hash_to_field_fq2(m, 2, H.DST_POP)
+        }
+        assert branches == {True, False}
+
+
+_POW_EXPONENTS = {
+    "zero": 0,
+    "one": 1,
+    "p_minus_2": F.P - 2,
+    "sqrt_exponent": (F.P + 1) // 4,
+    "inverse_sqrt_exponent": (F.P - 3) // 4,
+    "low_digits_zero": 0xABC << 372,
+    "longer_than_the_field": (1 << 400) + 0x1234567,
+}
+
+
+@pytest.mark.parametrize("name", sorted(_POW_EXPONENTS) + ["random_381_bit"])
+def test_fp_powmod_windowed_matches_pow(name):
+    rng = random.Random(name)
+    bases = [0, 1, 2, F.P - 1, F.P + 5, (1 << 384) - 1]  # the last two unreduced
+    bases += [rng.randrange(F.P) for _ in range(10)]
+    for base in bases:
+        if name == "random_381_bit":
+            exp = rng.getrandbits(381) | (1 << 380)
+        else:
+            exp = _POW_EXPONENTS[name]
+        assert native.fp_powmod(base, exp) == pow(base, exp, F.P), (base, exp)
