@@ -759,6 +759,7 @@ class BeaconApiServer:
         Offloaded route: the table snapshot copies under ops/aot._LOCK."""
         from ..ops import profile as ops_profile
         from ..ops.aot import all_shape_buckets, aot_stats, compile_profile
+        from ..ops.bls_batch import warmed_chain_layouts
 
         rows = compile_profile()
         for row in rows:
@@ -776,6 +777,8 @@ class BeaconApiServer:
                     "witness_verify": [],
                     **{k: list(v) for k, v in all_shape_buckets().items()},
                 },
+                # the BLS chain pads a smaller call up to these
+                "warmed_chain_layouts": [w._asdict() for w in warmed_chain_layouts()],
                 "executables": rows,
             }
         })

@@ -14,10 +14,11 @@ seed, ref: lib/lambda_ethereum_consensus/state_transition/misc.ex feeding
 - every committee's full pubkey sum on device (``DeviceCommitteeCache``),
 - the attester domain and per-validator effective balances,
 
-and each drain then reduces every aggregate to ``(committee_id,
-missing_member_indices)`` with numpy bit ops — the device computes
-``full_sum - sum(missing)`` and runs the whole RLC chain without the
-aggregate pubkey ever touching the host.  The reference's analogue is
+and each drain then reduces every aggregate to ``(committee_id, the
+shorter of its missing and its attesting member indices)`` with numpy bit
+ops — the device computes ``full_sum - sum(missing)`` or
+``sum(attesting)`` and runs the whole RLC chain without the aggregate
+pubkey ever touching the host, at any participation.  The reference's analogue is
 blst doing this in native code on every call (ref:
 native/bls_nif/src/lib.rs:14-158 via state_transition/predicates.ex:
 109-136); here the epoch structure turns it into a cache problem, which

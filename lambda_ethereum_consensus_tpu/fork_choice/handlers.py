@@ -332,14 +332,15 @@ def on_attestation_batch(
     Two bodies behind one contract (VERDICT r4 next #1 — the node path must
     run the machinery the headline measures):
 
-    - **cached device drain** (default whenever the chained device pipeline
-      is enabled for the batch size): aggregate pubkeys come from the
-      epoch-scoped ``DeviceCommitteeCache`` as ``full_sum[committee] -
-      sum(missing members)`` computed ON DEVICE, participation is reduced
+    - **cached device drain** (whenever the chained device pipeline is on,
+      at any batch size): aggregate pubkeys come from the epoch-scoped
+      ``DeviceCommitteeCache`` as ``full_sum[committee] - sum(missing
+      members)`` — or the attesting members' sum, whichever list is
+      shorter — computed ON DEVICE, participation is reduced
       with numpy bit ops, and accepted votes land through the vectorized
       latest-message/head-cache batch path;
     - **host path**: the per-item ``affine_add`` walk over cached pubkey
-      points, for small batches and non-device hosts.
+      points, for non-device hosts.
 
     ``traces`` (position-aligned with ``attestations``, entries may be
     None) links this ONE batched verify back to its member item traces:
@@ -366,11 +367,17 @@ def on_attestation_batch(
     aggregation per attestation — a trade to be measured on a live
     mesh, not defaulted.
     """
-    from ..crypto.bls.batch import _chain_enabled, shard_drain_active
+    from ..crypto.bls.batch import chain_on, shard_drain_active
 
     spec = spec or get_chain_spec()
     results: list[ForkChoiceError | None] = [None] * len(attestations)
-    device = bool(attestations) and _chain_enabled(len(attestations))
+    # where the device chain is on, EVERY flush takes the cached drain — a
+    # deadline flush below the coalescing target too: the host body walks
+    # each attestation's committee and members in Python, which at mainnet
+    # size costs ~0.5 s an attestation (a 60-entry flush: 31 s of event
+    # loop, measured by the open-loop cell of PR 34) against one chained
+    # dispatch whatever the batch
+    device = bool(attestations) and chain_on()
     sharded = device and shard_drain_active()
     cached = device and not sharded
     path = "sharded" if sharded else ("cached" if cached else "host")
@@ -480,8 +487,8 @@ def _attestation_batch_host(
 def _host_verify_group(ctx, group, contain, results):
     """Bit-exact host re-verify of one cached-drain context group after a
     contained device fault: aggregate each item's pubkey from the context
-    state's registry (the sparse path's recipe) and run the host-routed
-    batch check.  Returns per-item flags aligned with ``group``, or
+    state's registry and run the host-routed batch check.  Returns
+    per-item flags aligned with ``group``, or
     ``None`` after writing verdicts when even host prep fails."""
     from ..crypto.bls.api import _pubkey_point
     from ..crypto.bls.batch import batch_verify_each_points
@@ -518,20 +525,20 @@ def _attestation_batch_cached(
     root; then ONE ``batch_verify_each_cached`` chain per target context
     (aggregate pubkeys never touch the host).  An entry with exactly one
     attester goes as ``(validator_index, None, ...)``: the chain gathers
-    its pubkey from the device registry planes.  Other entries whose
-    missing-member count exceeds the cache's correction capacity fall back
-    to the host aggregate path within the same call.  Accepted votes apply through the
-    vectorized batch updater.
+    its pubkey from the device registry planes.  Every other entry goes
+    by the shorter of its two member lists (``smaller_side``): the
+    missing members subtracted from the cached committee sum, or — below
+    half participation — the participants summed from the identity, on
+    the device either way; a sparse flush selects a wider gather, never
+    the host.  Accepted votes apply through the vectorized batch updater.
     """
-    import numpy as np
-
     from ..crypto.bls import BlsError
-    from ..crypto.bls.api import _pubkey_point
-    from ..crypto.bls.batch import batch_verify_each_cached, batch_verify_each_points
-    from ..crypto.bls.curve import DeserializationError, g1, g2_from_bytes_batch
+    from ..crypto.bls.batch import batch_verify_each_cached
+    from ..crypto.bls.curve import DeserializationError, g2_from_bytes_batch
+    from ..ops.bls_batch import smaller_side
     from .attestation import get_attestation_context
 
-    pending = []  # (i, att, ctx, cid, attesting, missing, sroot, target_state)
+    pending = []  # (i, att, ctx, cid, attesting, missing, sroot)
     contain = _DrainContainment("cached attestation drain")
     with span("attestation_prepare"):
         for i, attestation in enumerate(attestations):
@@ -549,8 +556,7 @@ def _attestation_batch_cached(
                     raise ForkChoiceError("attestation has no participants", reject=True)
                 signing_root = ctx.signing_root(attestation.data)
                 pending.append(
-                    (i, attestation, ctx, cid, attesting, missing, signing_root,
-                     target_state)
+                    (i, attestation, ctx, cid, attesting, missing, signing_root)
                 )
             except ForkChoiceError as e:
                 results[i] = e
@@ -574,37 +580,24 @@ def _attestation_batch_cached(
 
     by_ctx: dict[int, list] = {}  # id(ctx) -> [(i, att, attesting, entry)]
     ctxs: dict[int, object] = {}
-    host_entries = []  # (i, att, attesting, point-entry) — over-capacity
-    for (i, attestation, ctx, cid, attesting, missing, signing_root,
-         target_state), sig_pt in zip(pending, sig_points):
+    for (i, attestation, ctx, cid, attesting, missing, signing_root), sig_pt in zip(
+            pending, sig_points):
         try:
             if sig_pt is False:
                 raise ForkChoiceError("undecodable signature", reject=True)
             if sig_pt is None:
                 raise ForkChoiceError("infinity signature", reject=True)
-            cache = ctx.device_cache()
+            ctx.device_cache()  # built here, so that a failure is this item's
             if len(attesting) == 1:
                 # an unaggregated vote: its pubkey is one registry column,
                 # gathered on the device by validator index
                 entry = (int(attesting[0]), None, signing_root, sig_pt)
-                by_ctx.setdefault(id(ctx), []).append((i, attestation, attesting, entry))
-                ctxs[id(ctx)] = ctx
-            elif len(missing) <= cache.mmax:
-                entry = (cid, missing.tolist(), signing_root, sig_pt)
-                by_ctx.setdefault(id(ctx), []).append((i, attestation, attesting, entry))
-                ctxs[id(ctx)] = ctx
             else:
-                # sparse aggregate: summing the participants beats
-                # correcting the full sum — host path, same batch check
-                agg_pk = None
-                for v in attesting:
-                    pt = _pubkey_point(bytes(target_state.validators[v].pubkey))
-                    if pt is None:
-                        raise ForkChoiceError("identity pubkey in committee")
-                    agg_pk = pt if agg_pk is None else g1.affine_add(agg_pk, pt)
-                host_entries.append(
-                    (i, attestation, ctx, attesting, (agg_pk, signing_root, sig_pt))
-                )
+                # an aggregate at ANY participation: the shorter of its
+                # missing and its attesting members, summed on the device
+                entry = (cid, smaller_side(attesting, missing), signing_root, sig_pt)
+            by_ctx.setdefault(id(ctx), []).append((i, attestation, attesting, entry))
+            ctxs[id(ctx)] = ctx
         except ForkChoiceError as e:
             results[i] = e
         except (BlsError, DeserializationError) as e:
@@ -641,8 +634,8 @@ def _attestation_batch_cached(
             # device-runtime fault (XlaRuntimeError, lost PJRT client)
             # mid-dispatch: round 20 containment — re-verify this
             # context's items on the bit-exact HOST path (aggregate from
-            # the context state's registry pubkeys, the same recipe the
-            # sparse path runs) instead of dropping the whole group.
+            # the context state's registry pubkeys) instead of dropping
+            # the whole group.
             # Counted + latched so the fallback stays operator-visible.
             log.exception(
                 "device verify fault on a %d-item context group; "
@@ -653,15 +646,6 @@ def _attestation_batch_cached(
             if flags is None:
                 continue
         for (i, attestation, attesting, _), ok in zip(group, flags):
-            if ok:
-                accepted.append((i, ctx, attestation, attesting))
-            else:
-                results[i] = ForkChoiceError(
-                    "invalid attestation signature", reject=True
-                )
-    if host_entries:
-        flags = batch_verify_each_points([e[4] for e in host_entries])
-        for (i, attestation, ctx, attesting, _), ok in zip(host_entries, flags):
             if ok:
                 accepted.append((i, ctx, attestation, attesting))
             else:

@@ -60,6 +60,22 @@ class DrainShapes:
             coeff_bits = _COEFF_BITS
         self.coeff_bits = coeff_bits
 
+    def chain_layout(self, interpret: bool):
+        """The :class:`...ops.bls_batch.ChainLayout` of one drain at these
+        shapes: what :func:`warm_drain_programs` dispatches, and what a
+        smaller flush is padded up to once it is registered."""
+        from ..ops import bls_batch as BB
+
+        b, _dead = BB._entry_budget(self.entries, interpret)
+        per_check = (self.entries + self.checks - 1) // self.checks
+        return BB.ChainLayout(
+            b=b,
+            checks=self.checks,
+            m1=BB._pow2(self.groups + 1) - 1,
+            s=BB._pow2(max(per_check // max(self.groups // self.checks, 1), 1)),
+            e=BB._pow2(per_check),
+        )
+
 
 def warm_sharded_programs(shapes: DrainShapes) -> float:
     """Dispatch one dummy SHARDED verify at ``shapes`` — the mesh
@@ -114,13 +130,9 @@ def warm_drain_programs(shapes: DrainShapes) -> float:
     t_single = time.perf_counter()
 
     with compile_context("warmup:drain"):
-        b, _dead = BB._entry_budget(shapes.entries, interpret)
+        b, _checks, m1, s, e = shapes.chain_layout(interpret)
         kp = BB._pow2(shapes.committee)
         mmax = BB._pow2(max(shapes.committee // 8, 2))
-        m1 = BB._pow2(shapes.groups + 1) - 1
-        per_check = (shapes.entries + shapes.checks - 1) // shapes.checks
-        s = BB._pow2(max(per_check // max(shapes.groups // shapes.checks, 1), 1))
-        e = BB._pow2(per_check)
 
         zreg = jnp.zeros((32, shapes.n_validators), jnp.int32)
         chunk = min(256, max(1, shapes.n_committees))
@@ -135,6 +147,7 @@ def warm_drain_programs(shapes: DrainShapes) -> float:
             jnp.zeros((b,), jnp.int32),
             jnp.zeros((b, mmax), jnp.int32),
             jnp.ones((b, mmax), bool),
+            jnp.zeros((b,), bool),
         )
         kb = jnp.zeros((shapes.coeff_bits, b), jnp.int32)
         lv = jnp.zeros((b,), bool)
@@ -258,6 +271,12 @@ def start_warmer(
     from ..witness.verify import DEFAULT_BATCH_BUCKETS
 
     register_shape_bucket("attestation_entries", shapes.entries)
+    # ... and the chain pads a flush BELOW the warmed drain (a deadline
+    # flush, a slot's ragged tail) up to this layout: each layout is a set
+    # of six programs, and only this one is ever loaded before traffic
+    from ..ops import bls_batch as BB
+
+    BB.register_chain_layout(shapes.chain_layout(not BB._use_planes()))
     for bucket in DEFAULT_BATCH_BUCKETS:
         register_shape_bucket("witness_verify", bucket)
     for bucket in DEFAULT_SIGN_BUCKETS:

@@ -29,6 +29,8 @@ slots/groups to powers of two) so jit caches stay warm across drains.
 
 from __future__ import annotations
 
+from typing import NamedTuple, Sequence
+
 import numpy as np
 
 from ..crypto.bls import curve as C
@@ -48,6 +50,8 @@ from .bls_pairing import _pow2_pad as _pow2
 __all__ = [
     "chain_verify",
     "chain_verify_cached",
+    "CommitteeSide",
+    "smaller_side",
     "aggregate_g1_chain",
     "DeviceCommitteeCache",
     "RegistryPlaneStore",
@@ -269,27 +273,34 @@ def make_chain_ops(interpret: bool = False):
         X, Y, Z, _ = _reduce_inline(g1j, (gx, gy, _ones_like(gx), inf))
         return _norm_g1(X, Y, Z)
 
-    def agg_corrected(rx, ry, sum_x, sum_y, comm_ids, miss_idx, miss_inf):
-        """Per-entry aggregate pubkeys as ``full_sum - missing_members``.
+    def agg_corrected(rx, ry, sum_x, sum_y, comm_ids, idx, idx_inf, attesting):
+        """Per-entry aggregate pubkeys from the SMALLER side of each
+        committee: ``full_sum - missing_members`` where ``attesting[e]``
+        is False, ``identity + attesting_members`` where it is True.
 
         Committee membership is fixed per epoch, so each drain only pays a
-        small correction gather: ``miss_idx`` (E, mm) registry indices of
-        NON-participating members (dead slots flagged in ``miss_inf``),
-        ``comm_ids`` (E,) committee of each entry.  Returns affine
-        (32, E) points plus an (E,) infinity mask (an empty-participation
-        entry reduces to infinity; callers must mark it dead).
+        gather over the shorter of an entry's two index lists: ``idx``
+        (E, w) registry indices (dead slots flagged in ``idx_inf``),
+        ``comm_ids`` (E,) committee of each entry.  One body, one program
+        per width ``w`` (:meth:`DeviceCommitteeCache.widths`).  Returns
+        affine (32, E) points plus an (E,) infinity mask (an
+        empty-participation entry reduces to infinity; callers must mark
+        it dead).
         """
-        e, mm = miss_idx.shape
-        gx = jnp.take(rx, miss_idx.reshape(-1), axis=1).reshape(-1, e, mm)
-        gy = jnp.take(ry, miss_idx.reshape(-1), axis=1).reshape(-1, e, mm)
-        X, Y, Z, minf = _reduce_inline(
-            g1j, (gx, gy, _ones_like(gx), miss_inf)
+        e, w = idx.shape
+        gx = jnp.take(rx, idx.reshape(-1), axis=1).reshape(-1, e, w)
+        gy = jnp.take(ry, idx.reshape(-1), axis=1).reshape(-1, e, w)
+        X, Y, Z, sinf = _reduce_inline(
+            g1j, (gx, gy, _ones_like(gx), idx_inf)
         )
         fx = jnp.take(sum_x, comm_ids, axis=1)  # (32, E)
         fy = jnp.take(sum_y, comm_ids, axis=1)
-        full = (fx, fy, _ones_like(fx), jnp.zeros((e,), jnp.bool_))
+        # the base point: the committee sum, or the identity (flagged
+        # infinity) where the listed members are the participants
+        base = (fx, fy, _ones_like(fx), attesting)
         # -missing: Jacobian negation is (X, -Y, Z)
-        X3, Y3, Z3, inf3 = g1j["jac_add"](full, (X, fq["neg"](Y), Z, minf))
+        Ys = jnp.where(attesting[None, :], Y, fq["neg"](Y))
+        X3, Y3, Z3, inf3 = g1j["jac_add"](base, (X, Ys, Z, sinf))
         ax, ay = _norm_g1(X3, Y3, Z3)
         return ax, ay, inf3
 
@@ -407,7 +418,8 @@ def chain_verify(
                 flat_coeff.append(coeff)
         n = len(flat_pk)
         _count_entries(points=n)
-        b, dead = _entry_budget(n, interpret)
+        layout, dead = _chain_layout(checks, interpret)
+        b = layout.b
 
         # Flat entry planes, padded with the generator at dead slots.
         pad = b - n
@@ -424,7 +436,7 @@ def chain_verify(
         ops = _get_chain_ops(interpret)
         jac1 = ops["ladder_g1"](pkx, pky, kbits, live)
         jac2 = ops["ladder_g2"](sgx, sgy, kbits, live)
-        ok = _dispatch_checks_tail(ops, jac1, jac2, checks, dead)
+        ok = _dispatch_checks_tail(ops, jac1, jac2, checks, layout, dead)
     return _fetch_flags(ok)
 
 
@@ -449,36 +461,86 @@ def _entry_budget(n: int, interpret: bool) -> tuple[int, int]:
     return b, n
 
 
-def _dispatch_checks_tail(ops, jac1, jac2, checks, dead: int):
+class ChainLayout(NamedTuple):
+    """The shape key of one chained verify: its six programs (aggregation
+    or gather, two ladders, prep, Miller, masked product) are compiled per
+    value of it, so every distinct layout is a set of programs to load —
+    or, cold, to compile — inside the call that first meets it."""
+
+    b: int  # padded flat-entry budget (aggregation, ladders)
+    checks: int  # checks of the call (1 but for a bisection level)
+    m1: int  # message groups per check (pow2 - 1; slot m1 is the sig pair)
+    s: int  # entries per group (pow2)
+    e: int  # entries per check (pow2)
+
+
+# Layouts whose programs a warmer has dispatched (node/warmup.py: the
+# drain's).  A call that fits inside one is padded up to it with dead
+# entries, groups and slots, so a flush of ANY size below the warmed drain
+# — a deadline flush, a slot's ragged tail — runs the programs already
+# resident instead of a set of its own.
+_WARMED_LAYOUTS: set[ChainLayout] = set()
+
+
+def register_chain_layout(layout: ChainLayout) -> None:
+    """Advertise that the chain's programs at ``layout`` are warmed (or
+    about to be: a warmer registers before its background dispatch, as it
+    does its shape buckets)."""
+    _WARMED_LAYOUTS.add(ChainLayout(*(int(v) for v in layout)))
+
+
+def warmed_chain_layouts() -> tuple[ChainLayout, ...]:
+    """The registered layouts, smallest first (``/debug/compile``)."""
+    return tuple(sorted(_WARMED_LAYOUTS))
+
+
+def _chain_layout(checks, interpret: bool) -> tuple[ChainLayout, int]:
+    """The layout ``checks`` are dispatched at and the canonical dead-slot
+    index: the smallest warmed layout that holds the call on every axis,
+    else the call's own (each axis pow2-padded, the entry budget by
+    :func:`_entry_budget`).  Padding is what every axis already carries
+    up to its pow2 — dead entries (``live`` False), empty groups
+    (``static_live`` False), dead slots — so a padded call's verdicts are
+    its own layout's."""
+    n = sum(len(entries) for entries, _, _ in checks)
+    b, dead = _entry_budget(n, interpret)
+    max_groups = max(max((len(h) for _, h, _ in checks), default=1), 1)
+    max_slot = 1
+    for _, h_points, group_ids in checks:
+        if h_points:
+            counts = np.bincount(np.asarray(group_ids, np.int64), minlength=1)
+            max_slot = max(max_slot, int(counts.max()))
+    own = ChainLayout(
+        b=b,
+        checks=len(checks),
+        m1=_pow2(max_groups + 1) - 1,
+        s=_pow2(max_slot),
+        e=_pow2(max((len(c[0]) for c in checks), default=1) or 1),
+    )
+    fits = [w for w in _WARMED_LAYOUTS
+            if w.checks == own.checks and all(x >= y for x, y in zip(w, own))]
+    return min(fits, default=own), dead
+
+
+def _dispatch_checks_tail(ops, jac1, jac2, checks, layout: ChainLayout, dead: int):
     """The shared back half of every chained verify: gather the laddered
     entries into (check, group, slot) rectangles, reduce, Miller, final
     exp — dispatched, one boolean per check still on the device
     (:func:`_fetch_flags` pulls them back).
 
-    ``checks`` supplies only the LAYOUT here (entry counts, h_points,
-    group_ids); the laddered planes arrive as ``jac1``/``jac2`` whether
-    they came from host-packed points (:func:`chain_verify`) or the
-    epoch committee cache (:func:`chain_verify_cached`).
+    ``checks`` supplies only the entry counts, h_points and group_ids
+    here, ``layout`` (:func:`_chain_layout`) the padded rectangles; the
+    laddered planes arrive as ``jac1``/``jac2`` whether they came from
+    host-packed points (:func:`chain_verify`) or the epoch committee
+    cache (:func:`chain_verify_cached`).
     """
     import jax.numpy as jnp
 
-    n_checks = len(checks)
+    n_checks, m1, s, e = layout.checks, layout.m1, layout.s, layout.e
     offsets, off = [], 0
     for entries, _, _ in checks:
         offsets.append(off)
         off += len(entries)
-
-    max_groups = max(max((len(h) for _, h, _ in checks), default=1), 1)
-    m1 = _pow2(max_groups + 1) - 1  # groups per check; slot m1 is the sig pair
-    max_slot = 1
-    for entries, h_points, group_ids in checks:
-        counts = [0] * len(h_points)
-        for g in group_ids:
-            counts[g] += 1
-        if counts:
-            max_slot = max(max_slot, max(counts))
-    s = _pow2(max_slot)
-    e = _pow2(max((len(c[0]) for c in checks), default=1) or 1)
 
     idx_g1 = np.full((n_checks, m1, s), dead, np.int32)
     idx_sig = np.full((n_checks, e), dead, np.int32)
@@ -526,6 +588,76 @@ def _fetch_flags(ok) -> list[bool]:
     return [bool(v) for v in flags]
 
 
+class CommitteeSide(NamedTuple):
+    """Registry indices of ONE side of a committee aggregate and which
+    side they are — the participants (``attesting`` True: summed from the
+    identity) or the missing members (False: subtracted from the cached
+    committee sum)."""
+
+    indices: Sequence[int]
+    attesting: bool
+
+
+def smaller_side(attesting, missing) -> CommitteeSide:
+    """The side of a committee aggregate the device sums over: whichever
+    index list is shorter (ties: subtract the missing), so the gather is
+    never wider than half the committee."""
+    if len(attesting) < len(missing):
+        return CommitteeSide(attesting, True)
+    return CommitteeSide(missing, False)
+
+
+def _pack_members(cache: "DeviceCommitteeCache", flat, b: int):
+    """Index and mask planes of one cached call, at its width bucket.
+
+    ``flat``: the call's entries; ``b``: the padded entry budget.  Returns
+    ``(cid, is_single, idx, idx_inf, attesting)`` — ``cid`` the entry's
+    committee or, for a single signer, its registry index; ``idx`` /
+    ``idx_inf`` (b, w) the listed members with dead slots flagged
+    (``None`` where every entry is a single signer); ``attesting`` (b,)
+    which side each list is.  ``w`` is the smallest of ``cache.widths``
+    that holds the call's longest list: read from the miss counts, never
+    from a flag.  Books ``bls_agg_entries_total{width, side}``.
+    """
+    cid = np.zeros(b, np.int32)
+    is_single = np.zeros(b, bool)
+    attesting = np.zeros(b, bool)
+    rows, lists = [], []
+    for i, (comm_id, members, _, _) in enumerate(flat):
+        cid[i] = comm_id
+        if members is None:
+            is_single[i] = True
+            continue
+        if isinstance(members, CommitteeSide):
+            members, attesting[i] = members
+        rows.append(i)
+        lists.append(members)
+    if not rows:
+        return cid, is_single, None, None, attesting
+    counts = np.fromiter((len(m) for m in lists), np.int64, len(lists))
+    longest = int(counts.max())
+    if longest > cache.wmax:
+        i = rows[int(counts.argmax())]
+        raise ValueError(
+            f"entry {i}: {longest} missing members exceeds cache capacity "
+            f"{cache.wmax}"
+        )
+    w = next(w for w in cache.widths if w >= longest)
+    # one scatter for the whole call: row r's list lands in idx[r, :len]
+    live = np.zeros((b, w), bool)
+    live[rows] = np.arange(w)[None, :] < counts[:, None]
+    idx = np.zeros((b, w), np.int32)
+    if longest:
+        idx[live] = np.concatenate([np.asarray(m, np.int32) for m in lists])
+    idx_inf = ~live
+    on_side = attesting[rows]
+    for side, count in (("attesting", int(on_side.sum())),
+                        ("missing", int((~on_side).sum()))):
+        if count:
+            inc("bls_agg_entries_total", value=count, width=str(w), side=side)
+    return cid, is_single, idx, idx_inf, attesting
+
+
 def chain_verify_cached(
     cache: "DeviceCommitteeCache",
     checks,
@@ -539,24 +671,33 @@ def chain_verify_cached(
 
     ``checks`` is an iterable (consumed once, under the ``bls_host_pack``
     span); each check is ``(entries, h_points, group_ids)`` where an
-    entry is ``(comm_id, miss_members, sig_xy, coeff)``:
+    entry is ``(comm_id, members, sig_xy, coeff)``:
 
     - ``comm_id``: the entry's committee index into the cache;
-    - ``miss_members``: registry indices of NON-participating committee
-      members (len <= ``cache.mmax`` — callers route lower-participation
-      entries to the host path);
+    - ``members``: a :class:`CommitteeSide` — registry indices of the
+      SHORTER of the committee's two sides and which side they are
+      (:func:`smaller_side`) — or a plain sequence, read as the
+      NON-participating members.  A list longer than ``cache.wmax``
+      (half the committee, pow2-padded) raises ``ValueError``: the
+      caller broke the smaller-side contract;
     - ``sig_xy``/``coeff``: as in :func:`chain_verify`.
 
     A **single-signer** entry is ``(validator_index, None, sig_xy,
     coeff)``: its pubkey is column ``validator_index`` of the registry
     planes the cache already holds on the device (``single_gather``) —
     no committee sum, no correction table, no host point.  A call may mix
-    both shapes; each entry is routed by its own ``miss_members``.
+    both shapes; each entry is routed by its own ``members``.
 
-    The aggregate pubkey never touches the host: ``full_sum[comm_id] -
-    sum(missing)`` is computed on device and flows straight into the RLC
-    ladder.  Callers must pre-reject empty-participation entries (their
-    aggregate is the infinity point, invalid per the spec's
+    The aggregate pubkey never touches the host at ANY participation:
+    ``full_sum[comm_id] - sum(missing)`` or ``sum(attesting)`` —
+    whichever list is shorter — is computed on device and flows straight
+    into the RLC ladder.  The gather width of the call is the smallest
+    of ``cache.widths`` that holds its longest list, so a call of
+    high-participation aggregates dispatches the ``mmax``-wide program
+    and only a call that carries a sparser aggregate pays a wider one
+    (a call that mixes both is padded to its widest entry: one program
+    per call).  Callers must pre-reject empty-participation entries
+    (their aggregate is the infinity point, invalid per the spec's
     fast-aggregate-verify preconditions).
     """
     import jax.numpy as jnp
@@ -578,32 +719,14 @@ def chain_verify_cached(
         if not checks:
             return []
 
-        mmax = cache.mmax
         flat = [entry for entries, _, _ in checks for entry in entries]
         n = len(flat)
-        b, dead = _entry_budget(n, interpret)
+        layout, dead = _chain_layout(checks, interpret)
+        b = layout.b
         pad = b - n
 
-        # ``cid``: the entry's committee, or — a single signer, whose
-        # ``miss_members`` is None — its attester's registry index
-        cid = np.zeros(b, np.int32)
-        is_single = np.zeros(b, bool)
-        miss_idx = miss_inf = None  # made where some entry is a committee aggregate
-        for i, (comm_id, miss, _, _) in enumerate(flat):
-            cid[i] = comm_id
-            if miss is None:
-                is_single[i] = True
-                continue
-            if miss_idx is None:
-                miss_idx = np.zeros((b, mmax), np.int32)
-                miss_inf = np.ones((b, mmax), bool)
-            mc = len(miss)
-            if mc > mmax:
-                raise ValueError(
-                    f"entry {i}: {mc} missing members exceeds cache capacity {mmax}"
-                )
-            miss_idx[i, :mc] = miss
-            miss_inf[i, :mc] = False
+        with span("agg_index_pack"):  # the index and mask planes of the call
+            cid, is_single, idx, idx_inf, attesting = _pack_members(cache, flat, b)
         n_single = int(is_single.sum())
         _count_entries(single=n_single, committee=n - n_single)
 
@@ -623,7 +746,7 @@ def chain_verify_cached(
             g1_live = live  # a registry key is never the identity
         else:
             comm_ids = np.where(is_single, 0, cid) if n_single else cid
-            agg_x, agg_y, agg_inf = cache.aggregate(comm_ids, miss_idx, miss_inf)
+            agg_x, agg_y, agg_inf = cache.aggregate(comm_ids, idx, idx_inf, attesting)
             if n_single:  # a mixed drain: both programs at b, then a select
                 sx, sy = cache.gather_single(np.where(is_single, cid, 0))
                 agg_x, agg_y, agg_inf = ops["single_merge"](
@@ -641,7 +764,7 @@ def chain_verify_cached(
         jac2 = ops["ladder_g2"](sgx, sgy, kbits, live)
         # layout builder only reads len(entries)/h_points/group_ids — the
         # cached-entry tuples carry the same positional layout contract
-        ok = _dispatch_checks_tail(ops, jac1, jac2, checks, dead)
+        ok = _dispatch_checks_tail(ops, jac1, jac2, checks, layout, dead)
     return _fetch_flags(ok)
 
 
@@ -875,13 +998,17 @@ class DeviceCommitteeCache:
     lib/lambda_ethereum_consensus/state_transition/misc.ex feeding
     ``get_beacon_committee``), so this cache computes each committee's FULL
     pubkey sum once per epoch (chunked gather + Jacobian tree reduce on
-    device) and each drain pays only a small correction per aggregate:
+    device) and each drain pays only a gather over the SHORTER side of
+    each aggregate:
 
         agg_pk[entry] = full_sum[committee] - sum(non-participating members)
+                     or sum(participating members), whichever list is shorter
 
     High-participation aggregates (the gossip norm) make the correction
-    gather ~20x smaller than the full gather.  All shapes are padded to a
-    small bucket set so the jitted programs cache across epochs.
+    gather ~20x smaller than the full gather; below two thirds
+    participation (a network that has lost finality) the gather is at
+    most half the committee.  All shapes are padded to a small bucket set
+    (``widths``) so the jitted programs cache across epochs.
 
     ``registry_planes`` is either a :class:`RegistryPlaneStore` — the
     production path: this cache holds a reference into the chain's ONE
@@ -933,10 +1060,13 @@ class DeviceCommitteeCache:
         n_comm, k = committees.shape
         kp = _pow2(k)
         self.n_comm = n_comm
-        # correction capacity for chain_verify_cached entries: 12.5% of
-        # the committee by default (high-participation aggregates are the
-        # gossip norm; callers route anything sparser to the host path)
-        self.mmax = mmax if mmax is not None else _pow2(max(k // 8, 2))
+        # gather widths of chain_verify_cached's aggregation program: the
+        # narrowest holds 12.5% of the committee (high-participation
+        # aggregates are the gossip norm), the widest half of it — the
+        # longer an entry's shorter side can ever be (smaller_side) — and
+        # powers of two between, one compiled program each
+        self.widths = self.gather_widths(k, mmax)
+        self.mmax, self.wmax = self.widths[0], self.widths[-1]
         # pad members to pow2 (dead slots flagged inf) and committees to a
         # chunk multiple so every chunk runs the same compiled program
         chunk = min(chunk, _pow2(n_comm))
@@ -966,6 +1096,16 @@ class DeviceCommitteeCache:
             sums_y.append(sy)
         self.sum_x = jnp.concatenate(sums_x, axis=1)[:, :n_comm]
         self.sum_y = jnp.concatenate(sums_y, axis=1)[:, :n_comm]
+
+    @staticmethod
+    def gather_widths(k: int, mmax: int | None = None) -> tuple[int, ...]:
+        """Ascending gather widths for committees of ``k`` members:
+        ``mmax`` (default an eighth of the committee, pow2), doubling up to
+        half the committee (pow2)."""
+        widths = [mmax if mmax is not None else _pow2(max(k // 8, 2))]
+        while widths[-1] < _pow2(max(k // 2, 1)):
+            widths.append(widths[-1] * 2)
+        return tuple(widths)
 
     def _refresh_planes(self) -> None:
         """Adopt the shared store's CURRENT buffer when registry growth
@@ -997,12 +1137,16 @@ class DeviceCommitteeCache:
             self.rx, self.ry, jnp.asarray(np.asarray(indices, np.int32))
         )
 
-    def aggregate(self, comm_ids, miss_idx, miss_inf):
+    def aggregate(self, comm_ids, idx, idx_inf, attesting=None):
         """Affine aggregate pubkey planes for one drain's entries.
 
-        ``comm_ids``: (E,) committee per entry; ``miss_idx``/``miss_inf``:
-        (E, mm) registry indices of non-participating members with dead
-        slots flagged (mm pow2-padded by the caller for shape stability).
+        ``comm_ids``: (E,) committee per entry; ``idx``/``idx_inf``:
+        (E, w) registry indices of each entry's listed members with dead
+        slots flagged (w one of ``self.widths``, padded by the caller for
+        shape stability); ``attesting``: (E,) True where the listed
+        members are the entry's participants (summed from the identity),
+        False — the default for every entry — where they are its
+        NON-participating members (subtracted from the committee sum).
         Returns ``(x_planes, y_planes, inf_mask)`` — entries whose
         participation is empty come back flagged infinity and MUST be
         marked dead by the caller (an aggregate with no participants is
@@ -1011,12 +1155,15 @@ class DeviceCommitteeCache:
         import jax.numpy as jnp
 
         self._refresh_planes()
+        if attesting is None:
+            attesting = np.zeros(len(comm_ids), bool)
         return self._ops["agg_corrected"](
             self.rx,
             self.ry,
             self.sum_x,
             self.sum_y,
             jnp.asarray(np.asarray(comm_ids, np.int32)),
-            jnp.asarray(np.asarray(miss_idx, np.int32)),
-            jnp.asarray(np.asarray(miss_inf, bool)),
+            jnp.asarray(np.asarray(idx, np.int32)),
+            jnp.asarray(np.asarray(idx_inf, bool)),
+            jnp.asarray(np.asarray(attesting, bool)),
         )

@@ -16,7 +16,9 @@ shapes of the greedy design:
    at bounded latency in real batches instead of batch-of-1 device
    calls.  Flush sizes snap down onto AOT-warmed shape buckets
    (ops/aot.py registry, fed by node/warmup.py) so a drain never traces
-   a program the warmer didn't already pay for.
+   a program the warmer didn't already pay for; a flush below the
+   smallest bucket goes out as it is, and the BLS chain pads it up to
+   the warmed layout (ops/bls_batch.py ``_chain_layout``).
 3. **Admission-time load shedding.**  A full lane — or a scheduler over
    its global item budget — sheds the OLDEST item from the
    lowest-priority backlogged lane (policy.choose_shed_victim) to admit

@@ -273,16 +273,16 @@ def _verify_deferred_attestations(state, deferred, spec) -> bool:
 
     Signatures decompress in one native thread-pool pass; on device-
     enabled hosts with enough total committee membership the aggregate
-    pubkeys come from the epoch committee cache (full sum minus missing,
-    on device — the same machinery the gossip drain runs), otherwise a
-    single host RLC check replaces the per-attestation pairings.
+    pubkeys come from the epoch committee cache
+    (:func:`_verify_deferred_cached` — the same machinery the gossip
+    drain runs), otherwise a single host RLC check replaces the
+    per-attestation pairings.
     """
     import os
 
     from ..crypto.bls.api import _pubkey_point
-    from ..crypto.bls.batch import batch_verify_each_cached, verify_points
+    from ..crypto.bls.batch import chain_on, verify_points
     from ..crypto.bls.curve import g1, g2_from_bytes_batch
-    from ..utils.env import device_default, env_flag
 
     sigs = g2_from_bytes_batch([bytes(ind.signature) for _, ind, _, _ in deferred])
     if any(s is False or s is None for s in sigs):
@@ -290,41 +290,10 @@ def _verify_deferred_attestations(state, deferred, spec) -> bool:
 
     total_members = sum(len(ind.attesting_indices) for _, ind, _, _ in deferred)
     min_members = int(os.environ.get("BLS_BLOCK_BATCH_MIN_MEMBERS", "4096"))
-    use_cached = total_members >= min_members and (
-        env_flag("BLS_DEVICE_CHAIN") or device_default()
-    )
+    use_cached = total_members >= min_members and chain_on()
     if use_cached:
-        from ..fork_choice.attestation import get_state_attestation_context
-
         try:
-            frozen = state.freeze()
-            by_ctx: dict[int, tuple] = {}
-            host_entries = []
-            for (att, ind, _pubkeys, signing_root), sig in zip(deferred, sigs):
-                ctx = get_state_attestation_context(
-                    frozen, int(att.data.target.epoch), spec
-                )
-                cid, attesting, missing = ctx.participation(att)
-                if len(missing) <= ctx.device_cache().mmax:
-                    by_ctx.setdefault(id(ctx), (ctx, []))[1].append(
-                        (cid, missing.tolist(), signing_root, sig)
-                    )
-                else:
-                    agg = None
-                    for v in attesting:
-                        pt = _pubkey_point(bytes(frozen.validators[v].pubkey))
-                        if pt is None:
-                            return False
-                        agg = pt if agg is None else g1.affine_add(agg, pt)
-                    host_entries.append((agg, signing_root, sig))
-            for ctx, entries in by_ctx.values():
-                flags = batch_verify_each_cached(
-                    ctx.device_cache(), entries,
-                    message_points=ctx.message_points,
-                )
-                if not all(flags):
-                    return False
-            return not host_entries or verify_points(host_entries)
+            return _verify_deferred_cached(state, deferred, sigs, spec)
         except ValueError:
             # a real validation failure (SpecError subclasses ValueError:
             # invalid registry pubkey, shape contract breach) fails on
@@ -338,6 +307,8 @@ def _verify_deferred_attestations(state, deferred, spec) -> bool:
 
             device_fault("bls_verify")
 
+    # no committee context: a host without the device chain, or a block
+    # too small to be worth a dispatch — host points, one RLC check
     entries = []
     for (att, ind, pubkeys, signing_root), sig in zip(deferred, sigs):
         agg = None
@@ -348,6 +319,38 @@ def _verify_deferred_attestations(state, deferred, spec) -> bool:
             agg = pt if agg is None else g1.affine_add(agg, pt)
         entries.append((agg, signing_root, sig))
     return verify_points(entries)
+
+
+def _verify_deferred_cached(state, deferred, sigs, spec) -> bool:
+    """A block's aggregates through the cached device chain, one call per
+    target context: every entry by ``(committee, the shorter of its
+    missing and its attesting members)`` from numpy bit ops — a single
+    signer by its registry index — so no aggregate public key is summed
+    on the host, at any participation."""
+    from ..crypto.bls.batch import batch_verify_each_cached
+    from ..fork_choice.attestation import get_state_attestation_context
+    from ..ops.bls_batch import smaller_side
+
+    frozen = state.freeze()
+    by_ctx: dict[int, tuple] = {}
+    for (att, _ind, _pubkeys, signing_root), sig in zip(deferred, sigs):
+        ctx = get_state_attestation_context(
+            frozen, int(att.data.target.epoch), spec
+        )
+        cid, attesting, missing = ctx.participation(att)
+        if len(attesting) == 1:
+            entry = (int(attesting[0]), None, signing_root, sig)
+        else:
+            entry = (cid, smaller_side(attesting, missing), signing_root, sig)
+        by_ctx.setdefault(id(ctx), (ctx, []))[1].append(entry)
+    for ctx, entries in by_ctx.values():
+        flags = batch_verify_each_cached(
+            ctx.device_cache(), entries,
+            message_points=ctx.message_points,
+        )
+        if not all(flags):
+            return False
+    return True
 
 
 # --------------------------------------------------------------- deposits

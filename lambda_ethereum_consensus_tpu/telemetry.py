@@ -104,8 +104,10 @@ _HELP = {
     "subnet_seen_votes": "first-seen vote cells held by the subnet drain across the live epochs",
     "signature_decompress_seconds": "one batched G2 signature decompression + subgroup check (host)",
     "bls_host_pack_seconds": "host side of one chained verify up to its first device dispatch: hash-to-G2, entry packing, limb planes, uploads",
+    "agg_index_pack_seconds": "inside bls_host_pack: the (entries, width) member-index and mask planes of one cached chained verify, at the call's gather width",
+    "bls_agg_entries_total": "committee entries of a cached chained verify, by the call's gather width (the committee cache's widths: narrowest for high participation, up to half the committee) and the side each entry lists (missing = subtracted from the cached committee sum, attesting = summed from the identity); bisection re-checks included",
     "bls_dispatch_seconds": "one chained verify's program calls and the layout packing between them (the device works meanwhile)",
-    "bls_chain_entries_total": "entries entering a chained device verify, by the shape their pubkeys take (single = gathered from the registry planes by validator index, committee = cached committee sum less missing members, points = host-packed points uploaded per call); bisection re-checks included",
+    "bls_chain_entries_total": "entries entering a chained device verify, by the shape their pubkeys take (single = gathered from the registry planes by validator index, committee = cached committee sum less the missing members or, below half participation, the attesting members' sum, points = host-packed points uploaded per call); bisection re-checks included",
     "bls_device_wait_seconds": "host blocked fetching one chained verify's verdict flags from the device",
     "votes_apply_seconds": "vectorized latest-message + head-cache update for one drain's accepted votes",
     "fork_choice_on_block_seconds": "one fork-choice on_block: checks, state transition, store update",

@@ -137,6 +137,7 @@ def run(
             jnp.zeros((b,), jnp.int32),
             jnp.zeros((b, mmax), jnp.int32),
             jnp.ones((b, mmax), bool),
+            jnp.zeros((b,), bool),
         )
         from lambda_ethereum_consensus_tpu.crypto.bls.batch import (
             _COEFF_BITS as w,

@@ -108,10 +108,20 @@ def single_device_programs(n_validators: int, coeff_bits: int):
         lv = sds((b,), BOOL)
         g1 = sds((32, b), I32)
         g2 = sds((32, 2, b), I32)
+        # the aggregation program: the dense width everywhere, and for a
+        # gossip drain the wider buckets a sparse flush selects (up to
+        # half the committee: a network below two thirds participation)
+        widths = (BB.DeviceCommitteeCache.gather_widths(k) if tag == "gossip"
+                  else (mmax,))
         out += [
-            (f"chain_agg_corrected[{tag} b={b}]", ops["agg_corrected"].jitted,
-             (reg, reg, sums, sums, sds((b,), I32), sds((b, mmax), I32),
-              sds((b, mmax), BOOL)), {}),
+            (f"chain_agg_corrected[{tag} b={b}]" if w == mmax
+             else f"chain_agg_corrected[{tag} b={b} w={w}]",
+             ops["agg_corrected"].jitted,
+             (reg, reg, sums, sums, sds((b,), I32), sds((b, w), I32),
+              sds((b, w), BOOL), sds((b,), BOOL)), {})
+            for w in widths
+        ]
+        out += [
             (f"chain_ladder_g1[{tag} b={b}]", ops["ladder_g1"].jitted,
              (g1, g1, kb, lv), {}),
             (f"chain_ladder_g2[{tag} b={b}]", ops["ladder_g2"].jitted,

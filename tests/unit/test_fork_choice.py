@@ -309,8 +309,8 @@ def test_on_attestation_batch_cached_matches_host(chain, monkeypatch):
     must run the machinery the bench measures) against the host path:
     same verdicts, same weights, same latest messages — across full
     participation, a missing-member correction, a forged signature, a
-    sparse aggregate (over the correction capacity -> host fallback
-    inside the cached drain) and a same-validator duplicate."""
+    one-bit vote (the single-signer shape of the cached drain) and a
+    same-validator duplicate."""
     import numpy as np
 
     from lambda_ethereum_consensus_tpu.fork_choice import on_attestation_batch

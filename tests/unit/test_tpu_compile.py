@@ -74,6 +74,8 @@ def programs(one_chip):
     pytest.param("merkle_tree_jnp[depth=19]", marks=pytest.mark.slow),
     "chain_ladder_g1[gossip b=2048]",  # one gossip drain: 1,024 entries
     "chain_norm_g1[gossip c=1 m1=127]",  # its 64 message groups
+    # a sparse drain's aggregation: the smaller side at half the committee
+    "chain_agg_corrected[gossip b=2048 w=256]",
     # one subnet flush: 4,096 one-bit votes, pubkeys gathered by index
     "chain_single_gather[subnet b=5120]",
     "chain_ladder_g1[subnet b=5120]",
