@@ -193,37 +193,42 @@ def make_chain_ops(interpret: bool = False):
             return _tree_reduce_j(jadd1 if which == 1 else jadd2, pt)
         return (reduce_g1_j if which == 1 else reduce_g2_j)(*pt)
 
+    def _take_entries(jac, idx, axis):
+        """Ladder outputs ``(X, Y, Z, inf)`` over the b lanes of the flat
+        entry batch (lane axis ``axis`` of the coordinates), gathered
+        into the rectangle of entry indices ``idx``.  An index past the
+        last lane — the dead slot of :func:`_entry_budget` — reads the
+        identity: ``inf`` True over zero coordinates.  Mode and fill are
+        written out, not left to ``jnp.take``'s default: a clamp
+        (``mode="clip"``, or ``x[:, idx]`` indexing) would read lane b - 1
+        there, a live entry of a full batch, and add its key into every
+        padded slot."""
+        X, Y, Z, inf = jac
+        flat = idx.reshape(-1)
+        return (
+            *(jnp.take(v, flat, axis=axis, mode="fill", fill_value=0)
+              .reshape(*v.shape[:axis], *idx.shape) for v in (X, Y, Z)),
+            jnp.take(inf, flat, axis=0, mode="fill", fill_value=True)
+            .reshape(idx.shape),
+        )
+
     def prep(jac1, jac2, idx_g1, idx_sig, h_x, h_y, static_live):
         """Gather + reduce + normalize + pack the Miller batch.
 
-        jac1/jac2: ladder outputs over the flat entry batch.
+        jac1/jac2: ladder outputs over the b lanes of the flat entry batch.
         idx_g1: (c, m1, s) int32 entry indices per (check, group, slot);
-        idx_sig: (c, e) indices per (check, slot); dead slots point at an
-        entry whose inf flag is set.  h_x/h_y: (32, 2, c, m1) hashed
-        message points; static_live: (c, m) host liveness (m = m1 + 1,
-        slot m-1 is the signature pair).
+        idx_sig: (c, e) indices per (check, slot); a dead slot holds an
+        index past the last lane (b: the sentinel of ``_take_entries``,
+        which reads the identity and costs the ladders no lane).
+        h_x/h_y: (32, 2, c, m1) hashed message points; static_live: (c, m)
+        host liveness (m = m1 + 1, slot m-1 is the signature pair).
         """
-        c, m1, s = idx_g1.shape
-        X, Y, Z, inf = jac1
-        g = (
-            jnp.take(X, idx_g1.reshape(-1), axis=1).reshape(-1, c, m1, s),
-            jnp.take(Y, idx_g1.reshape(-1), axis=1).reshape(-1, c, m1, s),
-            jnp.take(Z, idx_g1.reshape(-1), axis=1).reshape(-1, c, m1, s),
-            jnp.take(inf, idx_g1.reshape(-1), axis=0).reshape(c, m1, s),
-        )
-        gX, gY, gZ, ginf = _reduce_last(1, g)  # (32, c, m1), (c, m1)
-
-        X2, Y2, Z2, inf2 = jac2
-        e = idx_sig.shape[1]
-        s2 = (
-            jnp.take(X2, idx_sig.reshape(-1), axis=2).reshape(-1, 2, c, e),
-            jnp.take(Y2, idx_sig.reshape(-1), axis=2).reshape(-1, 2, c, e),
-            jnp.take(Z2, idx_sig.reshape(-1), axis=2).reshape(-1, 2, c, e),
-            jnp.take(inf2, idx_sig.reshape(-1), axis=0).reshape(c, e),
-        )
-        sX, sY, sZ, sinf = _reduce_last(2, s2)  # (32, 2, c), (c,)
         return finish(
-            (gX, gY, gZ, ginf), (sX, sY, sZ, sinf), h_x, h_y, static_live
+            # (32, c, m1, s) + (c, m1, s) -> (32, c, m1), (c, m1)
+            _reduce_last(1, _take_entries(jac1, idx_g1, axis=1)),
+            # (32, 2, c, e) + (c, e) -> (32, 2, c), (c,)
+            _reduce_last(2, _take_entries(jac2, idx_sig, axis=2)),
+            h_x, h_y, static_live,
         )
 
     def finish(group_jac, sig_jac, h_x, h_y, static_live):
@@ -344,6 +349,7 @@ def make_chain_ops(interpret: bool = False):
         "single_merge": wrap(single_merge, "single_merge"),
         # host-composed (see comment above prep) — pieces are jitted
         "prep": prep,
+        "take_entries": _take_entries,
         "finish": finish,
         "jadd1": jadd1,
         "jadd2": jadd2,
@@ -417,11 +423,12 @@ def chain_verify(
                 flat_sig.append(sig)
                 flat_coeff.append(coeff)
         n = len(flat_pk)
-        _count_entries(points=n)
-        layout, dead = _chain_layout(checks, interpret)
+        layout = _chain_layout(checks, interpret)
         b = layout.b
+        _count_entries(b, points=n)
 
-        # Flat entry planes, padded with the generator at dead slots.
+        # Flat entry planes, padded with the generator in the lanes past n
+        # (``live`` False; none where the call fills its budget).
         pad = b - n
         pkx, pky = _g1_planes(flat_pk + [C.G1_GENERATOR] * pad)
         sgx, sgy = _g2_planes(flat_sig + [C.G2_GENERATOR] * pad)
@@ -436,29 +443,41 @@ def chain_verify(
         ops = _get_chain_ops(interpret)
         jac1 = ops["ladder_g1"](pkx, pky, kbits, live)
         jac2 = ops["ladder_g2"](sgx, sgy, kbits, live)
-        ok = _dispatch_checks_tail(ops, jac1, jac2, checks, layout, dead)
+        ok = _dispatch_checks_tail(ops, jac1, jac2, checks, layout)
     return _fetch_flags(ok)
 
 
-def _count_entries(**by_shape: int) -> None:
+def _count_entries(b: int, **by_shape: int) -> None:
     """Book the entries entering a chained verify by the shape their
-    pubkeys take (``bls_chain_entries_total{shape}``): once per call,
-    bisection re-checks included — which path verified what."""
+    pubkeys take (``bls_chain_entries_total{shape}``) and the ``b`` lanes
+    they are dispatched at by what a lane holds
+    (``bls_chain_lanes_total{use}``: ``live`` an entry, ``pad`` nothing —
+    the share of the aggregation's and the ladders' per-lane cost that
+    verifies no signature): once per call, bisection re-checks included —
+    which path verified what, at what fill."""
     for shape, n in by_shape.items():
         if n:
             inc("bls_chain_entries_total", value=n, shape=shape)
+    live = sum(by_shape.values())
+    for use, lanes in (("live", live), ("pad", b - live)):
+        if lanes:
+            inc("bls_chain_lanes_total", value=lanes, use=use)
 
 
 def _entry_budget(n: int, interpret: bool) -> tuple[int, int]:
     """Padded flat-entry batch size and the canonical dead-slot index.
 
-    B > n always: index n is the dead slot (live=False -> inf).  The
-    1024-lane quantum only matters for the Pallas tiles; the CPU-testable
-    mode keeps batches tiny.
+    ``b`` is the smallest multiple of the quantum that holds ``n`` (one
+    quantum at least), so a full flush — n a multiple of the quantum —
+    has no padding lane at all.  The dead slot is index ``b``, one past
+    the last lane: ``prep``'s gathers read the identity there, and the
+    ladders, which cost per lane, carry no lane for it.  The 1024-lane
+    quantum only matters for the Pallas tiles; the CPU-testable mode
+    keeps batches tiny.
     """
     q = _QUANTUM if not interpret else 8
-    b = (n // q + 1) * q
-    return b, n
+    b = max(-(-n // q), 1) * q
+    return b, b
 
 
 class ChainLayout(NamedTuple):
@@ -494,16 +513,18 @@ def warmed_chain_layouts() -> tuple[ChainLayout, ...]:
     return tuple(sorted(_WARMED_LAYOUTS))
 
 
-def _chain_layout(checks, interpret: bool) -> tuple[ChainLayout, int]:
-    """The layout ``checks`` are dispatched at and the canonical dead-slot
-    index: the smallest warmed layout that holds the call on every axis,
-    else the call's own (each axis pow2-padded, the entry budget by
+def _chain_layout(checks, interpret: bool) -> ChainLayout:
+    """The layout ``checks`` are dispatched at: the smallest warmed layout that holds the call on every axis, else
+    the call's own (each axis pow2-padded, the entry budget by
     :func:`_entry_budget`).  Padding is what every axis already carries
-    up to its pow2 — dead entries (``live`` False), empty groups
-    (``static_live`` False), dead slots — so a padded call's verdicts are
-    its own layout's."""
+    up to its pow2 — dead entries (``live`` False: only where the call
+    does not fill its last quantum, or is padded up to a warmed layout),
+    empty groups (``static_live`` False), dead slots — so a padded call's
+    verdicts are its own layout's.  The dead slots hold the layout's
+    ``b``, the index one past its last lane (``b >= n``: a full call has
+    no lane to spare), which ``prep`` reads as the identity."""
     n = sum(len(entries) for entries, _, _ in checks)
-    b, dead = _entry_budget(n, interpret)
+    b, _dead = _entry_budget(n, interpret)
     max_groups = max(max((len(h) for _, h, _ in checks), default=1), 1)
     max_slot = 1
     for _, h_points, group_ids in checks:
@@ -519,17 +540,18 @@ def _chain_layout(checks, interpret: bool) -> tuple[ChainLayout, int]:
     )
     fits = [w for w in _WARMED_LAYOUTS
             if w.checks == own.checks and all(x >= y for x, y in zip(w, own))]
-    return min(fits, default=own), dead
+    return min(fits, default=own)
 
 
-def _dispatch_checks_tail(ops, jac1, jac2, checks, layout: ChainLayout, dead: int):
+def _dispatch_checks_tail(ops, jac1, jac2, checks, layout: ChainLayout):
     """The shared back half of every chained verify: gather the laddered
     entries into (check, group, slot) rectangles, reduce, Miller, final
     exp — dispatched, one boolean per check still on the device
     (:func:`_fetch_flags` pulls them back).
 
     ``checks`` supplies only the entry counts, h_points and group_ids
-    here, ``layout`` (:func:`_chain_layout`) the padded rectangles; the
+    here, ``layout`` (:func:`_chain_layout`) the padded rectangles, whose
+    dead slots hold ``layout.b`` — past the last lane, the identity; the
     laddered planes arrive as ``jac1``/``jac2`` whether they came from
     host-packed points (:func:`chain_verify`) or the epoch committee
     cache (:func:`chain_verify_cached`).
@@ -537,6 +559,7 @@ def _dispatch_checks_tail(ops, jac1, jac2, checks, layout: ChainLayout, dead: in
     import jax.numpy as jnp
 
     n_checks, m1, s, e = layout.checks, layout.m1, layout.s, layout.e
+    dead = layout.b  # one past the last lane: prep reads the identity there
     offsets, off = [], 0
     for entries, _, _ in checks:
         offsets.append(off)
@@ -721,14 +744,14 @@ def chain_verify_cached(
 
         flat = [entry for entries, _, _ in checks for entry in entries]
         n = len(flat)
-        layout, dead = _chain_layout(checks, interpret)
+        layout = _chain_layout(checks, interpret)
         b = layout.b
         pad = b - n
 
         with span("agg_index_pack"):  # the index and mask planes of the call
             cid, is_single, idx, idx_inf, attesting = _pack_members(cache, flat, b)
         n_single = int(is_single.sum())
-        _count_entries(single=n_single, committee=n - n_single)
+        _count_entries(b, single=n_single, committee=n - n_single)
 
         sgx, sgy = _g2_planes([sig for _, _, sig, _ in flat] + [C.G2_GENERATOR] * pad)
         live = np.zeros(b, bool)
@@ -764,7 +787,7 @@ def chain_verify_cached(
         jac2 = ops["ladder_g2"](sgx, sgy, kbits, live)
         # layout builder only reads len(entries)/h_points/group_ids — the
         # cached-entry tuples carry the same positional layout contract
-        ok = _dispatch_checks_tail(ops, jac1, jac2, checks, layout, dead)
+        ok = _dispatch_checks_tail(ops, jac1, jac2, checks, layout)
     return _fetch_flags(ok)
 
 

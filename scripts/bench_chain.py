@@ -94,10 +94,9 @@ def run(
     s = BB._pow2(aggs)
     e_slots = BB._pow2(groups * aggs)  # sig slots per check
     mmax = BB._pow2(max(committee // 8, 2))  # correction capacity (12.5%)
-    q = BB._QUANTUM if not interpret else 8
-    b = (a_total + q - 1) // q * q
-    if b == a_total:
-        b += q  # at least one dead lane for padded index slots
+    # padded slots of the index rectangles point at ``dead``: past the
+    # last lane, read as the identity by prep (no lane is kept for it)
+    b, dead = BB._entry_budget(a_total, interpret)
     n_vals = n_committees * committee
 
     # ---- program warmer: the first dispatch of each program pays its
@@ -261,7 +260,6 @@ def run(
             ),
         )
 
-        dead = a_total  # a padded lane; its live flag is False -> inf
         idx_g1 = np.full((inst, m1, s), dead, np.int32)
         idx_sig = np.full((inst, e_slots), dead, np.int32)
         static_live = np.zeros((inst, m1 + 1), bool)

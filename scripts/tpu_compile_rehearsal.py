@@ -126,6 +126,16 @@ def single_device_programs(n_validators: int, coeff_bits: int):
              (g1, g1, kb, lv), {}),
             (f"chain_ladder_g2[{tag} b={b}]", ops["ladder_g2"].jitted,
              (g2, g2, kb, lv), {}),
+            # prep's two gathers: a padded slot holds index b, one past
+            # the last lane, and must read the identity there
+            (f"chain_take_g1[{tag} b={b} c={c} m1={m1} s={s}]",
+             jax.jit(lambda X, Y, Z, inf, idx: ops["take_entries"](
+                 (X, Y, Z, inf), idx, axis=1)),
+             (g1, g1, g1, lv, sds((c, m1, s), I32)), {}),
+            (f"chain_take_g2[{tag} b={b} c={c} e={e}]",
+             jax.jit(lambda X, Y, Z, inf, idx: ops["take_entries"](
+                 (X, Y, Z, inf), idx, axis=2)),
+             (g2, g2, g2, lv, sds((c, e), I32)), {}),
             (f"chain_reduce_g1[{tag} c={c} m1={m1} s={s}]",
              ops["reduce_g1"].jitted,
              (sds((32, c, m1, s), I32),) * 3 + (sds((c, m1, s), BOOL),), {}),
@@ -200,6 +210,8 @@ def mesh_programs(mesh, n_validators: int, coeff_bits: int):
     # one drain: 1,024 entries over 64 messages, dealt round-robin
     n, groups, c = 1024, 64, 1
     nl = -(-n // d)
+    # ops/bls_shard.py's own per-device rule (a dead tail slot on every
+    # device), not the single-device chain's BB._entry_budget
     bl = (nl // BB._QUANTUM + 1) * BB._QUANTUM
     b = d * bl
     m1 = BB._pow2(groups + 1) - 1

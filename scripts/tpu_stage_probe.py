@@ -49,10 +49,7 @@ def main() -> None:
     rng = np.random.default_rng(0)
 
     a_total = inst * groups * aggs
-    q = BB._QUANTUM if not interpret else 8
-    B = (a_total + q - 1) // q * q
-    if B == a_total:
-        B += q
+    B, _dead = BB._entry_budget(a_total, interpret)
     mmax = BB._pow2(max(committee // 8, 2))
     m1 = BB._pow2(groups + 1) - 1
     s = BB._pow2(aggs)

@@ -7,6 +7,8 @@ scripts/bench_chain.py).  Mirrors the reference's aggregate-verify tests
 over bls_nif (ref: native/bls_nif/src/lib.rs:14-158).
 """
 
+import functools
+import random
 import secrets
 
 import numpy as np
@@ -27,13 +29,27 @@ def hs():
     return [hash_to_g2(m, DST_POP) for m in MSGS]
 
 
-def _points_entries() -> float:
-    """``bls_chain_entries_total{shape="points"}`` of the default registry."""
+def _labelled(family: str, **labels) -> float:
+    """A counter family of the default registry, summed over the series
+    that carry ``labels``."""
     from lambda_ethereum_consensus_tpu import telemetry
 
     lines = telemetry.get_metrics().render_prometheus(self_scrape=False).splitlines()
     return sum(float(ln.rsplit(" ", 1)[1]) for ln in lines
-               if ln.startswith("bls_chain_entries_total{") and 'shape="points"' in ln)
+               if ln.startswith(family + "{")
+               and all(f'{k}="{v}"' in ln for k, v in labels.items()))
+
+
+def _points_entries() -> float:
+    return _labelled("bls_chain_entries_total", shape="points")
+
+
+def _lanes() -> dict:
+    return {use: _labelled("bls_chain_lanes_total", use=use) for use in ("live", "pad")}
+
+
+def _lanes_gained(before: dict) -> dict:
+    return {use: v - before[use] for use, v in _lanes().items()}
 
 
 def _mk_check(hs, n=4, n_msgs=2, bad_index=None):
@@ -71,6 +87,162 @@ def test_chain_verify_valid_invalid_empty(hs):
     assert res == [True, False, True, True]
     # the uncached chain books its host-packed entries, once per call
     assert _points_entries() - before == 8
+
+
+# ------------------------ a call that fills its entry budget (n == b, PR 35)
+#
+# The dead slot of the (check, group, slot) rectangles is no lane of the
+# flat batch: ``_entry_budget`` hands out the index one past the last lane
+# and ``prep``'s gathers read the identity there.  In interpret mode the
+# quantum is 8, so 8 and 16 entries are full calls.
+
+Q = 8
+
+
+@pytest.mark.parametrize("n,interpret,b", [
+    (0, True, Q), (1, True, Q), (Q - 1, True, Q), (Q, True, Q),
+    (Q + 1, True, 2 * Q), (2 * Q, True, 2 * Q),
+    # the chip's tile: a block's 128 aggregates, a slot's 1,024, a subnet
+    # flush of 4,096 and its ragged tail
+    (128, False, 1024), (1024, False, 1024), (1025, False, 2048),
+    (3780, False, 4096), (4096, False, 4096),
+])
+def test_entry_budget_is_the_smallest_multiple_that_holds_the_call(n, interpret, b):
+    """No lane is set aside for the dead slot: a full call is dispatched
+    at its own size, and the dead index lies past the last lane."""
+    assert BB._entry_budget(n, interpret) == (b, b)
+
+
+@pytest.mark.parametrize("b,by_shape,want", [
+    (8, {"points": 8}, {"live": 8, "pad": 0}),  # a full call: no padding lane
+    (16, {"single": 3, "committee": 13}, {"live": 16, "pad": 0}),
+    (8, {"points": 5}, {"live": 5, "pad": 3}),
+    (16, {"single": 2, "committee": 7}, {"live": 9, "pad": 7}),
+    (1024, {"committee": 146}, {"live": 146, "pad": 878}),  # a paced flush, padded up
+])
+def test_lanes_counter_books_live_and_pad_once_a_call(b, by_shape, want):
+    before = _lanes()
+    BB._count_entries(b, **by_shape)
+    assert _lanes_gained(before) == want
+
+
+def _trap_check(hs, rng):
+    """Eight entries, 3 over message A and 5 over message B, honest but
+    for the LAST: its signature is ``4 sk H_B + 5 sk H_A`` — what the
+    check would sum to if the 5 padded slots of group A and the 3 of
+    group B (s = 8) each read the last lane's key.  Returns the check and,
+    per entry, ``(group, sk, coeff, signature as scalars over (H_A, H_B))``
+    for :func:`_scalar_verdict`."""
+    entries, gids, book = [], [], []
+    for i, g in enumerate([0, 0, 0, 1, 1, 1, 1, 1]):
+        sk = rng.randrange(1, 1 << 96)
+        coeff = rng.randrange(1, 1 << 16) | 1
+        over = [0, 0]
+        over[g] = sk
+        if i == 7:
+            over = [5 * sk, 4 * sk]
+        sig = C.g2.multiply_raw(hs[0], over[0]) if over[0] else None
+        if over[1]:
+            part = C.g2.multiply_raw(hs[1], over[1])
+            sig = part if sig is None else C.g2.affine_add(sig, part)
+        entries.append((C.g1.multiply_raw(C.G1_GENERATOR, sk), sig, coeff))
+        gids.append(g)
+        book.append((g, sk, coeff, over))
+    return (entries, hs[:2], gids), book
+
+
+def _scalar_verdict(book, s: int, dead_reads) -> bool:
+    """The check's verdict in the exponent (H_A, H_B independent): per
+    message, the keys gathered into its ``s`` slots against the signature
+    sum.  ``dead_reads``: the ``coeff * sk`` a padded slot contributes."""
+    for g in (0, 1):
+        slots = [coeff * sk for gg, sk, coeff, _ in book if gg == g]
+        keys = sum(slots) + (s - len(slots)) * dead_reads
+        sigs = sum(coeff * over[g] for _, _, coeff, over in book)
+        if (keys - sigs) % C.R:
+            return False
+    return True
+
+
+@pytest.fixture(scope="module")
+def full_calls(hs):
+    """Every chained verify of the full-call cases, made once: 2 calls of
+    ``chain_verify`` and one bisection (4 calls), 16-bit coefficients."""
+    from lambda_ethereum_consensus_tpu.crypto.bls import batch as HB
+
+    rng = random.Random(35)
+
+    def entry(g, bad=False):
+        sk = rng.randrange(1, 1 << 96)
+        return (C.g1.multiply_raw(C.G1_GENERATOR, sk), MSGS[g],
+                C.g2.multiply_raw(hs[g], sk + bad))
+
+    out = {}
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(HB, "_COEFF_BITS", 16)
+        mp.setattr(BB, "chain_verify",
+                   functools.partial(BB.chain_verify, interpret=True, coeff_bits=16))
+        # (1 call) 8 entries in groups of 5, 2 and 1: s = 8, so 3 + 6 + 7
+        # padded slots beside a batch with no padding lane
+        valid8 = [entry(g) for g in (0, 0, 0, 0, 0, 1, 1, 2)]
+        before = _lanes()
+        out["valid8"] = BB.chain_verify([HB._pack_check(valid8, DST_POP, {})])
+        out["valid8_lanes"] = _lanes_gained(before)
+        # (1 call, two checks, 16 entries) the clamp trap behind a valid check
+        trap, book = _trap_check(hs, rng)
+        out["trap_book"] = book
+        before = _lanes()
+        out["trap16"] = BB.chain_verify([HB._pack_check(valid8, DST_POP, {}), trap])
+        out["trap16_lanes"] = _lanes_gained(before)
+        # (4 calls) blame by bisection with the wrong signature in the last
+        # lane: 8, 4 + 4, 2 + 2, 1 + 1 entries — the first two levels full
+        blame8 = [entry(g, bad=(i == 7)) for i, g in enumerate((0, 1, 0, 1, 0, 1, 0, 2))]
+        out["blame8_host"] = HB.batch_verify_each_points(blame8)  # the host oracle
+        mp.setenv("BLS_DEVICE_CHAIN", "1")
+        mp.setenv("BLS_DEVICE_CHAIN_MIN", "1")
+        before = _lanes()
+        out["blame8"] = HB.batch_verify_each_points(blame8)
+        out["blame8_lanes"] = _lanes_gained(before)
+    return out
+
+
+@pytest.mark.device
+def test_full_call_all_valid(full_calls):
+    assert full_calls["valid8"] == [True]
+    assert full_calls["valid8_lanes"] == {"live": 8, "pad": 0}
+
+
+@pytest.mark.device
+def test_the_trap_is_a_trap(full_calls):
+    """In the exponent: the forged check fails where a padded slot reads
+    the identity, and would pass where it read the last lane's key (an
+    out-of-range index clamped onto lane b - 1)."""
+    book = full_calls["trap_book"]
+    _, sk, coeff, _ = book[-1]
+    assert _scalar_verdict(book, 8, dead_reads=0) is False
+    assert _scalar_verdict(book, 8, dead_reads=coeff * sk) is True
+
+
+@pytest.mark.device
+@pytest.mark.parametrize("at,want,what", [
+    (0, True, "a valid check: a padded slot that read lane 15 would fail it"),
+    (1, False, "the forged check: a padded slot that read lane 15 would pass it"),
+])
+def test_a_padded_slot_of_a_full_call_reads_the_identity(full_calls, at, want, what):
+    assert full_calls["trap16"][at] is want, what
+    assert full_calls["trap16_lanes"] == {"live": 16, "pad": 0}
+
+
+@pytest.mark.device
+@pytest.mark.parametrize("at", range(8))
+def test_full_call_bisection_equals_the_host_oracle(full_calls, at):
+    assert full_calls["blame8"][at] is full_calls["blame8_host"][at] is (at != 7)
+
+
+@pytest.mark.device
+def test_full_call_bisection_pads_only_below_the_quantum(full_calls):
+    # 8, 4 + 4 are full calls; 2 + 2 and 1 + 1 keep their padding lanes
+    assert full_calls["blame8_lanes"] == {"live": 8 + 8 + 4 + 2, "pad": 4 + 6}
 
 
 @pytest.mark.device

@@ -165,6 +165,28 @@ def test_a_list_longer_than_half_the_committee_is_refused(keys):
 # -------------------- a flush that mixes single, dense and sparse entries
 
 
+@functools.lru_cache(maxsize=None)
+def message_point(g: int):
+    return hash_to_g2(MSGS[g], DST_POP)
+
+
+def flush_entry(keys, rng, cid, misses, g, corrupt=False):
+    """One entry of a flush — committee ``cid`` with ``misses`` seeded
+    absentees signing message ``g`` (``corrupt``: with a wrong secret) —
+    as ``batch_verify_each_cached`` takes it (a single signer where one
+    member is left, else the smaller side) and as the host oracle does."""
+    sks, reg, _ = keys
+    attesting, missing = split(rng, cid, misses)
+    members = attesting.tolist()
+    sk = sum(sks[m] for m in members) + (1 if corrupt else 0)
+    sig = C.g2.multiply_raw(message_point(g), sk)
+    if len(members) == 1:
+        cached = (members[0], None, MSGS[g], sig)
+    else:
+        cached = (cid, BB.smaller_side(attesting, missing), MSGS[g], sig)
+    return cached, (host_sum(reg, members), MSGS[g], sig)
+
+
 @pytest.fixture(scope="module")
 def mixed_flush(keys):
     """Six entries of one flush — a single signer, two dense aggregates
@@ -172,21 +194,8 @@ def mixed_flush(keys):
     one of them signed with a wrong secret — through
     ``batch_verify_each_cached`` (4 chained calls: the flush, then three
     levels of bisection), against ``batch_verify_each_points``."""
-    sks, reg, cache = keys
-    rng = random.Random(11)
-    hs = [hash_to_g2(m, DST_POP) for m in MSGS]
-
-    def entry(cid, misses, g, corrupt=False):
-        attesting, missing = split(rng, cid, misses)
-        members = attesting.tolist()
-        sk = sum(sks[m] for m in members) + (1 if corrupt else 0)
-        sig = C.g2.multiply_raw(hs[g], sk)
-        if len(members) == 1:
-            cached = (members[0], None, MSGS[g], sig)
-        else:
-            cached = (cid, BB.smaller_side(attesting, missing), MSGS[g], sig)
-        return cached, (host_sum(reg, members), MSGS[g], sig)
-
+    _, _, cache = keys
+    entry = functools.partial(flush_entry, keys, random.Random(11))
     pairs = [entry(0, K - 1, 0), entry(0, 1, 0), entry(1, 0, 1),
              entry(1, 6, 1), entry(0, 7, 0, corrupt=True), entry(1, 11, 1)]
     with pytest.MonkeyPatch.context() as mp:
@@ -220,9 +229,68 @@ def test_mixed_flush_counts_entries_by_width_and_side(mixed_flush):
     assert sum(agg.values()) == chain["committee"]
 
 
+# ------------------ a flush that fills its entry budget (n == b, PR 35)
+
+
+def _lanes() -> dict:
+    return {use: _labelled("bls_chain_lanes_total", use=use) for use in ("live", "pad")}
+
+
+@pytest.fixture(scope="module")
+def full_flushes(keys):
+    """Cached calls with no padding lane (interpret mode: quantum 8), their
+    (group, slot) rectangles padded all the same: a flush of 8 that mixes
+    single signers and both committee sides with a wrong secret in the LAST
+    lane (4 chained calls: 8, 4 + 4, 2 + 2, 1 + 1 entries), 8 single
+    signers (1 call), 16 aggregates of both sides (1 call) — against the
+    host oracle over the same points."""
+    _, _, cache = keys
+    entry = functools.partial(flush_entry, keys, random.Random(35))
+    flushes = {
+        # groups of 5 and 3: s = 8, so 3 + 5 padded slots and an empty group
+        "blame8": [entry(0, K - 1, 0), entry(0, 1, 0), entry(1, 0, 0), entry(1, 6, 0),
+                   entry(0, 11, 0), entry(1, 14, 1), entry(1, K - 1, 1),
+                   entry(0, 7, 1, corrupt=True)],
+        "single8": [entry(i % 2, K - 1, g) for i, g in enumerate((0, 0, 0, 0, 0, 0, 1, 1))],
+        # groups of 11 and 5: s = 16
+        "valid16": [entry(i % 2, misses, int(i >= 11)) for i, misses in enumerate(
+            (0, 1, 2, 3, 6, 8, 9, 11, 13, 14, 5, 7, 10, 12, 4, 14))],
+    }
+    out = {}
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(batch_mod, "_COEFF_BITS", 16)
+        mp.setattr(BB, "chain_verify_cached",
+                   functools.partial(BB.chain_verify_cached, coeff_bits=16))
+        for name, pairs in flushes.items():
+            lanes0, chain0 = _lanes(), _chain_counter()
+            cached = batch_verify_each_cached(cache, [c for c, _ in pairs])
+            out[name] = {"cached": cached,
+                         "lanes": _gained(lanes0, _lanes()),
+                         "chain": _gained(chain0, _chain_counter()),
+                         "host": batch_verify_each_points([p for _, p in pairs])}
+    return out
+
+
+@pytest.mark.parametrize("name,want", [
+    ("blame8", [True] * 7 + [False]), ("single8", [True] * 8), ("valid16", [True] * 16)])
+def test_a_full_flush_reads_the_host_oracles_verdicts(full_flushes, name, want):
+    assert full_flushes[name]["cached"] == full_flushes[name]["host"] == want
+
+
+@pytest.mark.parametrize("name,lanes,chain", [
+    # 8, 4 + 4 full; 2 + 2 and 1 + 1 keep padding lanes up to the quantum
+    ("blame8", {"live": 22.0, "pad": 10.0}, {"single": 2 + 2 + 1 + 1, "committee": 6 + 6 + 3 + 1}),
+    ("single8", {"live": 8.0}, {"single": 8.0}),  # pad gains nothing
+    ("valid16", {"live": 16.0}, {"committee": 16.0}),
+])
+def test_a_full_flush_books_no_padding_lane(full_flushes, name, lanes, chain):
+    assert full_flushes[name]["lanes"] == lanes
+    assert full_flushes[name]["chain"] == chain
+
+
 # ------------- a flush below the warmed drain is padded up to its layout
 
-WARMED = BB.ChainLayout(b=24, checks=1, m1=7, s=4, e=16)
+WARMED = BB.ChainLayout(b=16, checks=1, m1=7, s=4, e=16)
 
 
 @pytest.fixture()
@@ -246,9 +314,9 @@ def _checks(*sizes_by_group):
     # (checks, the call's own layout, whether the warmed one takes it)
     (_checks([1]), (8, 1, 1, 1, 1), True),  # a deadline flush of one
     (_checks([3, 2]), (8, 1, 3, 4, 8), True),
-    (_checks([4] * 4), (24, 1, 7, 4, 16), True),  # the warmed drain itself
+    (_checks([4] * 4), (16, 1, 7, 4, 16), True),  # the warmed drain itself: full
     (_checks([1] * 7), (8, 1, 7, 1, 8), True),  # as many groups as it holds
-    (_checks([1] * 8), (16, 1, 15, 1, 8), False),  # one group too many
+    (_checks([1] * 8), (8, 1, 15, 1, 8), False),  # one group too many; a full call
     (_checks([5, 1]), (8, 1, 3, 8, 8), False),  # a group too large
     (_checks([4] * 4 + [1]), (24, 1, 7, 4, 32), False),  # too many entries
     (_checks([2], [1]), (8, 2, 1, 2, 2), False),  # a bisection level: two checks
@@ -256,13 +324,15 @@ def _checks(*sizes_by_group):
 def test_a_call_inside_a_warmed_layout_is_padded_up_to_it(warmed_layout, checks, own, padded):
     """Every distinct layout is a set of six programs: a call that fits
     inside the warmed one on every axis runs at it, any other at its own
-    pow2-padded layout, as before."""
+    pow2-padded layout, as before.  The dead slot is no lane of either
+    (the index b, which ``prep`` reads as the identity:
+    ``test_bls_chain.py``), so a call may fill its layout (b == n)."""
     n = sum(len(c[0]) for c in checks)
-    layout, dead = BB._chain_layout(checks, interpret=True)
-    assert dead == n and layout.b > n
+    layout = BB._chain_layout(checks, interpret=True)
     assert layout == (warmed_layout if padded else BB.ChainLayout(*own))
     BB._WARMED_LAYOUTS.clear()  # nothing warmed: every call at its own
-    assert BB._chain_layout(checks, interpret=True) == (BB.ChainLayout(*own), n)
+    assert BB._chain_layout(checks, interpret=True) == BB.ChainLayout(*own)
+    assert layout.b >= own[0] >= n > own[0] - 8  # never a whole quantum of padding lanes
 
 
 def test_a_padded_flush_reads_the_same_verdicts(keys, warmed_layout, monkeypatch):
@@ -290,7 +360,7 @@ def test_a_padded_flush_reads_the_same_verdicts(keys, warmed_layout, monkeypatch
     monkeypatch.setattr(BB, "chain_verify_cached",
                         functools.partial(BB.chain_verify_cached, coeff_bits=16))
     assert batch_verify_each_cached(cache, entries) == [True, False, True]
-    assert shapes[0] == tuple(warmed_layout)  # the flush: 3 entries at (24, 1, 7, 4, 16)
+    assert shapes[0] == tuple(warmed_layout)  # the flush: 3 entries at (16, 1, 7, 4, 16)
     assert [sh[1] for sh in shapes[1:]] == [2, 2]  # [a] with [bad, c]; [bad] with [c]
     padded = list(shapes)
     BB._WARMED_LAYOUTS.clear()
@@ -300,8 +370,9 @@ def test_a_padded_flush_reads_the_same_verdicts(keys, warmed_layout, monkeypatch
 
 
 @pytest.mark.parametrize("entries,groups,want", [
-    (1024, 64, (2048, 1, 127, 16, 1024)),  # a slot's aggregate channel (head cells)
-    (4096, 64, (5120, 1, 127, 64, 4096)),  # a flush of the all-subnets drain
+    # a full flush: no second tile of lanes for the dead slot
+    (1024, 64, (1024, 1, 127, 16, 1024)),  # a slot's aggregate channel (head cells)
+    (4096, 64, (4096, 1, 127, 64, 4096)),  # a flush of the all-subnets drain
 ])
 def test_the_warmer_registers_the_layout_it_dispatches(monkeypatch, entries, groups, want):
     """``start_warmer`` advertises the drain's layout, beside its shape

@@ -72,13 +72,18 @@ def programs(one_chip):
     "merkle_tree_jnp[depth=17]",  # the 2^20-entry balances subtree
     # the 2^20-validator registry subtree: ~9 s alone, over 10 s under load
     pytest.param("merkle_tree_jnp[depth=19]", marks=pytest.mark.slow),
-    "chain_ladder_g1[gossip b=2048]",  # one gossip drain: 1,024 entries
+    # one gossip drain: 1,024 entries in 1,024 lanes
+    "chain_ladder_g1[gossip b=1024]",
     "chain_norm_g1[gossip c=1 m1=127]",  # its 64 message groups
+    # prep's gathers over the ladders' outputs: the padded slots' index b
+    # is out of range and filled with the identity
+    "chain_take_g1[gossip b=1024 c=1 m1=127 s=16]",
+    "chain_take_g2[gossip b=1024 c=1 e=1024]",
     # a sparse drain's aggregation: the smaller side at half the committee
-    "chain_agg_corrected[gossip b=2048 w=256]",
+    "chain_agg_corrected[gossip b=1024 w=256]",
     # one subnet flush: 4,096 one-bit votes, pubkeys gathered by index
-    "chain_single_gather[subnet b=5120]",
-    "chain_ladder_g1[subnet b=5120]",
+    "chain_single_gather[subnet b=4096]",
+    "chain_ladder_g1[subnet b=4096]",
 ])
 def test_program_compiles_for_v5e(one_chip, programs, name):
     fn, shapes, static = programs[name]
@@ -90,5 +95,5 @@ def test_program_compiles_for_v5e(one_chip, programs, name):
                  + mem.temp_size_in_bytes)
     assert 0 < footprint < 16 << 30  # one v5e chip's HBM
     # plain jnp (a tree of hashes; an index gather); the rest are kernels
-    if not name.startswith(("merkle_tree_jnp", "chain_single_gather")):
+    if not name.startswith(("merkle_tree_jnp", "chain_single_gather", "chain_take_g")):
         assert "tpu_custom_call" in compiled.as_text()
