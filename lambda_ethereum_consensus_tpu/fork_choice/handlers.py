@@ -128,28 +128,30 @@ def _on_block(store: Store, signed_block, execution_engine, spec: ChainSpec) -> 
         pre_state, signed_block, validate_result=True,
         execution_engine=execution_engine, spec=spec,
     )
-    root = block.hash_tree_root(spec)
-    store.add_block(root, block, state)
-    forensics = getattr(store, "forensics", None)
-    if forensics is not None:
-        # evidence ledger: a second distinct root for (slot, proposer)
-        # is a double proposal — observed here, AFTER full validation,
-        # so only blocks that actually entered fork choice count
-        forensics.note_block(root, int(block.slot), int(block.proposer_index))
+    with span("on_block_store_update"):
+        root = block.hash_tree_root(spec)
+        store.add_block(root, block, state)
+        forensics = getattr(store, "forensics", None)
+        if forensics is not None:
+            # evidence ledger: a second distinct root for (slot, proposer)
+            # is a double proposal — observed here, AFTER full validation,
+            # so only blocks that actually entered fork choice count
+            forensics.note_block(root, int(block.slot), int(block.proposer_index))
 
-    # proposer boost for timely blocks (first 1/INTERVALS_PER_SLOT of the slot)
-    time_into_slot = (store.time - store.genesis_time) % spec.SECONDS_PER_SLOT
-    is_before_attesting_interval = time_into_slot < (
-        spec.SECONDS_PER_SLOT // constants.INTERVALS_PER_SLOT
-    )
-    if store.current_slot(spec) == block.slot and is_before_attesting_interval:
-        store.proposer_boost_root = root
-        store.bump()
+        # proposer boost for timely blocks (first 1/INTERVALS_PER_SLOT of the slot)
+        time_into_slot = (store.time - store.genesis_time) % spec.SECONDS_PER_SLOT
+        is_before_attesting_interval = time_into_slot < (
+            spec.SECONDS_PER_SLOT // constants.INTERVALS_PER_SLOT
+        )
+        if store.current_slot(spec) == block.slot and is_before_attesting_interval:
+            store.proposer_boost_root = root
+            store.bump()
 
-    update_checkpoints(
-        store, state.current_justified_checkpoint, state.finalized_checkpoint
-    )
-    compute_pulled_up_tip(store, root, state, spec)
+        update_checkpoints(
+            store, state.current_justified_checkpoint, state.finalized_checkpoint
+        )
+    with span("on_block_pulled_up_tip"):
+        compute_pulled_up_tip(store, root, state, spec)
     return root
 
 

@@ -14,7 +14,6 @@ from __future__ import annotations
 import asyncio
 import logging
 import time
-from collections import Counter
 from dataclasses import dataclass
 from typing import Awaitable, Callable
 
@@ -298,17 +297,6 @@ class TopicSubscription:
             )
 
 
-def _count_decoded(pairs, metric_topic: str) -> None:
-    """``gossip_decoded_total``: a drain's decoded messages by the decode
-    plan kind of their SSZ type (``none``: a topic without one) — once a
-    drain, not once a message (a lane's topics share a type: one ``inc``)."""
-    counts = Counter(sub.ssz_type for sub, _ in pairs)
-    specs = {sub.ssz_type: sub.spec for sub, _ in pairs}
-    for ssz_type, count in counts.items():
-        kind = "none" if ssz_type is None else ssz_type.decode_plan_kind(specs[ssz_type])
-        get_metrics().inc("gossip_decoded_total", value=count, topic=metric_topic, kind=kind)
-
-
 async def _drain_decode_verify(
     owner, items, handler, metric_topic: str, log_name: str
 ) -> None:
@@ -331,9 +319,8 @@ async def _drain_decode_verify(
     The bracket is opened on the first item's port (a node has one; a
     verdict for any other port would go out as a batch of its own).
     Two stage spans split the drain around the handler:
-    ``gossip_decode`` (the decode loop; ``gossip_decoded_total`` beside
-    it counts what it decoded) and ``gossip_verdicts`` (a hand-over: its
-    loop and its round trip).
+    ``gossip_decode`` (the decode loop) and ``gossip_verdicts`` (a
+    hand-over: its loop and its round trip).
 
     ``items`` are ``(subscription, msg_id, payload, peer_id, trace)``;
     ``handler`` receives ``[(subscription, GossipMessage)]`` pairs.
@@ -357,7 +344,6 @@ async def _drain_decode_verify(
                 rejected.append((sub, msg_id))
                 continue
             pairs.append((sub, GossipMessage(msg_id, data, peer_id, value, trace)))
-    _count_decoded(pairs, metric_topic)
     if rejected:  # before the handler: the peer's penalty does not wait for the verify
         with span("gossip_verdicts", topic=metric_topic):
             async with rejected[0][0].port.verdict_batch():

@@ -33,6 +33,7 @@ from ..store import (
     get_finalized_anchor,
     set_finalized_anchor,
 )
+from ..telemetry import gc_timer_install, gc_timer_remove
 from ..tracing import SlotClock, get_recorder, observe_head_update
 from ..types.beacon import BeaconBlock, BeaconBlockBody, BeaconState, SignedBeaconBlock
 from .chain import LiveChainView
@@ -142,6 +143,7 @@ class BeaconNode:
         self.duties = None  # DutyScheduler when config.duty_keys is set
         self._duty_task: asyncio.Task | None = None
         self._head_root: bytes | None = None  # last head seen by _on_applied
+        self._gc_threshold: tuple | None = None  # set by start(), restored by stop()
         # consensus forensics plane (round 24): per-NODE for the same
         # reason as the metrics registry above — co-resident fleet
         # members each keep their own reorg/evidence story.  Attached to
@@ -181,6 +183,7 @@ class BeaconNode:
         spec = self.spec
         self._gc_threshold = gc.get_threshold()  # process-wide, as the hash backend; stop() undoes
         gc.set_threshold(GC_YOUNG_OBJECTS, *self._gc_threshold[1:])
+        gc_timer_install()
         self._install_device_paths()
         self.kv = KvStore(self.config.db_path)
         self.blocks_db = BlockStore(self.kv)
@@ -576,13 +579,15 @@ class BeaconNode:
             await self._start_network()
 
     def _on_applied(self, root: bytes, signed: SignedBeaconBlock) -> None:
-        self.blocks_db.store_block(signed, self.spec)
+        with span("store_block"):
+            self.blocks_db.store_block(signed, self.spec)
         self.states_db.store_state(root, self.store.block_states[root], self.spec)
         self.metrics.set_gauge("sync_store_slot", signed.message.slot)
-        # a block apply can advance finality mid-slot; barrier now rather
-        # than waiting for the next tick (still batched per epoch)
-        self._persist_finality()
-        self._observe_head_transition()
+        with span("head_observe"):
+            # a block apply can advance finality mid-slot; barrier now rather
+            # than waiting for the next tick (still batched per epoch)
+            self._persist_finality()
+            self._observe_head_transition()
 
     def _observe_head_transition(self) -> None:
         """Record the head-update slot-phase metric whenever the cached
@@ -970,7 +975,10 @@ class BeaconNode:
 
     async def stop(self) -> None:
         self._stopping = True
-        gc.set_threshold(*getattr(self, "_gc_threshold", gc.get_threshold()))
+        if self._gc_threshold is not None:
+            gc.set_threshold(*self._gc_threshold)
+            gc_timer_remove()
+            self._gc_threshold = None
         if self._warmer is not None:
             # the drain-warmer is daemonized and bounded, but a stop()
             # that returns while it still compiles programs races the

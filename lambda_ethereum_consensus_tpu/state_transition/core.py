@@ -100,13 +100,20 @@ def process_block(
     """Full capella block processing (the reference wires only withdrawals +
     sync aggregate — ref: state_transition.ex:117-126)."""
     spec = spec or get_chain_spec()
-    operations.process_block_header(state, block, spec)
-    operations.process_withdrawals(state, block.body.execution_payload, spec)
-    operations.process_execution_payload(state, block.body, execution_engine, spec)
-    operations.process_randao(state, block.body, spec)
-    operations.process_eth1_data(state, block.body, spec)
+    # the payload's prev_randao check reads the mix process_randao then
+    # updates: the header and randao + eth1 are two entries of one span
+    # (state_transition's proposer signature a third)
+    with span("block_fixed_checks"):
+        operations.process_block_header(state, block, spec)
+    with span("block_payload"):
+        operations.process_withdrawals(state, block.body.execution_payload, spec)
+        operations.process_execution_payload(state, block.body, execution_engine, spec)
+    with span("block_fixed_checks"):
+        operations.process_randao(state, block.body, spec)
+        operations.process_eth1_data(state, block.body, spec)
     operations.process_operations(state, block.body, execution_engine, spec)
-    operations.process_sync_aggregate(state, block.body.sync_aggregate, spec)
+    with span("block_sync_aggregate"):
+        operations.process_sync_aggregate(state, block.body.sync_aggregate, spec)
 
 
 def state_transition(
@@ -121,19 +128,22 @@ def state_transition(
     block = signed_block.message
     with span("block_transition"):
         ws = BeaconStateMut(state)
-        _process_slots_mut(ws, block.slot, spec)
-        if validate_result and not verify_block_signature(ws, signed_block, spec):
-            raise StateTransitionError("invalid block signature")
+        with span("block_slots"):
+            _process_slots_mut(ws, block.slot, spec)
+        with span("block_fixed_checks"):
+            if validate_result and not verify_block_signature(ws, signed_block, spec):
+                raise StateTransitionError("invalid block signature")
         try:
             process_block(ws, block, execution_engine, spec)
         except OperationError as e:
             raise StateTransitionError(str(e)) from None
-        out = ws.freeze()
-        if validate_result:
-            expect_root = state_root(out, spec)
-            if bytes(block.state_root) != expect_root:
-                raise StateTransitionError(
-                    f"state root mismatch: block {bytes(block.state_root).hex()} "
-                    f"!= computed {expect_root.hex()}"
-                )
+        with span("block_post_root"):
+            out = ws.freeze()
+            if validate_result:
+                expect_root = state_root(out, spec)
+                if bytes(block.state_root) != expect_root:
+                    raise StateTransitionError(
+                        f"state root mismatch: block {bytes(block.state_root).hex()} "
+                        f"!= computed {expect_root.hex()}"
+                    )
     return out

@@ -16,6 +16,7 @@ from __future__ import annotations
 from ..config import ChainSpec, constants, get_chain_spec
 from ..crypto import bls
 from ..ssz import hash as ssz_hash
+from ..telemetry import span
 from ..types.beacon import (
     BeaconBlockHeader,
     Validator,
@@ -214,23 +215,25 @@ def process_attestation(
         "committee index out of range",
     )
 
-    # participation accounting (altair): may raise for bad source
-    try:
-        flag_indices = accessors.get_attestation_participation_flag_indices(
-            state, data, state.slot - data.slot, spec
-        )
-    except ValueError as e:
-        raise OperationError(str(e)) from None
+    with span("block_att_committee"):
+        # participation accounting (altair): may raise for bad source
+        try:
+            flag_indices = accessors.get_attestation_participation_flag_indices(
+                state, data, state.slot - data.slot, spec
+            )
+        except ValueError as e:
+            raise OperationError(str(e)) from None
 
-    indexed = accessors.get_indexed_attestation(state, attestation, spec)
+        indexed = accessors.get_indexed_attestation(state, attestation, spec)
     if defer_signatures is not None:
         # structural validity of the index set still checks NOW (sorted,
         # unique, in-range — OperationError on failure); only the pairing
         # work defers.  The inputs ride along so verification never
         # recomputes the pubkey extraction / signing root.
-        pubkeys, signing_root = predicates.indexed_attestation_signature_inputs(
-            state, indexed, spec
-        )
+        with span("block_att_signature_inputs"):
+            pubkeys, signing_root = predicates.indexed_attestation_signature_inputs(
+                state, indexed, spec
+            )
         defer_signatures.append((attestation, indexed, pubkeys, signing_root))
     else:
         expect(
@@ -238,34 +241,35 @@ def process_attestation(
             "invalid attestation signature",
         )
 
-    which = "current" if data.target.epoch == current_epoch else "previous"
-    participation = getattr(state, f"{which}_epoch_participation")
+    with span("block_att_participation"):
+        which = "current" if data.target.epoch == current_epoch else "previous"
+        participation = getattr(state, f"{which}_epoch_participation")
 
-    proposer_reward_numerator = 0
-    # get_base_reward per attester, with its per-increment factor (one
-    # O(registry) total-active-balance reduction) taken once, not per index
-    per_increment = accessors.get_base_reward_per_increment(state, spec)
-    base_rewards = {
-        i: state.validators[i].effective_balance
-        // spec.EFFECTIVE_BALANCE_INCREMENT * per_increment
-        for i in indexed.attesting_indices
-    }
-    for index in indexed.attesting_indices:
-        for flag_index, weight in enumerate(constants.PARTICIPATION_FLAG_WEIGHTS):
-            flag = 1 << flag_index
-            if flag_index in flag_indices and not participation[index] & flag:
-                participation[index] |= flag
-                proposer_reward_numerator += base_rewards[index] * weight
+        proposer_reward_numerator = 0
+        # get_base_reward per attester, with its per-increment factor (one
+        # O(registry) total-active-balance reduction) taken once, not per index
+        per_increment = accessors.get_base_reward_per_increment(state, spec)
+        base_rewards = {
+            i: state.validators[i].effective_balance
+            // spec.EFFECTIVE_BALANCE_INCREMENT * per_increment
+            for i in indexed.attesting_indices
+        }
+        for index in indexed.attesting_indices:
+            for flag_index, weight in enumerate(constants.PARTICIPATION_FLAG_WEIGHTS):
+                flag = 1 << flag_index
+                if flag_index in flag_indices and not participation[index] & flag:
+                    participation[index] |= flag
+                    proposer_reward_numerator += base_rewards[index] * weight
 
-    proposer_reward_denominator = (
-        (constants.WEIGHT_DENOMINATOR - constants.PROPOSER_WEIGHT)
-        * constants.WEIGHT_DENOMINATOR
-        // constants.PROPOSER_WEIGHT
-    )
-    proposer_reward = proposer_reward_numerator // proposer_reward_denominator
-    increase_balance(
-        state, accessors.get_beacon_proposer_index(state, spec), proposer_reward
-    )
+        proposer_reward_denominator = (
+            (constants.WEIGHT_DENOMINATOR - constants.PROPOSER_WEIGHT)
+            * constants.WEIGHT_DENOMINATOR
+            // constants.PROPOSER_WEIGHT
+        )
+        proposer_reward = proposer_reward_numerator // proposer_reward_denominator
+        increase_balance(
+            state, accessors.get_beacon_proposer_index(state, spec), proposer_reward
+        )
 
 
 def _verify_deferred_attestations(state, deferred, spec) -> bool:
@@ -684,13 +688,13 @@ def process_operations(
     for op in body.attester_slashings:
         process_attester_slashing(state, op, spec)
     deferred: list = []
-    for op in body.attestations:
-        process_attestation(state, op, spec, defer_signatures=deferred)
+    with span("block_attestations"):
+        for op in body.attestations:
+            process_attestation(state, op, spec, defer_signatures=deferred)
     if deferred:
-        expect(
-            _verify_deferred_attestations(state, deferred, spec),
-            "invalid attestation signature",
-        )
+        with span("block_att_verify"):
+            verified = _verify_deferred_attestations(state, deferred, spec)
+        expect(verified, "invalid attestation signature")
     for op in body.deposits:
         process_deposit(state, op, spec)
     for op in body.voluntary_exits:

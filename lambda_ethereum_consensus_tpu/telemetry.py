@@ -22,6 +22,8 @@ perf PRs report against:
   also writes a ``jax.profiler.TraceAnnotation`` named ``span:<name>``,
   so an idle gap on the device's timeline can be laid to what the host
   was doing.  Outside a capture it costs one global read per span entry.
+- **The collector** (:func:`gc_timer_install`): a running node books
+  every garbage collection into ``gc_collect_seconds{generation}``.
 - **No-op mode** (``TELEMETRY_OFF=1``, or ``Metrics(enabled=False)``):
   every recording call returns after one attribute check, ``span()``
   returns a shared inert context manager, and no metric keys are ever
@@ -37,6 +39,7 @@ circular import).  ``node/telemetry.py`` re-exports everything.
 
 from __future__ import annotations
 
+import gc
 import logging
 import os
 import threading
@@ -53,6 +56,8 @@ __all__ = [
     "annotate_spans",
     "device_fault",
     "device_fault_state",
+    "gc_timer_install",
+    "gc_timer_remove",
     "get_metrics",
     "inc",
     "observe",
@@ -83,7 +88,6 @@ _HELP = {
     "gossip_queue_depth": "queued gossip messages at drain start",
     "gossip_drain_seconds": "one gossip batch: decode + verify + verdicts",
     "gossip_decode_seconds": "one gossip batch's snappy + SSZ decode loop",
-    "gossip_decoded_total": "gossip messages decoded (snappy + SSZ) on their way to a handler, one increment a drain, by the decode plan kind of the topic's SSZ type (flat|mixed|fields)",
     "gossip_verdicts_seconds": "one gossip batch's verdict hand-over: trace ends + every validate_message staged, then the batch's one sidecar round trip",
     "gossip_shed_count": "gossip messages dropped at admission, by topic/reason",
     "ingest_lane_depth": "queued items per ingest scheduler lane",
@@ -116,6 +120,21 @@ _HELP = {
     "state_kv_put_seconds": "kv.put of one stored state's record (the complete SSZ) and of its slot-index key",
     "state_encode_fields_total": "big fields of a stored state by how their encoded image was brought level: reused (no delta), patched (logged elements re-serialized), rebuilt (column-wise full build: no chain to vouch)",
     "block_transition_seconds": "full state transition of one block (slots + block + state-root check)",
+    "block_slots_seconds": "inside block_transition: the slots advanced to the block's (process_slot's roots; at a boundary process_epoch)",
+    "block_fixed_checks_seconds": "inside block_transition, three a block: the proposer's signature, the header, then randao + eth1 vote",
+    "block_payload_seconds": "inside block_transition: process_withdrawals (the sweep) + process_execution_payload (the payload's roots)",
+    "block_attestations_seconds": "inside block_transition: the loop of process_attestation over the body's attestations, the deferred signature verify excluded",
+    "block_att_committee_seconds": "inside process_attestation, one an attestation: flag indices, then get_indexed_attestation (committee, bits to indices)",
+    "block_att_signature_inputs_seconds": "inside process_attestation, one a deferred attestation: indexed_attestation_signature_inputs",
+    "block_att_participation_seconds": "inside process_attestation, one an attestation: base rewards, the participation flag loop, the proposer reward",
+    "block_att_verify_seconds": "inside block_transition: a block's attestation signatures as one batched check (decompression, contexts, the cached chain)",
+    "block_sync_aggregate_seconds": "inside block_transition: process_sync_aggregate (fast-aggregate verify of the committee, rewards)",
+    "block_post_root_seconds": "inside block_transition: the post-state frozen and its state-root check",
+    "on_block_store_update_seconds": "on_block after the transition: block root, store.add_block, forensics note, proposer boost, update_checkpoints",
+    "on_block_pulled_up_tip_seconds": "on_block's last step: compute_pulled_up_tip (the unrealized justification pass)",
+    "store_block_seconds": "a block applied by the node: blocks_db.store_block",
+    "head_observe_seconds": "a block applied by the node: finality persisted and the head transition observed",
+    "gc_collect_seconds": "one garbage collection by generation (0|1|2), start to stop, booked by the gc.callbacks hook of a running node",
     "epoch_transition_seconds": "one epoch-boundary processing pass (resident or host path)",
     "epoch_plane_sync_seconds": "resident epoch path: the plane's sync, which ships the columns' deltas since the last boundary (first boundary of a lineage: the full upload)",
     "epoch_plane_sweep_seconds": "resident epoch path: first dispatch (the epoch sums) to the last fetched result (the hysteresis mask); the donated sweep runs under the host's justification, registry updates and slashings",
@@ -704,6 +723,69 @@ def observe(name: str, value: float, **labels) -> None:
 
 def set_gauge(name: str, value: float, **labels) -> None:
     get_metrics().set_gauge(name, value, **labels)
+
+
+# ------------------------------------------------------------ the collector
+#
+# A collection can start inside any allocation, one made under
+# ``Metrics._lock`` included (``_observe_key`` allocates a histogram there),
+# and the lock is not re-entrant: the callback takes no lock and creates no
+# span.  Its three histograms are resolved once, at install; collections
+# run one at a time under the GIL, so it updates them directly.  It writes
+# no trace annotation.
+
+
+class _GcTimer:
+    __slots__ = ("_metrics", "_hists", "_t0")
+
+    def __init__(self, metrics: Metrics):
+        self._metrics = metrics
+        self._hists = [
+            metrics._hist_handle(("gc_collect_seconds", (("generation", str(g)),)))
+            for g in range(3)
+        ]
+        self._t0 = None
+
+    def __call__(self, phase: str, info: dict) -> None:
+        if phase == "start":
+            self._t0 = time.perf_counter()
+            return
+        t0, self._t0 = self._t0, None
+        if t0 is None or not self._metrics._enabled:
+            return
+        dt = time.perf_counter() - t0
+        bounds, hist = self._hists[info["generation"]]
+        hist.counts[bisect_left(bounds, dt)] += 1
+        hist.sum += dt
+        hist.count += 1
+
+
+_GC_TIMER: _GcTimer | None = None
+_GC_USERS = 0
+_GC_LOCK = threading.Lock()
+
+
+def gc_timer_install() -> None:
+    """Book every garbage collection into ``gc_collect_seconds{generation}``
+    on the default registry, once per process however many nodes run;
+    a disabled registry gets no hook and no key."""
+    global _GC_TIMER, _GC_USERS
+    metrics = get_metrics()
+    with _GC_LOCK:
+        _GC_USERS += 1
+        if _GC_TIMER is None and metrics._enabled:
+            _GC_TIMER = _GcTimer(metrics)
+            gc.callbacks.append(_GC_TIMER)
+
+
+def gc_timer_remove() -> None:
+    """Undo one :func:`gc_timer_install`; the last one takes the hook out."""
+    global _GC_TIMER, _GC_USERS
+    with _GC_LOCK:
+        _GC_USERS = max(0, _GC_USERS - 1)
+        if _GC_USERS == 0 and _GC_TIMER is not None:
+            gc.callbacks.remove(_GC_TIMER)
+            _GC_TIMER = None
 
 
 # ----------------------------------------------------- device-fault health

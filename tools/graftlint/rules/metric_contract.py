@@ -6,7 +6,7 @@ Three artifacts must agree on every metric family:
    exposition documents), plus families synthesized directly as
    exposition text (``# HELP <name> ...`` string literals);
 2. the emitters — ``inc``/``observe``/``set_gauge``/``span``/
-   ``bound_span``/``_observe_key`` call sites across the package,
+   ``bound_span``/``_observe_key``/``_hist_handle`` call sites across the package,
    including one-level wrappers (a function whose parameter flows into
    the name position collects its call-site literals — how the
    slot-phase families reach ``observe``) and module-level key-tuple
@@ -248,7 +248,7 @@ class MetricContractRule:
                             # but not the registry methods/helpers
                             # themselves (their call sites are pass 1)
                             wrappers[fi.name] = params.index(arg0.id)
-                    elif cname == "_observe_key" and node.args:
+                    elif cname in ("_observe_key", "_hist_handle") and node.args:
                         fam = self._key_tuple_family(node.args[0], module)
                         if fam:
                             note(fam, set(), "histogram", module.rel, node.lineno)
