@@ -1,10 +1,12 @@
 """Spec ``compute_*`` helpers (ref: lib/.../state_transition/misc.ex:14-270).
 
-The swap-or-not shuffle is implemented whole-permutation and vectorized:
-instead of the reference's per-index 90-round walk (misc.ex:33-77), one numpy
-pass shuffles *every* index per round — the batched shape that a device
-backend can take over wholesale.  A per-``(seed, count)`` LRU keeps the
-permutation for the many committee lookups within an epoch.
+The swap-or-not shuffle has two paths, by what the caller reads.  The
+committees read all of an epoch's permutation: one vectorized numpy pass
+shuffles *every* index per round (instead of the reference's per-index
+90-round walk, misc.ex:33-77), and a per-``(seed, count)`` LRU keeps it for
+the many committee lookups within an epoch.  The proposer reads one
+candidate at a time under a seed that mixes in the slot, so it takes the
+spec's single-index walk, memoised on its pure arguments.
 """
 
 from __future__ import annotations
@@ -16,6 +18,7 @@ import numpy as np
 
 from ..config import ChainSpec, constants, get_chain_spec
 from ..ssz import hash as ssz_hash
+from ..telemetry import inc
 from ..types.beacon import ForkData, SigningData
 
 hash_bytes = ssz_hash.sha256
@@ -84,13 +87,10 @@ def compute_shuffled_indices(
     return indices
 
 
-def compute_shuffled_index(
-    index: int, index_count: int, seed: bytes, spec: ChainSpec | None = None
+def _shuffled_index_walk(
+    index: int, index_count: int, seed: bytes, round_count: int
 ) -> int:
-    """Single-index swap-or-not walk (spec-literal; used by tests as oracle)."""
-    spec = spec or get_chain_spec()
-    assert index < index_count
-    for rnd in range(spec.SHUFFLE_ROUND_COUNT):
+    for rnd in range(round_count):
         pivot = _round_pivot(seed, rnd, index_count)
         flip = (pivot + index_count - index) % index_count
         position = max(index, flip)
@@ -101,6 +101,28 @@ def compute_shuffled_index(
         if (byte >> (position % 8)) & 1:
             index = flip
     return index
+
+
+def compute_shuffled_index(
+    index: int, index_count: int, seed: bytes, spec: ChainSpec | None = None
+) -> int:
+    """Single-index swap-or-not walk (spec-literal).  The proposer draws
+    its candidates by this walk (memoised: ``_proposer_candidate_position``);
+    tests hold ``compute_shuffled_indices`` to it."""
+    spec = spec or get_chain_spec()
+    assert index < index_count
+    return _shuffled_index_walk(index, index_count, seed, spec.SHUFFLE_ROUND_COUNT)
+
+
+@functools.lru_cache(maxsize=256)
+def _proposer_candidate_position(
+    index: int, index_count: int, seed: bytes, round_count: int
+) -> int:
+    """The walk for one proposer candidate.  Pure in its arguments, so the
+    ~130 proposer lookups of one block cost one walk; balances and the
+    active set stay outside the memo."""
+    inc("proposer_shuffle_walks_total")
+    return _shuffled_index_walk(index, index_count, seed, round_count)
 
 
 def _shuffled_permutation(index_count: int, seed: bytes, spec: ChainSpec) -> tuple:
@@ -145,15 +167,20 @@ def compute_proposer_index(
     seed: bytes,
     spec: ChainSpec | None = None,
 ) -> int:
-    """Balance-weighted proposer sampling over the shuffled candidate stream."""
+    """Balance-weighted proposer sampling over the shuffled candidate
+    stream, candidate ``i`` drawn by the single-index walk as the spec
+    writes it: never the whole permutation, whose seed no other slot
+    shares."""
     spec = spec or get_chain_spec()
     assert len(indices) > 0
     max_eb = spec.MAX_EFFECTIVE_BALANCE
     total = len(indices)
-    perm = _shuffled_permutation(total, seed, spec)
     i = 0
     while True:
-        candidate = indices[perm[i % total]]
+        position = _proposer_candidate_position(
+            i % total, total, seed, spec.SHUFFLE_ROUND_COUNT
+        )
+        candidate = indices[position]
         random_byte = hash_bytes(seed + (i // 32).to_bytes(8, "little"))[i % 32]
         if effective_balances[candidate] * 255 >= max_eb * random_byte:
             return int(candidate)

@@ -122,6 +122,7 @@ _HELP = {
     "block_transition_seconds": "full state transition of one block (slots + block + state-root check)",
     "block_slots_seconds": "inside block_transition: the slots advanced to the block's (process_slot's roots; at a boundary process_epoch)",
     "block_fixed_checks_seconds": "inside block_transition, three a block: the proposer's signature, the header, then randao + eth1 vote",
+    "proposer_shuffle_walks_total": "single-index swap-or-not walks run for proposer candidates (a memo miss; the memo keys on index, count, seed and rounds): one an imported block at 32 ETH, none for the block's further proposer lookups",
     "block_payload_seconds": "inside block_transition: process_withdrawals (the sweep) + process_execution_payload (the payload's roots)",
     "block_attestations_seconds": "inside block_transition: the loop of process_attestation over the body's attestations, the deferred signature verify excluded",
     "block_att_committee_seconds": "inside process_attestation, one an attestation: flag indices, then get_indexed_attestation (committee, bits to indices)",

@@ -2,7 +2,12 @@
 
 import pytest
 
-from lambda_ethereum_consensus_tpu.config import constants, minimal_spec, use_chain_spec
+from lambda_ethereum_consensus_tpu.config import (
+    constants,
+    mainnet_spec,
+    minimal_spec,
+    use_chain_spec,
+)
 from lambda_ethereum_consensus_tpu.crypto import bls
 from lambda_ethereum_consensus_tpu.state_transition import (
     StateTransitionError,
@@ -16,6 +21,7 @@ from lambda_ethereum_consensus_tpu.state_transition.core import (
 )
 from lambda_ethereum_consensus_tpu.state_transition.genesis import build_genesis_state
 from lambda_ethereum_consensus_tpu.state_transition.mutable import BeaconStateMut
+from lambda_ethereum_consensus_tpu.telemetry import get_metrics
 from lambda_ethereum_consensus_tpu.types.beacon import (
     BeaconBlock,
     BeaconBlockBody,
@@ -49,6 +55,89 @@ def test_vectorized_shuffle_matches_scalar_oracle(minimal):
     for i in range(n):
         assert perm[i] == misc.compute_shuffled_index(i, n, seed, minimal)
     assert sorted(perm) == list(range(n))
+
+
+def _proposer_by_permutation(ebs, indices, seed, spec):
+    """The proposer as chosen from the whole permutation, and how many
+    candidates the acceptance loop turned down before it."""
+    total = len(indices)
+    perm = misc.compute_shuffled_indices(total, seed, spec.SHUFFLE_ROUND_COUNT)
+    i = 0
+    while True:
+        candidate = indices[perm[i % total]]
+        random_byte = misc.hash_bytes(seed + (i // 32).to_bytes(8, "little"))[i % 32]
+        if ebs[candidate] * 255 >= spec.MAX_EFFECTIVE_BALANCE * random_byte:
+            return int(candidate), i
+        i += 1
+
+
+def _balances(mix, n, spec):
+    full = spec.MAX_EFFECTIVE_BALANCE
+    step = spec.EFFECTIVE_BALANCE_INCREMENT
+    if mix == "full":
+        return [full] * n
+    if mix == "mixed":
+        return [(v * 7 % 32 + 1) * step for v in range(n)]
+    return [step] * n  # "low": one candidate in ~32 is accepted
+
+
+@pytest.mark.parametrize("mix", ["full", "mixed", "low"])
+@pytest.mark.parametrize("count", [1, 2, 37, 64, 300])
+@pytest.mark.parametrize("preset", ["minimal", "mainnet"])
+def test_proposer_walk_matches_whole_permutation(preset, count, mix):
+    spec = minimal_spec() if preset == "minimal" else mainnet_spec()
+    # validator indices that are not the positions: every third one is active
+    indices = [3 * k + 1 for k in range(count)]
+    ebs = _balances(mix, 3 * count + 2, spec)
+    turned_down = 0
+    for s in range(4):
+        seed = misc.hash_bytes(bytes([s, count % 256, len(preset)]))
+        want, rejected = _proposer_by_permutation(ebs, indices, seed, spec)
+        assert misc.compute_proposer_index(ebs, indices, seed, spec) == want
+        turned_down += rejected
+    if mix == "low":
+        assert turned_down > 0
+
+
+def _with_balances(state, mix, spec):
+    ebs = _balances(mix, len(state.validators), spec)
+    return state.copy(
+        validators=[
+            v.copy(effective_balance=eb) for v, eb in zip(state.validators, ebs)
+        ]
+    )
+
+
+@pytest.mark.parametrize("slot", [0, 3, 7])
+@pytest.mark.parametrize("mix", ["full", "mixed"])
+def test_proposer_same_on_mutable_and_frozen_state(genesis, mix, slot):
+    state, spec = genesis
+    with use_chain_spec(spec):
+        frozen = _with_balances(state, mix, spec)
+        assert not hasattr(frozen, "registry")  # the list fallback
+        assert accessors.get_beacon_proposer_index(
+            BeaconStateMut(frozen), spec, slot=slot
+        ) == accessors.get_beacon_proposer_index(frozen, spec, slot=slot)
+
+
+def test_proposer_lookups_walk_once_per_seed(genesis):
+    state, spec = genesis
+    metrics = get_metrics()
+    was = metrics.enabled
+    metrics.set_enabled(True)
+    try:
+        with use_chain_spec(spec):
+            ws = BeaconStateMut(state)
+            misc._proposer_candidate_position.cache_clear()
+            walks = metrics.get("proposer_shuffle_walks_total")
+            misses = misc.compute_shuffled_indices.cache_info().misses
+            first = accessors.get_beacon_proposer_index(ws, spec)
+            assert metrics.get("proposer_shuffle_walks_total") == walks + 1
+            assert accessors.get_beacon_proposer_index(ws, spec) == first
+            assert metrics.get("proposer_shuffle_walks_total") == walks + 1
+            assert misc.compute_shuffled_indices.cache_info().misses == misses
+    finally:
+        metrics.set_enabled(was)
 
 
 def test_committees_partition_active_set(genesis):
