@@ -38,7 +38,7 @@ import os
 import secrets
 from typing import Sequence
 
-from ...telemetry import device_fault
+from ...telemetry import device_fault, inc, span
 from ...utils.env import device_default
 from . import curve as C
 from .curve import DeserializationError
@@ -269,6 +269,41 @@ def _verify_points_host(
     return pairing_check(pairs)
 
 
+def _bisect(n: int, check_level) -> list[bool]:
+    """Per-entry flags of ``n`` entries by level-synchronous bisection:
+    ``check_level(ranges)`` judges one level's index ranges together (one
+    chained dispatch where the device is on) and a failed range of more
+    than one entry is halved for the next level.  The levels after the
+    first are the blame: ``bls_bisect`` spans them, once a flush whose
+    first check failed, and ``bls_bisect_checks_total{result}`` books each
+    range they judge."""
+    flags = [False] * n
+
+    def level(pending):
+        oks = check_level(pending)
+        nxt: list[list[int]] = []
+        for index_range, ok in zip(pending, oks):
+            if ok:
+                for i in index_range:
+                    flags[i] = True
+            elif len(index_range) > 1:
+                mid = len(index_range) // 2
+                nxt.append(index_range[:mid])
+                nxt.append(index_range[mid:])
+        return nxt, oks
+
+    pending = level([list(range(n))])[0] if n else []
+    if pending:
+        with span("bls_bisect"):
+            while pending:
+                pending, oks = level(pending)
+                passed = sum(map(bool, oks))
+                for result, count in (("pass", passed), ("fail", len(oks) - passed)):
+                    if count:
+                        inc("bls_bisect_checks_total", value=count, result=result)
+    return flags
+
+
 def batch_verify_each_points(
     entries: Sequence[PointEntry], dst: bytes = DST_POP
 ) -> list[bool]:
@@ -280,7 +315,6 @@ def batch_verify_each_points(
     items into a drain costs O(log N) device round-trips, not
     O(b log N) sequential checks.
     """
-    flags = [False] * len(entries)
     message_points: dict[tuple[bytes, bytes], C.AffinePoint] = {}
 
     def check_many(ranges: list[list[int]]) -> list[bool]:
@@ -319,20 +353,7 @@ def batch_verify_each_points(
             for r in ranges
         ]
 
-    pending = [list(range(len(entries)))] if entries else []
-    while pending:
-        oks = check_many(pending)
-        nxt: list[list[int]] = []
-        for index_range, ok in zip(pending, oks):
-            if ok:
-                for i in index_range:
-                    flags[i] = True
-            elif len(index_range) > 1:
-                mid = len(index_range) // 2
-                nxt.append(index_range[:mid])
-                nxt.append(index_range[mid:])
-        pending = nxt
-    return flags
+    return _bisect(len(entries), check_many)
 
 
 def batch_verify_each_cached(
@@ -363,7 +384,6 @@ def batch_verify_each_cached(
     """
     from ...ops.bls_batch import chain_verify_cached
 
-    flags = [False] * len(entries)
     if message_points is None:
         message_points = {}
 
@@ -385,8 +405,7 @@ def batch_verify_each_cached(
             packed.append((comm_id, miss, sig, secrets.randbits(_COEFF_BITS) | 1))
         return (packed, h_points, gids)
 
-    pending = [list(range(len(entries)))] if len(entries) else []
-    while pending:
+    def check_level(pending):
         # ranges with an undecodable signature are invalid by definition
         dead_ranges = {
             k for k, r in enumerate(pending) if any(entries[i][3] is None for i in r)
@@ -400,17 +419,9 @@ def batch_verify_each_cached(
                 live, chain_verify_cached(cache, (pack(r) for _, r in live))
             ):
                 oks[k] = ok
-        nxt: list[list[int]] = []
-        for k, index_range in enumerate(pending):
-            if oks[k]:
-                for i in index_range:
-                    flags[i] = True
-            elif len(index_range) > 1:
-                mid = len(index_range) // 2
-                nxt.append(index_range[:mid])
-                nxt.append(index_range[mid:])
-        pending = nxt
-    return flags
+        return [oks[k] for k in range(len(pending))]
+
+    return _bisect(len(entries), check_level)
 
 
 def batch_verify(

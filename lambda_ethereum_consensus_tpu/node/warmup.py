@@ -76,6 +76,33 @@ class DrainShapes:
             e=BB._pow2(per_check),
         )
 
+    def bisection_layouts(self, interpret: bool):
+        """The ladder of :class:`...ops.bls_batch.ChainLayout` rungs below
+        one drain at these shapes: level ``k`` of a failed flush re-checks
+        two ranges of at most ``r = ceil(entries / 2^k)`` entries, which
+        hold at most ``min(groups, r)`` messages and, where the flush fits
+        the drain's layout, at most ``min(s, r)`` entries of one message.
+        Each rung is that bound, so every level of a flush with one bad
+        entry lands on a rung whatever the arrival order and wherever the
+        bad entry sits.  A flush with bad entries in both halves of a range
+        re-checks more than two ranges a level: those levels keep layouts
+        of their own."""
+        from ..ops import bls_batch as BB
+
+        drain_s = self.chain_layout(interpret).s
+        rungs, r = [], self.entries
+        while r > 1:
+            r = -(-r // 2)  # the larger half of a range
+            b, _dead = BB._entry_budget(2 * r, interpret)
+            rungs.append(BB.ChainLayout(
+                b=b,
+                checks=2,
+                m1=BB._pow2(min(self.groups, r) + 1) - 1,
+                s=min(drain_s, BB._pow2(r)),
+                e=BB._pow2(r),
+            ))
+        return rungs
+
 
 def warm_sharded_programs(shapes: DrainShapes) -> float:
     """Dispatch one dummy SHARDED verify at ``shapes`` — the mesh
@@ -109,13 +136,14 @@ def warm_sharded_programs(shapes: DrainShapes) -> float:
 
 
 def warm_drain_programs(shapes: DrainShapes) -> float:
-    """Dispatch one dummy drain at ``shapes``; blocks until every program
-    ran on device.  Returns seconds spent (load/compile time).  On a
-    multi-device mesh with the sharded plane selected, the SHARDED
-    executables are warmed first — they are what the scheduler's flushes
-    will actually dispatch — and the single-device programs after (the
-    fallback, and the committee-cache drain's op set)."""
-    import jax
+    """Dispatch one dummy drain at ``shapes``, then one dummy call at each
+    rung of its bisection ladder (:meth:`DrainShapes.bisection_layouts`);
+    blocks until every program ran on device.  Returns seconds spent
+    (load/compile time).  On a multi-device mesh with the sharded plane
+    selected, the SHARDED executables are warmed first — they are what the
+    scheduler's flushes will actually dispatch — and the single-device
+    programs after (the fallback, and the committee-cache drain's op
+    set)."""
     import jax.numpy as jnp
     import numpy as np
 
@@ -130,7 +158,6 @@ def warm_drain_programs(shapes: DrainShapes) -> float:
     t_single = time.perf_counter()
 
     with compile_context("warmup:drain"):
-        b, _checks, m1, s, e = shapes.chain_layout(interpret)
         kp = BB._pow2(shapes.committee)
         mmax = BB._pow2(max(shapes.committee // 8, 2))
 
@@ -142,32 +169,41 @@ def warm_drain_programs(shapes: DrainShapes) -> float:
             jnp.zeros((chunk, kp), bool),
         )
         sx = jnp.zeros((32, shapes.n_committees), jnp.int32)
-        ax, ay, _ = ops["agg_corrected"](
-            zreg, zreg, sx, sx,
-            jnp.zeros((b,), jnp.int32),
-            jnp.zeros((b, mmax), jnp.int32),
-            jnp.ones((b, mmax), bool),
-            jnp.zeros((b,), bool),
-        )
-        kb = jnp.zeros((shapes.coeff_bits, b), jnp.int32)
-        lv = jnp.zeros((b,), bool)
-        jac1 = ops["ladder_g1"](ax, ay, kb, lv)
-        jac2 = ops["ladder_g2"](
-            jnp.zeros((32, 2, b), jnp.int32), jnp.zeros((32, 2, b), jnp.int32),
-            kb, lv,
-        )
-        px, py, qx, qy, mask = ops["prep"](
-            jac1, jac2,
-            jnp.zeros((shapes.checks, m1, s), jnp.int32),
-            jnp.zeros((shapes.checks, e), jnp.int32),
-            jnp.zeros((32, 2, shapes.checks, m1), jnp.int32),
-            jnp.zeros((32, 2, shapes.checks, m1), jnp.int32),
-            jnp.zeros((shapes.checks, m1 + 1), bool),
-        )
-        f = ops["miller"](px, py, qx, qy)
-        np.asarray(ops["check_tail"](f, mask))  # pull: blocks until loaded
+        laddered = {}  # entry budget b -> its two laddered planes
+
+        def dispatch(b, checks, m1, s, e):
+            if b not in laddered:
+                ax, ay, _ = ops["agg_corrected"](
+                    zreg, zreg, sx, sx,
+                    jnp.zeros((b,), jnp.int32),
+                    jnp.zeros((b, mmax), jnp.int32),
+                    jnp.ones((b, mmax), bool),
+                    jnp.zeros((b,), bool),
+                )
+                kb = jnp.zeros((shapes.coeff_bits, b), jnp.int32)
+                lv = jnp.zeros((b,), bool)
+                laddered[b] = (ops["ladder_g1"](ax, ay, kb, lv), ops["ladder_g2"](
+                    jnp.zeros((32, 2, b), jnp.int32), jnp.zeros((32, 2, b), jnp.int32),
+                    kb, lv,
+                ))
+            px, py, qx, qy, mask = ops["prep"](
+                *laddered[b],
+                jnp.zeros((checks, m1, s), jnp.int32),
+                jnp.zeros((checks, e), jnp.int32),
+                jnp.zeros((32, 2, checks, m1), jnp.int32),
+                jnp.zeros((32, 2, checks, m1), jnp.int32),
+                jnp.zeros((checks, m1 + 1), bool),
+            )
+            f = ops["miller"](px, py, qx, qy)
+            np.asarray(ops["check_tail"](f, mask))  # pull: blocks until loaded
+
+        dispatch(*shapes.chain_layout(interpret))
+        t_ladder = time.perf_counter()
+        observe("warmup_phase_seconds", t_ladder - t_single, phase="drain")
+        for rung in shapes.bisection_layouts(interpret):
+            dispatch(*rung)
     observe(
-        "warmup_phase_seconds", time.perf_counter() - t_single, phase="drain"
+        "warmup_phase_seconds", time.perf_counter() - t_ladder, phase="bisection"
     )
     return time.perf_counter() - t0
 
@@ -272,11 +308,14 @@ def start_warmer(
 
     register_shape_bucket("attestation_entries", shapes.entries)
     # ... and the chain pads a flush BELOW the warmed drain (a deadline
-    # flush, a slot's ragged tail) up to this layout: each layout is a set
-    # of six programs, and only this one is ever loaded before traffic
+    # flush, a slot's ragged tail) up to this layout, and each bisection
+    # level of a flush with one bad entry up to its rung of the ladder: each
+    # layout is a set of programs, and only these are loaded before traffic
     from ..ops import bls_batch as BB
 
-    BB.register_chain_layout(shapes.chain_layout(not BB._use_planes()))
+    interpret = not BB._use_planes()
+    for layout in (shapes.chain_layout(interpret), *shapes.bisection_layouts(interpret)):
+        BB.register_chain_layout(layout)
     for bucket in DEFAULT_BATCH_BUCKETS:
         register_shape_bucket("witness_verify", bucket)
     for bucket in DEFAULT_SIGN_BUCKETS:

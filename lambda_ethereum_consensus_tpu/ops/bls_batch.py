@@ -494,10 +494,11 @@ class ChainLayout(NamedTuple):
 
 
 # Layouts whose programs a warmer has dispatched (node/warmup.py: the
-# drain's).  A call that fits inside one is padded up to it with dead
-# entries, groups and slots, so a flush of ANY size below the warmed drain
-# — a deadline flush, a slot's ragged tail — runs the programs already
-# resident instead of a set of its own.
+# drain's and the bisection ladder below it).  A call that fits inside one
+# is padded up to it with dead entries, groups and slots, so a flush of ANY
+# size below the warmed drain — a deadline flush, a slot's ragged tail —
+# and every bisection level of a flush that holds one bad entry run the
+# programs already resident instead of a set of their own.
 _WARMED_LAYOUTS: set[ChainLayout] = set()
 
 
@@ -522,7 +523,9 @@ def _chain_layout(checks, interpret: bool) -> ChainLayout:
     empty groups (``static_live`` False), dead slots — so a padded call's
     verdicts are its own layout's.  The dead slots hold the layout's
     ``b``, the index one past its last lane (``b >= n``: a full call has
-    no lane to spare), which ``prep`` reads as the identity."""
+    no lane to spare), which ``prep`` reads as the identity.  Books
+    ``bls_chain_layouts_total{layout="warmed"|"own"}``: an ``own`` layout is
+    a program set that no warmer loaded, compiled or loaded inside the call."""
     n = sum(len(entries) for entries, _, _ in checks)
     b, _dead = _entry_budget(n, interpret)
     max_groups = max(max((len(h) for _, h, _ in checks), default=1), 1)
@@ -540,6 +543,7 @@ def _chain_layout(checks, interpret: bool) -> ChainLayout:
     )
     fits = [w for w in _WARMED_LAYOUTS
             if w.checks == own.checks and all(x >= y for x, y in zip(w, own))]
+    inc("bls_chain_layouts_total", layout="warmed" if fits else "own")
     return min(fits, default=own)
 
 

@@ -113,6 +113,9 @@ _HELP = {
     "bls_dispatch_seconds": "one chained verify's program calls and the layout packing between them (the device works meanwhile)",
     "bls_chain_entries_total": "entries entering a chained device verify, by the shape their pubkeys take (single = gathered from the registry planes by validator index, committee = cached committee sum less the missing members or, below half participation, the attesting members' sum, points = host-packed points uploaded per call); bisection re-checks included",
     "bls_chain_lanes_total": "lanes of the flat entry batch a chained device verify is dispatched at, by use (live = holds an entry, pad = padding up to the 1,024-lane tile or to a warmed layout: aggregation and ladders cost per lane whatever it holds); once a call, bisection re-checks included",
+    "bls_chain_layouts_total": "chained device verifies by the layout they were dispatched at (warmed = padded up to a layout a warmer loaded: the drain's or a rung of its bisection ladder; own = the call's own layout, a program set compiled or loaded inside the call)",
+    "bls_bisect_seconds": "blame by bisection after a flush's first check failed: every level after the first, once per such flush",
+    "bls_bisect_checks_total": "ranges judged by bisection after a flush's first check, by result (pass = every entry of the range valid, fail = halved again or, at one entry, REJECTed)",
     "bls_device_wait_seconds": "host blocked fetching one chained verify's verdict flags from the device",
     "votes_apply_seconds": "vectorized latest-message + head-cache update for one drain's accepted votes",
     "fork_choice_on_block_seconds": "one fork-choice on_block: checks, state transition, store update",

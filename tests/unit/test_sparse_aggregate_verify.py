@@ -377,7 +377,8 @@ def test_a_padded_flush_reads_the_same_verdicts(keys, warmed_layout, monkeypatch
 def test_the_warmer_registers_the_layout_it_dispatches(monkeypatch, entries, groups, want):
     """``start_warmer`` advertises the drain's layout, beside its shape
     bucket, before the background dispatch — the one layout a smaller
-    flush is padded up to."""
+    flush is padded up to — and the rungs of its bisection ladder (two
+    checks each: ``test_bisection_ladder.py``)."""
     from lambda_ethereum_consensus_tpu.node import warmup
     from lambda_ethereum_consensus_tpu.ops import aot
 
@@ -391,16 +392,20 @@ def test_the_warmer_registers_the_layout_it_dispatches(monkeypatch, entries, gro
                  "warm_kzg"):
         monkeypatch.setattr(warmup, name, lambda *a, **k: 0.0)
     warmup.start_warmer(shapes, {}).join()
-    assert BB.warmed_chain_layouts() == (BB.ChainLayout(*want),)
+    ladder = shapes.bisection_layouts(interpret=False)
+    assert [w.checks for w in ladder] == [2] * (entries.bit_length() - 1)
+    registered = sorted({BB.ChainLayout(*want), *ladder})
+    assert BB.warmed_chain_layouts() == tuple(registered)
+    assert [w for w in BB.warmed_chain_layouts() if w.checks == 1] == [BB.ChainLayout(*want)]
     assert aot.shape_buckets("attestation_entries") == (entries,)
     assert "aggregate_entries" not in aot.all_shape_buckets()
-    # ... and /debug/compile shows it beside the buckets
+    # ... and /debug/compile shows them beside the buckets
     import json
 
     from lambda_ethereum_consensus_tpu.api.beacon_api import BeaconApiServer
 
     data = json.loads(BeaconApiServer(store=None, spec=None)._debug_compile()[2])["data"]
-    assert data["warmed_chain_layouts"] == [BB.ChainLayout(*want)._asdict()]
+    assert data["warmed_chain_layouts"] == [w._asdict() for w in registered]
 
 
 # ------------------------------------------------ both call sites' source
