@@ -84,21 +84,23 @@ class DrainShapes:
         the drain's layout, at most ``min(s, r)`` entries of one message.
         Each rung is that bound, so every level of a flush with one bad
         entry lands on a rung whatever the arrival order and wherever the
-        bad entry sits.  A flush with bad entries in both halves of a range
+        bad entry sits.  Every rung keeps the drain's ``b``: a level
+        re-checks its ranges on the laddered planes of the flush's first
+        check (``ops/bls_batch.chain_recheck``), whose lanes are the
+        drain's.  A flush with bad entries in both halves of a range
         re-checks more than two ranges a level: those levels keep layouts
         of their own."""
         from ..ops import bls_batch as BB
 
-        drain_s = self.chain_layout(interpret).s
+        drain = self.chain_layout(interpret)
         rungs, r = [], self.entries
         while r > 1:
             r = -(-r // 2)  # the larger half of a range
-            b, _dead = BB._entry_budget(2 * r, interpret)
             rungs.append(BB.ChainLayout(
-                b=b,
+                b=drain.b,
                 checks=2,
                 m1=BB._pow2(min(self.groups, r) + 1) - 1,
-                s=min(drain_s, BB._pow2(r)),
+                s=min(drain.s, BB._pow2(r)),
                 e=BB._pow2(r),
             ))
         return rungs

@@ -50,6 +50,9 @@ from .bls_pairing import _pow2_pad as _pow2
 __all__ = [
     "chain_verify",
     "chain_verify_cached",
+    "chain_verify_cached_planes",
+    "chain_recheck",
+    "LadderedPlanes",
     "CommitteeSide",
     "smaller_side",
     "aggregate_g1_chain",
@@ -443,7 +446,7 @@ def chain_verify(
         ops = _get_chain_ops(interpret)
         jac1 = ops["ladder_g1"](pkx, pky, kbits, live)
         jac2 = ops["ladder_g2"](sgx, sgy, kbits, live)
-        ok = _dispatch_checks_tail(ops, jac1, jac2, checks, layout)
+        ok = _dispatch_tail(ops, jac1, jac2, _tail_operands(checks, layout))
     return _fetch_flags(ok)
 
 
@@ -453,8 +456,10 @@ def _count_entries(b: int, **by_shape: int) -> None:
     they are dispatched at by what a lane holds
     (``bls_chain_lanes_total{use}``: ``live`` an entry, ``pad`` nothing —
     the share of the aggregation's and the ladders' per-lane cost that
-    verifies no signature): once per call, bisection re-checks included —
-    which path verified what, at what fill."""
+    verifies no signature): once per call that ladders its entries —
+    which path verified what, at what fill.  A re-check on a flush's
+    laddered planes (:func:`chain_recheck`) books nothing here: no entry
+    enters a ladder there."""
     for shape, n in by_shape.items():
         if n:
             inc("bls_chain_entries_total", value=n, shape=shape)
@@ -514,7 +519,7 @@ def warmed_chain_layouts() -> tuple[ChainLayout, ...]:
     return tuple(sorted(_WARMED_LAYOUTS))
 
 
-def _chain_layout(checks, interpret: bool) -> ChainLayout:
+def _chain_layout(checks, interpret: bool, b: int | None = None) -> ChainLayout:
     """The layout ``checks`` are dispatched at: the smallest warmed layout that holds the call on every axis, else
     the call's own (each axis pow2-padded, the entry budget by
     :func:`_entry_budget`).  Padding is what every axis already carries
@@ -523,11 +528,15 @@ def _chain_layout(checks, interpret: bool) -> ChainLayout:
     empty groups (``static_live`` False), dead slots — so a padded call's
     verdicts are its own layout's.  The dead slots hold the layout's
     ``b``, the index one past its last lane (``b >= n``: a full call has
-    no lane to spare), which ``prep`` reads as the identity.  Books
+    no lane to spare), which ``prep`` reads as the identity.  A re-check
+    (:func:`chain_recheck`) passes the ``b`` of the planes it reads: its
+    layout keeps that budget, and only a warmed layout at that very ``b``
+    holds it.  Books
     ``bls_chain_layouts_total{layout="warmed"|"own"}``: an ``own`` layout is
     a program set that no warmer loaded, compiled or loaded inside the call."""
-    n = sum(len(entries) for entries, _, _ in checks)
-    b, _dead = _entry_budget(n, interpret)
+    fixed_b = b
+    if b is None:
+        b, _dead = _entry_budget(sum(len(entries) for entries, _, _ in checks), interpret)
     max_groups = max(max((len(h) for _, h, _ in checks), default=1), 1)
     max_slot = 1
     for _, h_points, group_ids in checks:
@@ -542,32 +551,33 @@ def _chain_layout(checks, interpret: bool) -> ChainLayout:
         e=_pow2(max((len(c[0]) for c in checks), default=1) or 1),
     )
     fits = [w for w in _WARMED_LAYOUTS
-            if w.checks == own.checks and all(x >= y for x, y in zip(w, own))]
+            if w.checks == own.checks and all(x >= y for x, y in zip(w, own))
+            and fixed_b in (None, w.b)]
     inc("bls_chain_layouts_total", layout="warmed" if fits else "own")
     return min(fits, default=own)
 
 
-def _dispatch_checks_tail(ops, jac1, jac2, checks, layout: ChainLayout):
-    """The shared back half of every chained verify: gather the laddered
-    entries into (check, group, slot) rectangles, reduce, Miller, final
-    exp — dispatched, one boolean per check still on the device
-    (:func:`_fetch_flags` pulls them back).
+def _tail_operands(checks, layout: ChainLayout, offsets=None):
+    """The host planes of a chained verify's tail, uploaded: the (check,
+    group, slot) and (check, slot) rectangles of lane indices, the hashed
+    message points and the static liveness.
 
     ``checks`` supplies only the entry counts, h_points and group_ids
     here, ``layout`` (:func:`_chain_layout`) the padded rectangles, whose
-    dead slots hold ``layout.b`` — past the last lane, the identity; the
-    laddered planes arrive as ``jac1``/``jac2`` whether they came from
-    host-packed points (:func:`chain_verify`) or the epoch committee
-    cache (:func:`chain_verify_cached`).
-    """
+    dead slots hold ``layout.b`` — past the last lane, the identity.
+    Entry ``ei`` of check ``ci`` reads lane ``offsets[ci] + ei``: by
+    default the running sum of the entry counts (the checks' entries
+    laddered in order); a re-check passes each range's first index in
+    the flush whose planes it reads."""
     import jax.numpy as jnp
 
     n_checks, m1, s, e = layout.checks, layout.m1, layout.s, layout.e
     dead = layout.b  # one past the last lane: prep reads the identity there
-    offsets, off = [], 0
-    for entries, _, _ in checks:
-        offsets.append(off)
-        off += len(entries)
+    if offsets is None:
+        offsets, off = [], 0
+        for entries, _, _ in checks:
+            offsets.append(off)
+            off += len(entries)
 
     idx_g1 = np.full((n_checks, m1, s), dead, np.int32)
     idx_sig = np.full((n_checks, e), dead, np.int32)
@@ -591,16 +601,20 @@ def _dispatch_checks_tail(ops, jac1, jac2, checks, layout: ChainLayout):
     hx, hy = _g2_planes(h_points_padded)
     hx = hx.reshape(32, 2, n_checks, m1)
     hy = hy.reshape(32, 2, n_checks, m1)
+    return tuple(jnp.asarray(a) for a in (idx_g1, idx_sig, hx, hy, static_live))
 
-    px, py, qx, qy, mask = ops["prep"](
-        jac1,
-        jac2,
-        jnp.asarray(idx_g1),
-        jnp.asarray(idx_sig),
-        jnp.asarray(hx),
-        jnp.asarray(hy),
-        jnp.asarray(static_live),
-    )
+
+def _dispatch_tail(ops, jac1, jac2, operands):
+    """The shared back half of every chained verify: ``prep`` gathers the
+    laddered entries into (check, group, slot) rectangles and reduces
+    them, then ``miller`` and ``check_tail`` — dispatched, one boolean per
+    check still on the device (:func:`_fetch_flags` pulls them back).  The
+    laddered planes arrive as ``jac1``/``jac2`` from host-packed points
+    (:func:`chain_verify`) or the epoch committee cache
+    (:func:`chain_verify_cached_planes`, whose planes
+    :func:`chain_recheck` reads again); ``operands`` from
+    :func:`_tail_operands`."""
+    px, py, qx, qy, mask = ops["prep"](jac1, jac2, *operands)
     # miller preserves the (C, m) batch shape; the group axis is already
     # innermost, exactly what check_tail's masked product reduces.
     f = ops["miller"](px, py, qx, qy)
@@ -644,7 +658,8 @@ def _pack_members(cache: "DeviceCommitteeCache", flat, b: int):
     (``None`` where every entry is a single signer); ``attesting`` (b,)
     which side each list is.  ``w`` is the smallest of ``cache.widths``
     that holds the call's longest list: read from the miss counts, never
-    from a flag.  Books ``bls_agg_entries_total{width, side}``.
+    from a flag.  Books ``bls_agg_entries_total{width, side}`` (a re-check
+    on the first check's planes packs no members and books none).
     """
     cid = np.zeros(b, np.int32)
     is_single = np.zeros(b, bool)
@@ -683,6 +698,19 @@ def _pack_members(cache: "DeviceCommitteeCache", flat, b: int):
         if count:
             inc("bls_agg_entries_total", value=count, width=str(w), side=side)
     return cid, is_single, idx, idx_inf, attesting
+
+
+class LadderedPlanes(NamedTuple):
+    """The laddered entry planes of one cached chained verify, still on the
+    device: ``r_i * pk_i`` (``jac1``) and ``r_i * sig_i`` (``jac2``) as
+    Jacobian coordinates plus infinity flags, lane ``i`` for the call's
+    entry ``i`` over its ``b`` lanes (a dead lane reads the identity).  No
+    chain program donates its inputs, so a tail may read them any number
+    of times (:func:`chain_recheck`)."""
+
+    jac1: tuple
+    jac2: tuple
+    b: int
 
 
 def chain_verify_cached(
@@ -727,6 +755,19 @@ def chain_verify_cached(
     (their aggregate is the infinity point, invalid per the spec's
     fast-aggregate-verify preconditions).
     """
+    return chain_verify_cached_planes(cache, checks, interpret, coeff_bits)[0]
+
+
+def chain_verify_cached_planes(
+    cache: "DeviceCommitteeCache",
+    checks,
+    interpret: bool | None = None,
+    coeff_bits: int = _COEFF_BITS,
+) -> tuple[list[bool], LadderedPlanes | None]:
+    """:func:`chain_verify_cached`, handing back the call's laddered planes
+    beside its flags (``None`` where the call holds no check): the first
+    check of a flush that bisects keeps them, and every level after it
+    re-checks its ranges on them (:func:`chain_recheck`)."""
     import jax.numpy as jnp
 
     # batch quantization and op set must match the ops the CACHE compiled
@@ -744,7 +785,7 @@ def chain_verify_cached(
     with span("bls_host_pack"):
         checks = list(checks)
         if not checks:
-            return []
+            return [], None
 
         flat = [entry for entries, _, _ in checks for entry in entries]
         n = len(flat)
@@ -791,7 +832,57 @@ def chain_verify_cached(
         jac2 = ops["ladder_g2"](sgx, sgy, kbits, live)
         # layout builder only reads len(entries)/h_points/group_ids — the
         # cached-entry tuples carry the same positional layout contract
-        ok = _dispatch_checks_tail(ops, jac1, jac2, checks, layout)
+        ok = _dispatch_tail(ops, jac1, jac2, _tail_operands(checks, layout))
+    return _fetch_flags(ok), LadderedPlanes(jac1, jac2, b)
+
+
+def chain_recheck(
+    cache: "DeviceCommitteeCache", planes: LadderedPlanes, checks, offsets
+) -> list[bool]:
+    """Re-check ranges of a flush on the laddered planes of its first check:
+    the chain's tail alone — ``prep`` -> ``miller`` -> ``check_tail``.
+
+    ``planes``: the first check's (:func:`chain_verify_cached_planes`),
+    whose one check held the whole flush, entry ``i`` in lane ``i``.
+    ``checks``: an iterable of ``(entries, h_points, group_ids)``, of whose
+    entries only the count is read; ``offsets``: each check's first index
+    in the flush — a bisection range is a contiguous slice of it, so entry
+    ``ei`` of check ``ci`` reads lane ``offsets[ci] + ei``.  No member
+    index planes, signature limbs or scalar bits are packed, no coefficient
+    is drawn, and neither aggregation nor ladder runs: the call is
+    dispatched at the planes' ``b`` (:func:`_chain_layout`), a rung of the
+    warmed bisection ladder where the warmer holds one.
+
+    Soundness.  The coefficients ``r_i`` are drawn once a flush, by its
+    first check: secret, uniform, odd and 64 bits wide (``BLS_RLC_BITS``),
+    never revealed.  The check of a subset S is still the small-exponents
+    batch test over S: ``prod_g e(sum_{i in S, g} r_i pk_i, H_g) *
+    e(-g1, sum_{i in S} r_i sig_i) == 1``.  A subset of valid entries
+    passes whatever the coefficients.  A false pass needs ``sum_{i in S
+    and Bad} r_i d_i = 0 (mod q)``, ``d_i != 0`` entry i's error exponent:
+    impossible with one bad entry in S (``r_i`` is odd and smaller than
+    ``q``), probability at most 2^-63 over the draw for a fixed subset
+    with more.  Which subsets bisection judges depends on earlier
+    outcomes, and so on the same ``r_i``; but every one is a range of the
+    flush's halving tree, a family fixed before the draw with at most
+    ``2n`` members.  A union bound over that family bounds a false pass
+    anywhere in the flush by ``2n * 2^-63``.  So the per-entry flags are
+    what a fresh draw at every level gives, up to that bound; only the
+    work differs.
+
+    Books ``bls_recheck_planes_total{planes="reused"}`` (one a bisection
+    level) and ``bls_chain_layouts_total`` as every chained call does, and
+    nothing into ``bls_chain_entries_total``, ``bls_chain_lanes_total`` or
+    ``bls_agg_entries_total``: no entry enters a ladder here."""
+    with span("bls_host_pack"):  # hash-to-G2, the rectangles, the h planes
+        checks = list(checks)
+        if not checks:
+            return []
+        inc("bls_recheck_planes_total", planes="reused")
+        layout = _chain_layout(checks, cache._interpret, b=planes.b)
+        operands = _tail_operands(checks, layout, offsets)
+    with span("bls_dispatch"):
+        ok = _dispatch_tail(cache._ops, planes.jac1, planes.jac2, operands)
     return _fetch_flags(ok)
 
 

@@ -109,12 +109,13 @@ _HELP = {
     "signature_decompress_seconds": "one batched G2 signature decompression + subgroup check (host)",
     "bls_host_pack_seconds": "host side of one chained verify up to its first device dispatch: hash-to-G2, entry packing, limb planes, uploads",
     "agg_index_pack_seconds": "inside bls_host_pack: the (entries, width) member-index and mask planes of one cached chained verify, at the call's gather width",
-    "bls_agg_entries_total": "committee entries of a cached chained verify, by the call's gather width (the committee cache's widths: narrowest for high participation, up to half the committee) and the side each entry lists (missing = subtracted from the cached committee sum, attesting = summed from the identity); bisection re-checks included",
+    "bls_agg_entries_total": "committee entries of a cached chained verify, by the call's gather width (the committee cache's widths: narrowest for high participation, up to half the committee) and the side each entry lists (missing = subtracted from the cached committee sum, attesting = summed from the identity); once per call that aggregates, so a bisection re-check on the first check's laddered planes books none",
     "bls_dispatch_seconds": "one chained verify's program calls and the layout packing between them (the device works meanwhile)",
-    "bls_chain_entries_total": "entries entering a chained device verify, by the shape their pubkeys take (single = gathered from the registry planes by validator index, committee = cached committee sum less the missing members or, below half participation, the attesting members' sum, points = host-packed points uploaded per call); bisection re-checks included",
-    "bls_chain_lanes_total": "lanes of the flat entry batch a chained device verify is dispatched at, by use (live = holds an entry, pad = padding up to the 1,024-lane tile or to a warmed layout: aggregation and ladders cost per lane whatever it holds); once a call, bisection re-checks included",
+    "bls_chain_entries_total": "entries entering a chained device verify, by the shape their pubkeys take (single = gathered from the registry planes by validator index, committee = cached committee sum less the missing members or, below half participation, the attesting members' sum, points = host-packed points uploaded per call); once per call that ladders its entries, so a bisection re-check on the first check's laddered planes books none",
+    "bls_chain_lanes_total": "lanes of the flat entry batch a chained device verify is dispatched at, by use (live = holds an entry, pad = padding up to the 1,024-lane tile or to a warmed layout: aggregation and ladders cost per lane whatever it holds); once per call that ladders its entries, so a bisection re-check on the first check's laddered planes books none",
     "bls_chain_layouts_total": "chained device verifies by the layout they were dispatched at (warmed = padded up to a layout a warmer loaded: the drain's or a rung of its bisection ladder; own = the call's own layout, a program set compiled or loaded inside the call)",
     "bls_bisect_seconds": "blame by bisection after a flush's first check failed: every level after the first, once per such flush",
+    "bls_recheck_planes_total": "bisection levels of a cached flush by the entry planes they judge on (reused = the chain's tail alone on the laddered planes of the flush's first check; no aggregation and no ladder)",
     "bls_bisect_checks_total": "ranges judged by bisection after a flush's first check, by result (pass = every entry of the range valid, fail = halved again or, at one entry, REJECTed)",
     "bls_device_wait_seconds": "host blocked fetching one chained verify's verdict flags from the device",
     "votes_apply_seconds": "vectorized latest-message + head-cache update for one drain's accepted votes",

@@ -9,7 +9,6 @@ table, no host point.  Held here (CPU, interpret mode) to
 and messages.
 """
 
-import functools
 import random
 
 import numpy as np
@@ -85,8 +84,6 @@ def runs():
     out = {}
     with pytest.MonkeyPatch.context() as mp:
         mp.setattr(batch_mod, "_COEFF_BITS", 16)
-        mp.setattr(BB, "chain_verify_cached",
-                   functools.partial(BB.chain_verify_cached, coeff_bits=16))
         # (1 call) a subnet drain: single signers only, all valid
         out["valid"] = both([single(v, v % 3) for v in (0, 3, 5, 9, 12, 15)])
         # (2 calls of one shape) blame by bisection: a valid vote, validator
@@ -105,7 +102,8 @@ def runs():
         before = _counters()
         out["mixed"] = BB.chain_verify_cached(cache, [
             ([(c[0], c[1], c[3], 3 + 2 * i) for i, (c, _) in enumerate(pairs)],
-             hs, [MSGS.index(c[2]) for c, _ in pairs]) for pairs in checks])
+             hs, [MSGS.index(c[2]) for c, _ in pairs]) for pairs in checks],
+            coeff_bits=16)
         out["mixed_gained"] = _gained(before)
         out["mixed_host"] = [all(batch_verify_each_points([p for _, p in pairs]))
                              for pairs in checks]

@@ -200,8 +200,6 @@ def mixed_flush(keys):
              entry(1, 6, 1), entry(0, 7, 0, corrupt=True), entry(1, 11, 1)]
     with pytest.MonkeyPatch.context() as mp:
         mp.setattr(batch_mod, "_COEFF_BITS", 16)
-        mp.setattr(BB, "chain_verify_cached",
-                   functools.partial(BB.chain_verify_cached, coeff_bits=16))
         agg0, chain0 = _agg_counter(), _chain_counter()
         cached = batch_verify_each_cached(cache, [c for c, _ in pairs])
         gained = _gained(agg0, _agg_counter()), _gained(chain0, _chain_counter())
@@ -215,17 +213,16 @@ def test_mixed_flush_blames_the_wrong_secret_alone(mixed_flush):
 
 
 def test_mixed_flush_counts_entries_by_width_and_side(mixed_flush):
-    """A call of the chain is padded to its widest entry, and a bisection
-    level is one call: the flush (6 entries), its two halves together (6),
-    [sparse 6] with [bad 7, sparse 11] (3), [bad 7] with [sparse 11] (2) —
-    every one holds a sparse entry, so every one runs at width 8.  No
-    entry takes the uncached chain."""
+    """A call of the chain is padded to its widest entry: the flush's first
+    check (6 entries) holds sparse ones, so it runs at width 8.  The three
+    bisection levels re-check on that check's laddered planes
+    (``chain_recheck``) and book no entry.  No entry takes the uncached
+    chain."""
     agg, chain = mixed_flush["agg"], mixed_flush["chain"]
     assert chain.get("points", 0) == 0
-    assert chain["single"] == 2  # the flush, the first half
-    # missing side: dense 1, dense 0, sparse 6, bad 7 twice; 6, 7; 7
-    # attesting side: sparse 11 in each of the four calls
-    assert agg == {("8", "missing"): 4 + 4 + 2 + 1, ("8", "attesting"): 4}
+    assert chain["single"] == 1
+    # missing side: dense 1, dense 0, sparse 6, bad 7; attesting side: sparse 11
+    assert agg == {("8", "missing"): 4, ("8", "attesting"): 1}
     assert sum(agg.values()) == chain["committee"]
 
 
@@ -241,7 +238,8 @@ def full_flushes(keys):
     """Cached calls with no padding lane (interpret mode: quantum 8), their
     (group, slot) rectangles padded all the same: a flush of 8 that mixes
     single signers and both committee sides with a wrong secret in the LAST
-    lane (4 chained calls: 8, 4 + 4, 2 + 2, 1 + 1 entries), 8 single
+    lane (4 chained calls: 8, then 4 + 4, 2 + 2, 1 + 1 entries re-checked on
+    the first call's laddered planes), 8 single
     signers (1 call), 16 aggregates of both sides (1 call) — against the
     host oracle over the same points."""
     _, _, cache = keys
@@ -259,8 +257,6 @@ def full_flushes(keys):
     out = {}
     with pytest.MonkeyPatch.context() as mp:
         mp.setattr(batch_mod, "_COEFF_BITS", 16)
-        mp.setattr(BB, "chain_verify_cached",
-                   functools.partial(BB.chain_verify_cached, coeff_bits=16))
         for name, pairs in flushes.items():
             lanes0, chain0 = _lanes(), _chain_counter()
             cached = batch_verify_each_cached(cache, [c for c, _ in pairs])
@@ -278,8 +274,8 @@ def test_a_full_flush_reads_the_host_oracles_verdicts(full_flushes, name, want):
 
 
 @pytest.mark.parametrize("name,lanes,chain", [
-    # 8, 4 + 4 full; 2 + 2 and 1 + 1 keep padding lanes up to the quantum
-    ("blame8", {"live": 22.0, "pad": 10.0}, {"single": 2 + 2 + 1 + 1, "committee": 6 + 6 + 3 + 1}),
+    # the first call, full; its levels re-check on its planes and book no lane
+    ("blame8", {"live": 8.0}, {"single": 2, "committee": 6}),
     ("single8", {"live": 8.0}, {"single": 8.0}),  # pad gains nothing
     ("valid16", {"live": 16.0}, {"committee": 16.0}),
 ])
@@ -338,8 +334,9 @@ def test_a_call_inside_a_warmed_layout_is_padded_up_to_it(warmed_layout, checks,
 def test_a_padded_flush_reads_the_same_verdicts(keys, warmed_layout, monkeypatch):
     """Three aggregates, one signed with a wrong secret, through the chain
     with a larger layout warmed: the flush is dispatched at the warmed
-    shapes (its bisection levels, two checks each, at their own), and the
-    blame falls where it falls unpadded."""
+    shapes (its bisection levels, two checks each, at their own on the
+    first call's planes and so at its b), and the blame falls where it
+    falls unpadded."""
     sks, reg, cache = keys
     rng = random.Random(5)
     hs = [hash_to_g2(m, DST_POP) for m in MSGS]
@@ -357,16 +354,16 @@ def test_a_padded_flush_reads_the_same_verdicts(keys, warmed_layout, monkeypatch
 
     monkeypatch.setitem(cache._ops, "prep", recording)
     monkeypatch.setattr(batch_mod, "_COEFF_BITS", 16)
-    monkeypatch.setattr(BB, "chain_verify_cached",
-                        functools.partial(BB.chain_verify_cached, coeff_bits=16))
     assert batch_verify_each_cached(cache, entries) == [True, False, True]
     assert shapes[0] == tuple(warmed_layout)  # the flush: 3 entries at (16, 1, 7, 4, 16)
     assert [sh[1] for sh in shapes[1:]] == [2, 2]  # [a] with [bad, c]; [bad] with [c]
+    assert [sh[0] for sh in shapes[1:]] == [16, 16]  # the flush's planes
     padded = list(shapes)
     BB._WARMED_LAYOUTS.clear()
     shapes.clear()
     assert batch_verify_each_cached(cache, entries) == [True, False, True]
-    assert shapes[0] == (8, 1, 3, 2, 4) and shapes[1:] == padded[1:]
+    assert shapes[0] == (8, 1, 3, 2, 4) and [sh[0] for sh in shapes[1:]] == [8, 8]
+    assert [sh[1:] for sh in shapes[1:]] == [sh[1:] for sh in padded[1:]]
 
 
 @pytest.mark.parametrize("entries,groups,want", [
@@ -487,8 +484,6 @@ def sparse_block():
             mp.setenv("BLS_DEVICE_CHAIN", "1")
             mp.setenv("BLS_BLOCK_BATCH_MIN_MEMBERS", "1")
             mp.setattr(batch_mod, "_COEFF_BITS", 16)
-            mp.setattr(BB, "chain_verify_cached",
-                       functools.partial(BB.chain_verify_cached, coeff_bits=16))
             agg0, chain0 = _agg_counter(), _chain_counter()
             out["device"] = state_transition(genesis, signed, spec=spec).hash_tree_root(spec)
             out["agg"] = _gained(agg0, _agg_counter())

@@ -29,7 +29,6 @@ from lambda_ethereum_consensus_tpu.crypto import bls
 from lambda_ethereum_consensus_tpu.crypto.bls import batch as batch_mod
 from lambda_ethereum_consensus_tpu.node import BeaconNode, NodeConfig
 from lambda_ethereum_consensus_tpu.node import ingest as ingest_mod
-from lambda_ethereum_consensus_tpu.ops import bls_batch as BB
 from lambda_ethereum_consensus_tpu.state_transition import accessors, misc
 from lambda_ethereum_consensus_tpu.state_transition.genesis import build_genesis_state
 from lambda_ethereum_consensus_tpu.types.beacon import Attestation, AttestationData, Checkpoint
@@ -260,10 +259,6 @@ def ran(tmp_path_factory):
         mp.setenv("BLS_DEVICE_CHAIN_MIN", "1")
         # 16-bit RLC coefficients and ladder, as the benchmark's rehearsal
         mp.setattr(batch_mod, "_COEFF_BITS", 16)
-        import functools
-
-        mp.setattr(BB, "chain_verify_cached",
-                   functools.partial(BB.chain_verify_cached, coeff_bits=16))
         m = telemetry.get_metrics()
         was = m.enabled
         m.set_enabled(True)  # the default registry recording, whatever TELEMETRY_OFF says
